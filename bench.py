@@ -435,8 +435,8 @@ def main():
     import jax
 
     # persistent XLA compile cache: the SECOND process run of this bench
-    # skips the cold spec/compile entirely (H2O3_COMPILE_CACHE_DIR knob;
-    # time_to_first_model_s below tracks the win per round).
+    # skips the cold spec/compile entirely (JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax_cache; time_to_first_model_s below tracks the win).
     # setup_compilation_cache also installs the telemetry collectors, so
     # the compile/cache/transfer counters below see the whole round.
     cache_dir = setup_compilation_cache()

@@ -16,12 +16,11 @@ Env contract (set by h2o-k8s/manifests or the h2o-helm chart):
                             StatefulSet hostname suffix when unset
   H2O3_REST_PORT            REST port on the coordinator (default 54321)
   H2O3_MESH_MODEL           'model' mesh axis size (default 1)
-  H2O3_COMPILE_CACHE_DIR    persistent XLA compilation cache directory
-                            (default ~/.cache/h2o3_tpu/xla; '0'/'off'
-                            disables). Mount a PVC here so a pod
-                            restart's time-to-first-model skips the
-                            cold train-step compile (~2 minutes at the
-                            10M-row bench shape).
+  JAX_COMPILATION_CACHE_DIR JAX's own variable: where the persistent XLA
+                            compilation cache lives. Unset, the cache is
+                            <checkout>/.jax_cache. Mount a PVC there so a
+                            pod restart's time-to-first-model skips the
+                            cold train-step compile.
   H2O3_RECOVERY_DIR         durable restart-recovery root (mount a PVC).
                             When set, boot scans it for trains the
                             PREVIOUS process left interrupted (crash /
@@ -42,46 +41,42 @@ from dataclasses import dataclass
 from typing import Mapping, Optional
 
 
-def setup_compilation_cache(env: Optional[Mapping[str, str]] = None) -> Optional[str]:
+# the one default cache location: inside the checkout (git-ignored), so
+# a copy of the tree at the same path finds what an earlier run compiled
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def setup_compilation_cache() -> str:
     """Wire JAX's persistent compilation cache so the cold train-step
     spec/compile amortises across process restarts (the reference JVM
     has no compile step; this cost is TPU-stack-specific and so is the
-    fix). Returns the cache dir, or None when disabled / unsupported.
+    fix). Returns the cache dir in use.
+
+    The directory is placed from OUTSIDE: with ``JAX_COMPILATION_CACHE_DIR``
+    in the environment JAX has already read it into
+    ``jax.config.jax_compilation_cache_dir`` and nothing is set here; a
+    directory some caller configured before (the test conftest's
+    per-worker cache) is kept as well. Otherwise the cache is the fixed
+    ``<checkout>/.jax_cache`` — never a temp name, pid or time, because
+    the path has to be the same for the next process to hit.
 
     Safe to call before OR after the first jax use in the process —
-    compiles after the call hit the cache. Honors an explicit
-    ``jax_compilation_cache_dir`` already set (e.g. the test conftest's
-    per-worker cache) rather than overriding it."""
-    env = dict(env if env is not None else os.environ)
+    compiles after the call hit the cache."""
     # boot is the earliest common chokepoint every entrypoint passes
-    # through (k8s pod, bench, tools) — install the telemetry listeners
-    # here so the production compile counter sees the FIRST compile
+    # through (k8s pod, bench, tools, chip_smoke) — install the telemetry
+    # listeners here so the production compile counter sees the FIRST
+    # compile
     from h2o3_tpu import telemetry
     telemetry.install()
-    raw = env.get("H2O3_COMPILE_CACHE_DIR")
-    raw = raw.strip() if raw is not None else None   # k8s YAML whitespace
-    if raw is not None and raw.lower() in ("0", "off", "false"):
-        return None
-    # empty-but-set (blank helm value) means unset: fall through to the
-    # default dir rather than silently disabling the cache
     import jax
-    try:
-        if jax.config.jax_compilation_cache_dir:
-            return jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        pass
-    d = raw or os.path.join(os.path.expanduser("~"), ".cache",
-                            "h2o3_tpu", "xla")
-    try:
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        # the defaults skip sub-second compiles; the chunked train step
-        # is minutes cold, so any threshold works — keep 1s to avoid
-        # churning the cache with trivial eager-op executables
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except (OSError, AttributeError, ValueError):
-        return None
-    return d
+    if jax.config.jax_compilation_cache_dir:
+        return jax.config.jax_compilation_cache_dir
+    os.makedirs(DEFAULT_COMPILE_CACHE_DIR, exist_ok=True)
+    # JAX's own 1-second floor on what is worth caching stays: it keeps
+    # the trivial eager-op executables out of the directory
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+    return DEFAULT_COMPILE_CACHE_DIR
 
 
 @dataclass

@@ -10,7 +10,7 @@ replica runs one :class:`FleetAgent` that:
    response carries the fleet's registry snapshot, and the agent
    deploys every model it can resolve with ``warm=True`` — compiles
    land in the shared persistent compile cache
-   (``H2O3_COMPILE_CACHE_DIR``, cluster_boot.setup_compilation_cache),
+   (``JAX_COMPILATION_CACHE_DIR``, cluster_boot.setup_compilation_cache),
    so a restarted replica's warmup is a cache read, and the first
    ROUTED request compiles zero XLA modules;
 3. **heartbeats** every ``H2O3_FLEET_HEARTBEAT_MS``: incarnation token

@@ -38,14 +38,18 @@ def _default_budget() -> int:
     env = os.environ.get("H2O3_DEVICE_BUDGET_BYTES")
     if env:
         return int(env)
-    try:
-        import jax
-        d = jax.devices()[0]
-        stats = d.memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
+    import jax
+    d = jax.devices()[0]
+    stats = d.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if d.platform == "tpu":
+        # an unlimited budget on a 16 GB chip turns every admission and
+        # dense-vs-streamed decision into a guess — fail where it shows
+        raise RuntimeError(
+            f"{d.device_kind}: memory_stats() reports no bytes_limit "
+            f"({stats!r}); set H2O3_DEVICE_BUDGET_BYTES to the device's "
+            "memory to run without it")
     return 1 << 62             # effectively unlimited (CPU backend)
 
 

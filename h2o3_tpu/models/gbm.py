@@ -863,12 +863,24 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                         # surfaces here and retries like any other
                         # transient execute error
                         faults.check("collective", pipeline="train")
-                return step(
+                operands = (
                     Xtr, codes_t_arg, margin, yf, w, vtrain, vmargin,
                     key, jnp.float32(lr), huber_delta,
                     root_lo, root_hi, nb_f, mono_arr, sets_arr,
                     jnp.int32(start_trees + disp), jnp.int32(c),
                     rate_t, col_rate_t, anneal_t)
+                # AOT handle on this chunk executable, by shape only: it
+                # pins no device buffer and never reads a margin the
+                # dispatch below has donated. Only a committed operand
+                # keeps its sharding — the scalars and dummies follow
+                # the mesh as they do in the call. The cost capture
+                # lowers through it; chip_smoke.py reads the compiled HLO
+                self.chunk_lowering = partial(step.lower, *(
+                    jax.ShapeDtypeStruct(
+                        a.shape, a.dtype,
+                        sharding=a.sharding if a.committed else None)
+                    for a in operands))
+                return step(*operands)
             try:
                 # transient device failures retry with backoff; donated
                 # operand buffers cannot be replayed, so donation (TPU,
@@ -906,15 +918,8 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                 # so a cold key's trace+lower (host work inside the
                 # measured loop) is excluded from device seconds.
                 t_cap0 = time.perf_counter()
-                step = _compiled_chunk(*lru_key)    # lru cache hit
                 perf_acc.add(telemetry.costmodel.executable_cost(
-                    ("gbm.chunk",) + lru_key,
-                    lambda s=step, d=disp, cc=c: s.lower(
-                        Xtr, codes_t_arg, margin, yf, w, vtrain,
-                        vmargin, key, jnp.float32(lr), huber_delta,
-                        root_lo, root_hi, nb_f, mono_arr, sets_arr,
-                        jnp.int32(start_trees + d), jnp.int32(cc),
-                        rate_t, col_rate_t, anneal_t),
+                    ("gbm.chunk",) + lru_key, self.chunk_lowering,
                     scale=bucket))
                 perf_acc.note_capture_seconds(
                     time.perf_counter() - t_cap0)

@@ -32,7 +32,7 @@ import warnings
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "fast_csv.cpp")
 _SO = os.path.join(_DIR, "libfastcsv.so")
-_HASH = _SO + ".srchash"  # sha256 of the source the .so was built from
+_HASH = _SO + ".srchash"  # stamp: source + host CPU the .so was built for
 _COMPILER = "g++"
 _LOCK = threading.Lock()
 _LIB = None
@@ -47,18 +47,33 @@ DECLINE_REASONS = {1: "ragged_rows", 2: "unterminated_quote",
                    3: "trailing_after_quote"}
 
 
-def _src_hash() -> str:
+def _build_stamp() -> str:
+    """sha256 over the source AND this host's CPU feature flags: the
+    build uses ``-march=native``, so a .so carried to a host with another
+    CPU (a copied working tree) must rebuild, not die of SIGILL inside
+    the tokenizer with no Python error."""
+    h = hashlib.sha256()
     with open(_SRC, "rb") as f:
-        return hashlib.sha256(f.read()).hexdigest()
+        h.update(f.read())
+    try:
+        with open("/proc/cpuinfo") as f:
+            h.update(next((ln for ln in f if ln.startswith("flags")),
+                          "").encode())
+    except OSError:
+        pass                    # no /proc: the stamp covers the source only
+    return h.hexdigest()
 
 
 def _build() -> bool:
-    """Compile the .so and stamp the source hash it was built from. A
+    """Compile the .so and stamp what it was built from and for. A
     failed compile records a clear error NAMING the compiler (the silent
     `return False` used to leave "why is ingest slow" undiagnosable)."""
     global BUILD_ERROR
+    # per-process temporaries: several processes (xdist workers) may
+    # build at once, and each renames a whole file into the fixed path
+    tmp_so = f"{_SO}.{os.getpid()}.tmp"
     cmd = [_COMPILER, "-O3", "-march=native", "-shared", "-fPIC",
-           "-o", _SO + ".tmp", _SRC]
+           "-o", tmp_so, _SRC]
     try:
         r = subprocess.run(cmd, capture_output=True, timeout=120)
     except (OSError, subprocess.SubprocessError) as e:
@@ -73,36 +88,32 @@ def _build() -> bool:
                        f"{r.returncode} compiling {_SRC}:\n{tail}")
         warnings.warn(BUILD_ERROR, RuntimeWarning, stacklevel=2)
         return False
-    os.replace(_SO + ".tmp", _SO)
+    os.replace(tmp_so, _SO)
     try:
-        with open(_HASH + ".tmp", "w") as f:
-            f.write(_src_hash())
-        os.replace(_HASH + ".tmp", _HASH)
+        tmp_hash = f"{_HASH}.{os.getpid()}.tmp"
+        with open(tmp_hash, "w") as f:
+            f.write(_build_stamp())
+        os.replace(tmp_hash, _HASH)
     except OSError:
-        pass  # hash sidecar is advisory; mtime still catches most edits
+        pass  # without its stamp the next process rebuilds
     BUILD_ERROR = None
     return True
 
 
 def _stale() -> bool:
-    """Rebuild-if-stale guard: CONTENT hash of fast_csv.cpp against the
-    sidecar stamped at build time. mtime alone served stale symbols when
-    a checkout/copy stamped the .so newer than an edited source (git
-    checkout, rsync, build caches) — with new entry points landing per
-    PR that silently pinned callers to an old ABI."""
+    """Rebuild-if-stale guard: the stamp written at build time against
+    this checkout's source and this host's CPU. mtime alone served stale
+    symbols when a checkout/copy stamped the .so newer than an edited
+    source (git checkout, rsync, build caches) — with new entry points
+    landing per PR that silently pinned callers to an old ABI. A .so
+    without a stamp says nothing of the CPU it was built for: rebuild."""
     if not os.path.exists(_SO):
         return True
     try:
         with open(_HASH) as f:
-            built_from = f.read().strip()
+            return f.read().strip() != _build_stamp()
     except OSError:
-        # pre-hash .so (or lost sidecar): fall back to the mtime check
-        # once; the rebuild it triggers writes the sidecar
-        try:
-            return os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-        except OSError:
-            return True
-    return built_from != _src_hash()
+        return True
 
 
 def lib():

@@ -1128,15 +1128,22 @@ def _stripe_probe() -> bool:
     if jax.default_backend() != "tpu":
         return False
     try:
-        ct = jnp.full((2, TILE), 15, jnp.int8)
-        nid = jnp.zeros(TILE, jnp.int32)
-        ghw = jnp.zeros((3, TILE), jnp.float32)
-        z1 = jnp.zeros(1, jnp.float32)
-        nid2, hist = binned_level_tpu_stripe(
-            ct, nid, ghw, (z1, z1, z1, z1), 0, 1, 0, 16)
-        jax.block_until_ready((nid2, hist))  # h2o3-lint: allow[transfer-seam] once-per-process capability probe: the block IS the probe (Mosaic lowering failures surface at execute)
+        # binned_level asks from inside the chunk step's trace, where
+        # these ops would only be staged and nothing would reach Mosaic
+        with jax.core.eval_context():
+            ct = jnp.full((2, TILE), 15, jnp.int8)
+            nid = jnp.zeros(TILE, jnp.int32)
+            ghw = jnp.zeros((3, TILE), jnp.float32)
+            z1 = jnp.zeros(1, jnp.float32)
+            nid2, hist = binned_level_tpu_stripe(
+                ct, nid, ghw, (z1, z1, z1, z1), 0, 1, 0, 16)
+            jax.block_until_ready((nid2, hist))  # h2o3-lint: allow[transfer-seam] once-per-process capability probe: the block IS the probe (Mosaic lowering failures surface at execute)
         return True
-    except Exception:
+    except Exception as e:  # noqa: BLE001 — any Mosaic refusal demotes
+        from h2o3_tpu.log import warn
+        warn("binned_level_tpu_stripe failed its Mosaic probe (%s: %s) — "
+             "W=16 levels run binned_level_tpu_t instead",
+             type(e).__name__, e)
         return False
 
 
@@ -1343,6 +1350,17 @@ def _binned_pad(ct, nid, ghw, W):
     return ct, nid, ghw
 
 
+def binned_level_kernel(W: int, F: int, method: str = "auto") -> str:
+    """Name of the body :func:`binned_level` dispatches a bf16/f32 level
+    to — the one rule both the dispatch and its reporters
+    (chip_smoke.py) read, so what is printed is what ran."""
+    if _resolve_method(method) != "pallas":
+        return "binned_level_xla"
+    if W == 16 and F >= 2 and stripe_supported():
+        return "binned_level_tpu_stripe"
+    return "binned_level_tpu_t"
+
+
 def binned_level(codes_rm, nid, ghw, tables, n_prev: int, n_nodes: int,
                  level_base: int, W: int, method: str = "auto",
                  mxu_dtype=jnp.bfloat16, ct=None, qs=None):
@@ -1368,7 +1386,8 @@ def binned_level(codes_rm, nid, ghw, tables, n_prev: int, n_nodes: int,
                 ct, nid, q, scales, tables, n_prev, n_nodes, level_base,
                 W, interpret=pallas_interpret())
             return nid2[:rows], hist
-        if W == 16 and ct.shape[0] >= 2 and stripe_supported():
+        if (binned_level_kernel(W, ct.shape[0], method)
+                == "binned_level_tpu_stripe"):
             from h2o3_tpu.ops.binning import stripe_pair_codes
             nid2, hist = binned_level_tpu_stripe(
                 stripe_pair_codes(ct, W), nid, ghw, tables, n_prev,

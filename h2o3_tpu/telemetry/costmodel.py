@@ -25,10 +25,10 @@ Honesty riders, recorded rather than hidden:
 - peaks come from a per-chip lookup table over
   ``jax.devices()[0].device_kind`` (bf16 MXU peak + HBM bandwidth),
   overridable via ``H2O3_PEAK_FLOPS`` / ``H2O3_PEAK_BYTES_PER_S`` for
-  unknown hardware. ``peak_source`` is recorded per field; any
-  ``nominal`` source (CPU / unknown kind without an override) flags the
-  whole point ``informational`` — a CPU-virtual MFU is a trend line,
-  not a utilization claim.
+  hardware the table does not know — without which an unknown TPU kind
+  is an error. ``peak_source`` is recorded per field; the CPU backend's
+  ``nominal`` source flags the whole point ``informational`` — a
+  CPU-virtual MFU is a trend line, not a utilization claim.
 
 ``H2O3_TELEMETRY=0`` keeps every producer a checked no-op:
 ``accumulator()`` returns None and ``executable_cost`` returns without
@@ -36,6 +36,7 @@ tracing anything.
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from collections import OrderedDict
@@ -68,19 +69,11 @@ _PEAK_TABLE: Tuple[Tuple[str, float, float], ...] = (
     ("tpu v2", 45e12, 700e9),
 )
 
-# unknown hardware (CPU backend, virtual devices, new TPU kinds without
-# a table row or override): a nominal single-socket-class constant so
-# trend lines still render — flagged informational, never a claim
+# the CPU backend (virtual test devices): a nominal single-socket-class
+# constant so trend lines still render — flagged informational, never a
+# claim. A TPU kind without a table row or override is an error.
 NOMINAL_PEAK_FLOPS = 1e12
 NOMINAL_PEAK_BYTES_PER_S = 100e9
-
-
-def _device_kind() -> str:
-    try:
-        import jax
-        return str(jax.devices()[0].device_kind)
-    except Exception:
-        return "unknown"
 
 
 def _env_float(name: str) -> Optional[float]:
@@ -98,10 +91,14 @@ def _env_float(name: str) -> Optional[float]:
 def device_peaks() -> Dict[str, object]:
     """Per-chip peak FLOPS and memory bandwidth with provenance:
     ``source`` per field is ``override`` (env), ``table`` (device_kind
-    lookup) or ``nominal`` (unknown hardware); ``informational`` is set
-    whenever any field fell back to nominal. Read fresh each call (env
-    overrides are test/bench knobs)."""
-    kind = _device_kind()
+    lookup) or ``nominal`` (the CPU backend); ``informational`` is set
+    whenever any field fell back to nominal. A TPU whose kind has
+    neither a table row nor an override raises — a made-up peak would
+    read as a utilization. Read fresh each call (env overrides are
+    test/bench knobs)."""
+    import jax
+    dev = jax.devices()[0]
+    kind = str(dev.device_kind)
     t_flops = t_bytes = None
     for sub, fl, by in _PEAK_TABLE:
         if sub in kind.lower():
@@ -110,6 +107,12 @@ def device_peaks() -> Dict[str, object]:
     out: Dict[str, object] = {"device_kind": kind}
     ov_f = _env_float("H2O3_PEAK_FLOPS")
     ov_b = _env_float("H2O3_PEAK_BYTES_PER_S")
+    if dev.platform == "tpu" and ((ov_f is None and t_flops is None)
+                                  or (ov_b is None and t_bytes is None)):
+        raise LookupError(
+            f"no peak FLOP/s and bytes/s known for TPU device_kind "
+            f"'{kind}': add a _PEAK_TABLE row with its source, or set "
+            "H2O3_PEAK_FLOPS and H2O3_PEAK_BYTES_PER_S")
     if ov_f is not None:
         out["flops"], out["flops_source"] = ov_f, "override"
     elif t_flops is not None:
@@ -155,6 +158,16 @@ def _extract_cost(lowered) -> Optional[Cost]:
                 float(ca.get("bytes accessed", 0.0) or 0.0))
 
 
+@functools.lru_cache(maxsize=8)
+def _warn_no_cost(why: str) -> None:
+    """Say ONCE per reason that executables go unaccounted — otherwise
+    ``model.output['perf']`` and every MFU field are silently absent
+    (the TPU runtime answers ``Lowered.cost_analysis()`` with None)."""
+    from h2o3_tpu.log import warn
+    warn("performance accounting is off: %s — no FLOP/byte cost is "
+         "attached to this process's executables", why)
+
+
 def lowered_cost(lower: Callable[[], object],
                  scale: float = 1.0) -> Optional[Cost]:
     """Uncached capture: ``lower()`` returns a ``jax.stages.Lowered``
@@ -165,9 +178,11 @@ def lowered_cost(lower: Callable[[], object],
         return None
     try:
         c = _extract_cost(lower())
-    except Exception:
-        return None
+        why = "the backend returned no cost analysis for lowered HLO"
+    except Exception as e:  # noqa: BLE001 — accounting must not fail a train
+        c, why = None, f"{type(e).__name__}: {e}"
     if c is None:
+        _warn_no_cost(why)
         return None
     return Cost(c.flops * scale, c.bytes * scale)
 

@@ -8,7 +8,9 @@ import os
 import pytest
 import yaml
 
-from h2o3_tpu.cluster_boot import BootConfig, resolve_boot_config
+from h2o3_tpu.cluster_boot import (DEFAULT_COMPILE_CACHE_DIR, BootConfig,
+                                   resolve_boot_config,
+                                   setup_compilation_cache)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -69,3 +71,23 @@ def test_resolve_boot_config_validation():
                             hostname="x-0")
     with pytest.raises(ValueError, match="ordinal"):
         resolve_boot_config(base, hostname="nodigit")
+
+
+@pytest.mark.parametrize("placed", [True, False],
+                         ids=["JAX_COMPILATION_CACHE_DIR", "unset"])
+def test_compile_cache_is_placed_from_outside(placed, tmp_path):
+    """JAX reads JAX_COMPILATION_CACHE_DIR into its config at import;
+    setup_compilation_cache keeps that directory, and without one uses
+    the fixed <checkout>/.jax_cache — the same path on every call."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    want = str(tmp_path / "xla") if placed else DEFAULT_COMPILE_CACHE_DIR
+    try:
+        jax.config.update("jax_compilation_cache_dir",
+                          want if placed else None)
+        assert setup_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert setup_compilation_cache() == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert DEFAULT_COMPILE_CACHE_DIR == os.path.join(ROOT, ".jax_cache")

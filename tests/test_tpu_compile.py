@@ -1,0 +1,149 @@
+"""The main path's kernels, compiled for a v5e that is described, not
+attached (on-chip-measurement guide, section 2): what Mosaic or XLA:TPU
+would refuse on the chip fails here, at no chip time. Nothing runs, so
+this says nothing about results or speed.
+
+The ONLY file that describes the chip. The topology is described inside
+a module-scoped fixture, never at import: one process at a time may load
+libtpu, and under xdist every worker imports every test file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from h2o3_tpu.models.tree import predict_raw_stacked
+from h2o3_tpu.ops import hist_adaptive as ha
+from h2o3_tpu.ops import hist_pallas
+from h2o3_tpu.ops.binning import stripe_pair_codes
+
+F = 28                    # HIGGS width, the bench and chip_smoke shape
+W = 16                    # nbins=14 -> 16 lanes per feature
+ROWS = 8 * ha.TILE
+ROOT = (0, 1, 0)          # (n_prev, n_nodes, level_base)
+LEVEL5 = (16, 32, 31)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _tables(n_prev):
+    n = max(n_prev, 1)
+    return tuple(((n,), jnp.float32) for _ in range(4))
+
+
+def _level_operands(code_dtype, n_prev):
+    return [((F, ROWS), code_dtype), ((ROWS,), jnp.int32),
+            ((3, ROWS), jnp.float32), *_tables(n_prev)]
+
+
+def _binned_t(level):
+    n_prev, n_nodes, base = level
+
+    def fn(ct, nid, ghw, *tables):
+        return ha.binned_level_tpu_t(ct, nid, ghw, tables, n_prev, n_nodes,
+                                     base, W, tile=ha.TILE)
+    return fn, _level_operands(jnp.int8, n_prev)
+
+
+def _binned_stripe(level, mxu_dtype=jnp.bfloat16):
+    n_prev, n_nodes, base = level
+
+    def fn(ct, nid, ghw, *tables):
+        # the operand and F exactly as binned_level hands them over
+        return ha.binned_level_tpu_stripe(
+            stripe_pair_codes(ct, W), nid, ghw, tables, n_prev, n_nodes,
+            base, W, tile=ha.TILE, F=ct.shape[0], mxu_dtype=mxu_dtype)
+    return fn, _level_operands(jnp.int8, n_prev)
+
+
+def _binned_route():
+    def fn(ct, nid, *tables):
+        return ha.binned_route_only_tpu_t(ct, nid, tables, 32, 63, W,
+                                          tile=ha.TILE)
+    return fn, [((F, ROWS), jnp.int8), ((ROWS,), jnp.int32), *_tables(32)]
+
+
+def _adaptive_t(level):
+    n_prev, n_nodes, base = level
+
+    def fn(xt, nid, ghw, lo, inv, *tables):
+        return ha.adaptive_level_tpu_t(xt, nid, ghw, tables, lo, inv,
+                                       n_prev, n_nodes, base, W,
+                                       tile=ha.TILE)
+    ops = _level_operands(jnp.float32, n_prev)
+    ranges = [((n_nodes, F), jnp.float32)] * 2
+    return fn, ops[:3] + ranges + ops[3:]
+
+
+def _hist_pallas3():
+    rows = 8 * hist_pallas.TILE
+
+    def fn(codes_t, seg, ghw):
+        return hist_pallas.hist_pallas3(codes_t, seg, ghw, 32, W)
+    return fn, [((32, rows), jnp.int32), ((rows,), jnp.int32),
+                ((3, rows), jnp.float32)]
+
+
+def _serve_scorer():
+    """The deployed GBM's scorer at the 64-row serving bucket."""
+    trees, depth = 5, 6
+    nodes = 2 ** (depth + 1) - 1
+
+    def fn(X, feat, thr, na_left, is_split, value):
+        return predict_raw_stacked(X, feat, thr, na_left, is_split, value,
+                                   depth)
+    tm = (trees, nodes)
+    return fn, [((64, F), jnp.float32), (tm, jnp.int32), (tm, jnp.float32),
+                (tm, jnp.bool_), (tm, jnp.bool_), (tm, jnp.float32)]
+
+
+CASES = {
+    "binned_level_tpu_t-root": lambda: _binned_t(ROOT),
+    "binned_level_tpu_t-level5": lambda: _binned_t(LEVEL5),
+    "binned_level_tpu_stripe-root": lambda: _binned_stripe(ROOT),
+    "binned_level_tpu_stripe-level5": lambda: _binned_stripe(LEVEL5),
+    # what histogram_precision='auto' runs below 2^18 rows
+    "binned_level_tpu_stripe-level5-f32":
+        lambda: _binned_stripe(LEVEL5, jnp.float32),
+    "binned_route_only_tpu_t": _binned_route,
+    "adaptive_level_tpu_t-level5": lambda: _adaptive_t(LEVEL5),
+    "hist_pallas3": _hist_pallas3,
+    "predict_raw_stacked-bucket64": _serve_scorer,
+}
+PALLAS = {k for k in CASES if not k.startswith("predict_raw_stacked")}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_v5e(case, one_chip, no_persistent_cache):
+    fn, operands = CASES[case]()
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+              for shape, dtype in operands]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    n_mosaic = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    assert (n_mosaic > 0) == (case in PALLAS), (case, n_mosaic)

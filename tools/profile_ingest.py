@@ -179,9 +179,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from h2o3_tpu import telemetry
+    from h2o3_tpu.cluster_boot import setup_compilation_cache
     from h2o3_tpu.ingest.parse import parse_setup
 
-    telemetry.install()
+    setup_compilation_cache()               # also installs telemetry
     if not telemetry.enabled():
         log("H2O3_TELEMETRY=0: stage attribution unavailable — stage "
             "fields will be null (re-run with telemetry enabled)")

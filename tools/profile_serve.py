@@ -39,9 +39,10 @@ def log(*a):
 def main():
     import h2o3_tpu as h2o
     from h2o3_tpu import serve, telemetry
+    from h2o3_tpu.cluster_boot import setup_compilation_cache
     from h2o3_tpu.models.gbm import H2OGradientBoostingEstimator
 
-    telemetry.install()
+    setup_compilation_cache()               # also installs telemetry
     if not telemetry.enabled():
         log("H2O3_TELEMETRY=0: span/compile attribution unavailable — "
             "those fields will be empty (stats still report)")
