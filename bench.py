@@ -743,39 +743,6 @@ def main():
             out["blackbox"] = _blackbox_round()
         except Exception as e:  # must never sink the headline run
             log(f"blackbox round FAILED to run: {e!r}")
-    # multichip scaling round (ISSUE 7): rows/s/chip at n_devices ∈
-    # {1,4,8} with a scaling-efficiency verdict (tools/multichip_bench.py
-    # runs in its OWN process so a single-chip parent can still force
-    # the 8-virtual-device CPU mesh; on TPU it inherits the real fleet)
-    if os.environ.get("H2O3_BENCH_MULTICHIP", "1") not in ("0", "false",
-                                                           ""):
-        try:
-            import subprocess
-            tool = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                "tools", "multichip_bench.py")
-            r = subprocess.run([sys.executable, tool], capture_output=True,
-                               text=True, timeout=3600)
-            if r.returncode == 0 and r.stdout.strip():
-                out["multichip"] = json.loads(
-                    r.stdout.strip().splitlines()[-1])
-                # collective/straggler attribution (ISSUE 8): a scaling
-                # regression is explainable from the BENCH JSON alone —
-                # wait share says "barrier", straggler says "one slow
-                # shard", neither says "recompute the whole round"
-                out["multichip.collective_wait_share"] = \
-                    out["multichip"].get("collective_wait_share")
-                out["multichip.straggler_ratio"] = \
-                    out["multichip"].get("straggler_ratio")
-                log(f"multichip: eff_8="
-                    f"{out['multichip'].get('scaling_efficiency_8')} "
-                    f"verdict={out['multichip'].get('verdict')} "
-                    f"straggler={out['multichip.straggler_ratio']} "
-                    f"wait_share={out['multichip.collective_wait_share']}")
-            else:
-                log(f"multichip round failed rc={r.returncode}: "
-                    f"{r.stderr[-500:]}")
-        except Exception as e:  # must never sink the headline run
-            log(f"multichip round FAILED to run: {e!r}")
     # per-round telemetry (ISSUE 4): compile count and transfer volume
     # regressions are now tracked in BENCH_*.json, not just wall time.
     # warm_train.compiles is the headline — the zero-recompile contract.

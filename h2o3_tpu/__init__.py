@@ -15,21 +15,6 @@ K/V store of columnar frame chunks, computed over with MRTask map/reduce
 Public surface mirrors the h2o python client (reference h2o-py/h2o/h2o.py).
 """
 
-import jax as _jax
-
-# ``jax.shard_map`` is only public on newer jax; older jaxlib builds (e.g.
-# 0.4.37) still keep it under jax.experimental. Alias it once here so every
-# call site works on both (this package is always imported before use).
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _shard_map_compat(*args, **kwargs):
-        if "check_vma" in kwargs:  # newer spelling of check_rep
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.frame.vec import Vec
 from h2o3_tpu.ingest.parse import import_file, parse_setup, upload_numpy

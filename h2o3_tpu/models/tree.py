@@ -188,16 +188,6 @@ def _find_splits(trip, cfg: TreeConfig, col_mask, mono=None,
             wl_s, wr_sel)
 
 
-def _axis_size(axis_name) -> int:
-    """Static mesh-axis size inside shard_map, across jax versions
-    (jax.lax.axis_size is missing on 0.4.x; jax.core.axis_frame returns
-    the bare size there and a frame object on newer builds)."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    frame = jax.core.axis_frame(axis_name)
-    return int(frame if isinstance(frame, int) else frame.size)
-
-
 def _find_splits_sharded(trip, cfg: TreeConfig, col_mask, mono=None,
                          model_axis=None, max_bin=None):
     """Split search sharded over the mesh 'model' axis: each model shard
@@ -215,7 +205,7 @@ def _find_splits_sharded(trip, cfg: TreeConfig, col_mask, mono=None,
     if model_axis is None:
         return _find_splits(trip, cfg, col_mask, mono=mono,
                             max_bin=max_bin)
-    n_model = _axis_size(model_axis)
+    n_model = jax.lax.axis_size(model_axis)
     if n_model == 1:
         return _find_splits(trip, cfg, col_mask, mono=mono,
                             max_bin=max_bin)
@@ -1100,7 +1090,7 @@ def grow_tree_spmd(codes, g, h, w, cfg: TreeConfig, col_mask,
     B1 = cfg.n_bins + 1
     rows, F_loc = codes.shape
     midx = jax.lax.axis_index(model_axis)
-    n_model = _axis_size(model_axis)
+    n_model = jax.lax.axis_size(model_axis)
 
     feat = jnp.full(M, -1, jnp.int32)
     split_bin = jnp.zeros(M, jnp.int32)

@@ -160,8 +160,10 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
     an all-gather, so every chip would need to hold the FULL [padded, F]
     matrix (plus its sorted copy) — a frame sized for the aggregate HBM
     of a data-sharded mesh would OOM. On any multi-shard accelerator
-    mesh this falls back to the host-side sketch (device_get +
-    np.quantile, the pre-device-sketch behavior; identical edges); the
+    mesh the EDGES fall back to the host-side sketch (device_get +
+    np.quantile, the pre-device-sketch behavior; identical edges),
+    while the digitise still runs on the sharded device matrix — a host
+    copy handed to it would land whole on the first chip; the
     CPU test mesh's virtual shards share one host RAM, so it keeps the
     device path. A per-shard sketch merged with a psum would scale but
     is not bit-exact — the future lever."""
@@ -170,10 +172,11 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
     from h2o3_tpu.parallel.mesh import current_mesh, n_data_shards
     if (_jax.default_backend() != "cpu"
             and n_data_shards(current_mesh()) > 1):
-        return bin_matrix(np.asarray(telemetry.device_get(
-            X, pipeline="train")), names, is_cat,
-            nrow, nbins=nbins, nbins_cats=nbins_cats,
-            histogram_type=histogram_type, with_t=with_t)
+        return bin_matrix(X, names, is_cat, nrow, nbins=nbins,
+                          nbins_cats=nbins_cats,
+                          histogram_type=histogram_type, with_t=with_t,
+                          X_host=np.asarray(telemetry.device_get(
+                              X, pipeline="train"), np.float32))
     F = X.shape[1]
     Xs, nfin_d, fmin_d, fmax_d = _sketch_stats(X, jnp.int32(nrow))
     # ONE counted fetch of the O(F) sketch stats (transfer-seam)
@@ -252,8 +255,11 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
 def bin_matrix(X, names: Sequence[str], is_cat: Sequence[bool], nrow: int,
                nbins: int = 255, nbins_cats: int = 1024,
                histogram_type: str = "quantiles_global",
-               with_t: bool = True) -> BinnedMatrix:
+               with_t: bool = True, X_host=None) -> BinnedMatrix:
     """Digitise a dense [padded_rows, F] float matrix (NaN = NA) into codes.
+    The edges come from a host copy (``X_host`` when the caller already
+    holds one); the digitise runs on ``X`` where it lives, so a sharded
+    device matrix is digitised shard by shard.
 
     Categorical columns with cardinality <= nbins_cats use identity binning
     (code = category id) — group-per-category splits, the reference's
@@ -263,7 +269,8 @@ def bin_matrix(X, names: Sequence[str], is_cat: Sequence[bool], nrow: int,
     numeric features simply leave the extra bins empty). Cardinalities
     beyond nbins_cats fall back to quantile grouping of the code space.
     """
-    X_host = np.asarray(X, dtype=np.float32)
+    if X_host is None:
+        X_host = np.asarray(X, dtype=np.float32)
     edges, n_bins_eff = _edges_host(X_host, nrow, is_cat, nbins,
                                     nbins_cats, histogram_type)
     codes = make_codes_view(digitize_with_edges(X, edges, n_bins_eff),

@@ -57,8 +57,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from h2o3_tpu.ops.pallas_compat import CompilerParams as _CompilerParams
-
 import os as _os
 
 TILE = int(_os.environ.get("H2O3_HIST_TILE", 8192))
@@ -241,7 +239,7 @@ def adaptive_level_tpu(x, nid, ghw, tables, lo, inv, n_prev: int,
         cost_estimate=pl.CostEstimate(
             flops=2 * 3 * n_nodes * F * W * rows,
             bytes_accessed=rows * F * 4 + rows * 16, transcendentals=0),
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(x, nid[None, :], ghw, tabs, loinv)
     return nid2[0], hist.reshape(3, n_nodes, F, W)
@@ -428,7 +426,7 @@ def leaf_totals_tpu(x, nid, ghw, tables, n_prev: int, n_nodes: int,
             jax.ShapeDtypeStruct((3 * n_nodes, 128), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((3 * n_nodes, 128), jnp.float32)],
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(x, nid[None, :], ghw, tabs)
     return nid2[0], tot[:, 0].reshape(3, n_nodes)
@@ -600,7 +598,7 @@ def adaptive_level_tpu_i8(xt, nid, q, scales, tables, lo, inv, n_prev: int,
         ],
         scratch_shapes=[pltpu.VMEM((3 * terms * n_nodes, F * W),
                                    jnp.int32)],
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(xt, nid[None, :], q, scales[None, :], tabs, loinv)
     return nid2[0], hist.reshape(3, n_nodes, F, W)
@@ -746,7 +744,7 @@ def adaptive_level_tpu_t(xt, nid, ghw, tables, lo, inv, n_prev: int,
         cost_estimate=pl.CostEstimate(
             flops=2 * 3 * n_nodes * F * W * rows,
             bytes_accessed=rows * F * 4 + rows * 16, transcendentals=0),
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(xt, nid[None, :], ghw, tabs, loinv)
     return nid2[0], hist.reshape(3, n_nodes, F, W)
@@ -778,7 +776,7 @@ def route_only_tpu_t(xt, nid, tables, n_prev: int, level_base: int,
         ],
         out_specs=pl.BlockSpec((1, tile), lambda r: (0, r)),
         out_shape=jax.ShapeDtypeStruct((1, rows), jnp.int32),
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(xt, nid[None, :], tabs)
     return nid2[0]
@@ -814,7 +812,7 @@ def route_only_tpu(x, nid, tables, n_prev: int, level_base: int,
         ],
         out_specs=pl.BlockSpec((1, tile), lambda r: (0, r)),
         out_shape=jax.ShapeDtypeStruct((1, rows), jnp.int32),
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(x, nid[None, :], tabs)
     return nid2[0]
@@ -1005,7 +1003,7 @@ def binned_level_tpu_t(ct, nid, ghw, tables, n_prev: int, n_nodes: int,
             flops=2 * 3 * n_nodes * F * W * rows,
             bytes_accessed=rows * F * itemsize + rows * 16,
             transcendentals=0),
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(ct, nid[None, :], ghw, tabs)
     return nid2[0], hist.reshape(3, n_nodes, F, W)
@@ -1110,7 +1108,7 @@ def binned_level_tpu_stripe(ct, nid, ghw, tables, n_prev: int,
             flops=2 * 3 * n_nodes * 2 * F2 * W * rows,
             bytes_accessed=rows * 2 * F2 * itemsize + rows * 16,
             transcendentals=0),
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(ct, nid[None, :], ghw, tabs)
     return nid2[0], hist.reshape(3, n_nodes, 2 * F2, W)[:, :, :F, :]
@@ -1248,7 +1246,7 @@ def binned_level_tpu_i8(ct, nid, q, scales, tables, n_prev: int,
             bytes_accessed=(rows * F * jnp.dtype(ct.dtype).itemsize
                             + rows * (4 + 3 * terms)),
             transcendentals=0),
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(ct, nid[None, :], q, scales[None, :], tabs)
     return nid2[0], hist.reshape(3, n_nodes, F, W)
@@ -1313,7 +1311,7 @@ def binned_route_only_tpu_t(ct, nid, tables, n_prev: int, level_base: int,
         ],
         out_specs=pl.BlockSpec((1, tile), lambda r: (0, r)),
         out_shape=jax.ShapeDtypeStruct((1, rows), jnp.int32),
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(ct, nid[None, :], tabs)
     return nid2[0]

@@ -672,3 +672,30 @@ def test_enum_stream_cardinality_blowout_falls_back(tmp_path, monkeypatch):
     fr_par = parse([str(p)], setup)
     assert parse_mod.LAST_PROFILE["chunks"] > 1
     _frames_equal(fr_serial, fr_par)
+
+
+def test_native_build_is_stamped_for_this_source_and_this_cpu(
+        tmp_path, monkeypatch):
+    """The tokenizer is built with -march=native and is not in git: its
+    stamp covers the source AND the host CPU's flags, so a tree copied to
+    another machine rebuilds instead of dying of SIGILL; a .so with no
+    stamp, or an older source-only one, is stale."""
+    import hashlib
+    import shutil
+
+    from h2o3_tpu import native
+    if native.lib() is None:
+        pytest.skip("native tokenizer unavailable in this image")
+    so, stamp = tmp_path / "libfastcsv.so", tmp_path / "libfastcsv.so.srchash"
+    shutil.copy(native._SO, so)
+    monkeypatch.setattr(native, "_SO", str(so))
+    monkeypatch.setattr(native, "_HASH", str(stamp))
+    assert native._stale()                              # no stamp at all
+    with open(native._SRC, "rb") as f:
+        stamp.write_text(hashlib.sha256(f.read()).hexdigest())
+    assert native._stale()                              # source-only stamp
+    stamp.write_text(native._build_stamp())
+    assert not native._stale()
+    assert native._build() and not native._stale()      # rebuild re-stamps
+    assert sorted(p.name for p in tmp_path.iterdir()) == [
+        "libfastcsv.so", "libfastcsv.so.srchash"]       # no torn temporaries

@@ -93,3 +93,32 @@ def test_streaming_unsupported_algo_fails_fast():
     drf = H2ORandomForestEstimator(ntrees=2, max_depth=3)
     with pytest.raises(RuntimeError, match="streaming"):
         drf.train(y="resp", training_frame=fr)
+
+
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform, self.device_kind, self._stats = platform, "TPU vX", stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("tpu", {"bytes_limit": 16 << 30}, 16 << 30),
+    ("tpu", None, RuntimeError),          # a chip with no limit: refuse
+    ("tpu", {"bytes_in_use": 1}, RuntimeError),
+    ("cpu", None, 1 << 62),               # the CPU backend reports none
+])
+def test_default_budget_is_the_device_limit_or_an_error(
+        monkeypatch, platform, stats, want):
+    """An unlimited budget is the CPU backend's alone: on a TPU a missing
+    bytes_limit raises instead of making every admission a guess."""
+    import jax
+    monkeypatch.delenv("H2O3_DEVICE_BUDGET_BYTES", raising=False)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice(platform, stats)])
+    if isinstance(want, int):
+        assert memman._default_budget() == want
+    else:
+        with pytest.raises(want, match="bytes_limit"):
+            memman._default_budget()

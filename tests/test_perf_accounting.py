@@ -160,6 +160,31 @@ def test_peak_provenance_is_honest(monkeypatch):
     assert costmodel.device_peaks()["flops_source"] != "override"
 
 
+@pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),
+                                        ("TPU v9 imaginary", False)])
+def test_unknown_tpu_kind_is_an_error_not_a_nominal_peak(monkeypatch, kind,
+                                                         known):
+    """Made-up peaks are the CPU backend's alone: a TPU whose kind has no
+    table row raises, unless both peaks are overridden."""
+    import jax
+
+    class Dev:
+        platform, device_kind = "tpu", kind
+    monkeypatch.delenv("H2O3_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("H2O3_PEAK_BYTES_PER_S", raising=False)
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    if known:
+        peaks = costmodel.device_peaks()
+        assert peaks["flops_source"] == peaks["bytes_source"] == "table"
+        assert peaks["informational"] is False
+        return
+    with pytest.raises(LookupError, match="TPU v9 imaginary"):
+        costmodel.device_peaks()
+    monkeypatch.setenv("H2O3_PEAK_FLOPS", "1e15")
+    monkeypatch.setenv("H2O3_PEAK_BYTES_PER_S", "1e12")
+    assert costmodel.device_peaks()["peak_source"] == "override"
+
+
 # ----------------------------------------------------- train wiring
 
 def test_gbm_perf_output_and_warm_cost_identity(monkeypatch):

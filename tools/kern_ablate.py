@@ -22,7 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from h2o3_tpu.ops.pallas_compat import CompilerParams as _CompilerParams
 
 ROWS = 10_002_432
 F, W, N = 28, 32, 32
@@ -149,7 +148,7 @@ def run(ablate, X, nid0, ghw, tabs, loinv):
                 flops=2 * 3 * N * F * W * X.shape[0],
                 bytes_accessed=X.shape[0] * F * 4 + X.shape[0] * 16,
                 transcendentals=0) if os.environ.get("COST") else None),
-            compiler_params=_CompilerParams(vmem_limit_bytes=_VM),
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VM),
         )(X, nid[None, :], ghw, tabs, loinv)
         return nid2[0], hist
 
@@ -248,7 +247,7 @@ def run_levels(L, ablate, ct, nid0, ghw, tabs, tile, interp):
                 jax.ShapeDtypeStruct((3 * LN, LF * LW), jnp.float32),
             ],
             scratch_shapes=[pltpu.VMEM((3 * LN, LF * LW), jnp.float32)],
-            compiler_params=_CompilerParams(vmem_limit_bytes=_VM),
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VM),
             interpret=interp,
         )(ct, nid[None, :], ghw, tabs)
         return nid2[0], hist

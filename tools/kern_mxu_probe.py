@@ -17,7 +17,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from h2o3_tpu.ops.pallas_compat import CompilerParams as _CompilerParams
 
 ROWS = int(os.environ.get("ROWS", 2_500_608))
 TILE = int(os.environ.get("TILE", 8192))
@@ -52,7 +51,7 @@ def run(M):
         out_specs=pl.BlockSpec((M, FW), lambda r: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((M, FW), jnp.float32),
         scratch_shapes=[pltpu.VMEM((M, FW), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 2 ** 20),
         interpret=os.environ.get("H2O3_PALLAS_INTERPRET", "") == "1",
     )

@@ -12,7 +12,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from h2o3_tpu.ops.pallas_compat import CompilerParams as _CompilerParams
 
 ROWS = 10_002_432
 F, W = 28, 32
@@ -146,7 +145,7 @@ def level(x, nid, ghw, tables, lo, inv, n_prev, n_nodes, level_base, W,
             jax.ShapeDtypeStruct((3 * n_nodes, F * W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((3 * n_nodes, F * W), jnp.float32)],
-        compiler_params=_CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
     )(x, nid[None, :], ghw, tabs, loinv)
     return nid2[0], hist
 

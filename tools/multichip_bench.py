@@ -7,15 +7,18 @@ frame rebuilt under its mesh (Frame.resharded), and the verdict compares
 rows/s/chip at 8 devices against the single-device number
 (``scaling_efficiency_8 >= 0.7`` is the acceptance bar).
 
-On a host without 8 accelerator devices the tool forces 8 VIRTUAL CPU
-devices (``--xla_force_host_platform_device_count=8``) so the sharded
-code path still runs end-to-end — but virtual devices share one host's
-cores, so aggregate throughput physically cannot scale; the verdict is
-then reported as ``informational`` (basis=cpu-virtual-devices) rather
-than a fake pass/fail. On a real TPU mesh the verdict is enforced.
+The tool runs over the devices JAX sees and forces none: device counts
+the host does not have are skipped. To walk the sharded code path
+without a chip, give it virtual CPU devices yourself
+(``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8``)
+— they share one host's cores, so aggregate throughput physically cannot
+scale, and the verdict is then ``informational``
+(basis=cpu-virtual-devices) rather than a fake pass/fail. On a real TPU
+mesh the verdict is enforced.
 
-Runs standalone (``python tools/multichip_bench.py``) or as the
-``multichip`` round bench.py spawns. Prints ONE JSON line on stdout.
+Runs standalone (``python tools/multichip_bench.py``), in its own
+process: a chip belongs to one process at a time, so nothing that has
+touched JAX may spawn it. Prints ONE JSON line on stdout.
 
 Env knobs: H2O3_MC_ROWS (default 1M TPU / 120k CPU), H2O3_MC_TREES (10),
 H2O3_MC_DEPTH (6), H2O3_MC_NBINS (14), H2O3_MC_MIN_EFF (0.7).
@@ -26,14 +29,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# force the virtual 8-device CPU mesh BEFORE jax import when the host
-# has no accelerator fleet (the parent bench may run single-chip)
-if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu") and \
-        "xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
 
 import numpy as np
 
