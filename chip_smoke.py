@@ -31,7 +31,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import shutil
 import sys
 import tempfile
 import time
@@ -42,6 +41,7 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, ROOT)
 
 FEATURES = 28                      # HIGGS feature count — never cut
+ROWS = 10_000_000                  # the repo's headline shape; --rows cuts it
 GBM = dict(max_depth=6, nbins=14, learn_rate=0.1, distribution="bernoulli",
            seed=7, min_rows=1.0, score_tree_interval=0, stopping_rounds=0,
            histogram_type="quantiles_global", packed_codes="auto")
@@ -107,10 +107,11 @@ def delta(a: dict, b: dict) -> dict:
     return {k: int(b[k] - a[k]) for k in a}
 
 
-def peak_bytes():
-    """Device 0's peak bytes in use so far (None where unreported)."""
+def peak_bytes(index: int = 0):
+    """One device's peak bytes in use so far (None where unreported)."""
     import jax
-    return (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+    return (jax.devices()[index].memory_stats() or {}).get(
+        "peak_bytes_in_use")
 
 
 def on_platform(arr, platform: str) -> bool:
@@ -135,17 +136,18 @@ def chunk_hlo(builder) -> str:
 # ------------------------------------------------------------------ phases
 
 
-def phase_device(args):
+def phase_device(args, device: dict) -> bool:
+    """Fill ``device`` as JAX reports it; False when there is no
+    accelerator to run on (and this is no rehearsal)."""
     import jax
     import jaxlib
     from importlib import metadata
     dev = jax.devices()[0]
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": args.chips}
+    device.update(platform=dev.platform, kind=dev.device_kind)
     if dev.platform != "tpu" and not args.rehearse:
         emit("device", ok=False, error="no accelerator: jax.devices()[0]."
              f"platform is '{dev.platform}'", **device)
-        return device, False
+        return False
     check(len(jax.devices()) >= args.chips,
           f"--chips {args.chips} needs {args.chips} devices, JAX sees "
           f"{len(jax.devices())}")
@@ -162,9 +164,9 @@ def phase_device(args):
     except metadata.PackageNotFoundError:
         libtpu = None
     if dev.platform == "tpu":
-        check(stats.get("bytes_limit"), "memory_stats() has no bytes_limit")
-        check(budget == stats["bytes_limit"],
-              f"memman budget {budget} != bytes_limit {stats['bytes_limit']}")
+        check(budget == stats.get("bytes_limit"),
+              f"memman budget {budget} != bytes_limit "
+              f"{stats.get('bytes_limit')}")
         check(peaks["flops_source"] == peaks["bytes_source"] == "table"
               and not peaks["informational"],
               f"cost model has no table row for '{dev.device_kind}': {peaks}")
@@ -174,7 +176,7 @@ def phase_device(args):
          peak_source=peaks["peak_source"], jax=jax.__version__,
          jaxlib=jaxlib.__version__, libtpu=libtpu, compile_cache=cache_dir,
          rehearse=args.rehearse)
-    return device, True
+    return True
 
 
 def phase_ingest(args, platform, workdir):
@@ -240,7 +242,8 @@ def check_packed_pallas_train(model, hlo: str) -> dict:
     check(pc.get("enabled") is True, f"packed codes not enabled: {pc}")
     check(not out.get("streamed"), "train went through the streamed path")
     for name in ("h2o3_degrade_total", "h2o3_retry_total"):
-        check(counter_total(name) == 0, f"{name} = {counter_total(name)}")
+        fired = counter_total(name)
+        check(fired == 0, f"{name} = {fired}")
     kernel = ha.binned_level_kernel(pc["W"], FEATURES)
     n_custom = hlo.count('custom_call_target="tpu_custom_call"')
     if not ha.pallas_interpret():
@@ -268,8 +271,7 @@ def phase_train(args, X, y):
           f"second identical train compiled {warm_c['compiles']} programs")
     facts = check_packed_pallas_train(model, chunk_hlo(gbm))
     emit("train", ok=True, rows=fr.nrow, features=FEATURES, trees=TREES,
-         rows_cut_from=(args.rows_default if args.rows < args.rows_default
-                        else None),
+         rows_cut_from=ROWS if args.rows < ROWS else None,
          frame_s=round(frame_s, 2), cold_train_s=round(cold_s, 2),
          warm_train_s=round(warm_s, 2), cold=cold_c, warm=warm_c,
          warm_train_profile=again.model.output.get("train_profile"),
@@ -411,7 +413,6 @@ def phase_multichip(args, X, y):
     """The train phase on n_data=4, held against the same train on a
     one-device mesh of this process. Every check is gathered into one
     verdict: a four-chip run is too dear to show one fault at a time."""
-    import jax
     import numpy as np
     import h2o3_tpu as h2o
     failures = []
@@ -426,8 +427,7 @@ def phase_multichip(args, X, y):
     gbm, cold_s, cold_c = train_once(fr, TREES)
     model = gbm.model
     # a stage that runs on the first chip alone shows as a lopsided peak
-    peak = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-            for d in jax.devices()[:args.chips]]
+    peak = [peak_bytes(i) for i in range(args.chips)]
     if all(peak) and max(peak) / min(peak) >= 2.0:
         failures.append(f"device memory peaks are lopsided: {peak}")
     hlo = chunk_hlo(gbm)
@@ -470,20 +470,15 @@ def phase_multichip(args, X, y):
 def run(args, device: dict) -> bool:
     """Every phase in order. Returns False when no accelerator is found
     (nothing ran); raises when a phase fails."""
-    found, usable = phase_device(args)
-    device.update(found)
-    if not usable:
+    if not phase_device(args, device):
         return False
     platform = device["platform"]
     X, y = make_arrays(args.rows, args.seed)
     if args.chips > 1:
         phase_multichip(args, X, y)
         return True
-    workdir = tempfile.mkdtemp(prefix="chip_smoke_")
-    try:
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
         phase_ingest(args, platform, workdir)
-    finally:
-        shutil.rmtree(workdir, ignore_errors=True)
     fr, model = phase_train(args, X, y)
     phase_reference(X, y)
     phase_predict(args, platform, fr, model, X)
@@ -493,7 +488,7 @@ def run(args, device: dict) -> bool:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--rows", type=int, default=10_000_000,
+    ap.add_argument("--rows", type=int, default=ROWS,
                     help="training rows (the width, depth and bins are fixed)")
     ap.add_argument("--csv-rows", type=int, default=1_000_000,
                     help="rows of the CSV the ingest phase writes and parses")
@@ -504,7 +499,6 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU + interpreted kernels; never prints ok:true")
     args = ap.parse_args(argv)
-    args.rows_default = ap.get_default("rows")
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ.setdefault("H2O3_PALLAS_INTERPRET", "1")

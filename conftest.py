@@ -24,11 +24,11 @@ if os.environ.get("H2O3_TPU_TEST_PLATFORM", "cpu") == "cpu":
     # process ("only 7 of them arrived on time") — observed
     # intermittently on the wide sharded tests. The stall resolves;
     # give it room instead of dying (jaxlib 0.9.0 knows both flags).
-    _flags = (os.environ.get("XLA_FLAGS", "")
-              + " --xla_force_host_platform_device_count=8"
-              " --xla_cpu_collective_call_warn_stuck_timeout_seconds=120"
-              " --xla_cpu_collective_call_terminate_timeout_seconds=900")
-    os.environ["XLA_FLAGS"] = _flags
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=8"
+        " --xla_cpu_collective_call_warn_stuck_timeout_seconds=120"
+        " --xla_cpu_collective_call_terminate_timeout_seconds=900")
     import jax
 
     jax.config.update("jax_platforms", "cpu")

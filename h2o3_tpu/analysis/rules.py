@@ -709,7 +709,8 @@ class PallasGridSpecRule(Rule):
     and block mapping explicitly so the tiling is a reviewed decision,
     not a default. A ``grid_spec=`` kwarg carries both and satisfies
     the rule; ``**kwargs`` forwarding is assumed to carry them (call
-    wrappers must not be flagged for forwarding). ``interpret=True`` as a LITERAL pins the interpreter
+    wrappers must not be flagged for forwarding). ``interpret=True`` as
+    a LITERAL pins the interpreter
     into production code — the repo's convention is an ``interpret=``
     parameter threaded from ``pallas_interpret()`` (env-gated) so TPU
     runs compile; tests/ may pin it (CPU CI has no Mosaic).
