@@ -1,0 +1,1 @@
+"""Found by file name (harness.loader.plugin); one file per entry."""
