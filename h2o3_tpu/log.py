@@ -95,11 +95,13 @@ class Profile:
     repeat; durations accumulate. Not thread-safe by design — one Profile
     per training driver, like one MRProfile per MRTask.
 
-    Telemetry: every phase also lands as a ``{prefix}{name}`` span in
-    h2o3_tpu.telemetry (same clock, one measurement) so the stage split
-    that travels with the model and the one /metrics exports are the
-    same numbers. ``parent_span`` is the training driver's root span —
-    set by ModelBuilder.train and handed across the job thread."""
+    Telemetry: every phase IS a ``{prefix}{name}`` span of
+    h2o3_tpu.telemetry, and the phase's seconds are that span's duration
+    (the phase's own clock only where telemetry is off), so the stage
+    split that travels with the model and the one /metrics exports are
+    the same numbers. ``parent_span`` is the training driver's root span
+    — set by ModelBuilder.train and handed across the job thread; with
+    none, a phase nests under the calling thread's current span."""
 
     def __init__(self, prefix: str = "train.", parent_span=None):
         self.phases: Dict[str, float] = {}
@@ -109,22 +111,22 @@ class Profile:
 
     @contextmanager
     def phase(self, name: str):
+        """Yields the phase's span (None where telemetry is off), for
+        attributes known only at the end of the phase."""
         from h2o3_tpu import telemetry
         t0 = time.perf_counter()
-        # enter a REAL span (thread-local) so nested stage spans inside
-        # the phase (gbm's bin/loop/score/finalize) parent implicitly
-        cm = telemetry.span(self.prefix + name, parent=self.parent_span)
-        cm.__enter__()
+        sp = None
         try:
-            yield
-        except BaseException as e:
-            # hand the exception to the span exit so the failed stage
-            # is noted (jobs.py reads it for /3/Jobs failed_stage)
-            cm.__exit__(type(e), e, e.__traceback__)
-            self._accumulate(name, time.perf_counter() - t0)
-            raise
-        cm.__exit__(None, None, None)
-        self._accumulate(name, time.perf_counter() - t0)
+            # a REAL span (thread-local) so nested stage spans inside the
+            # phase (gbm's bin/loop/score/finalize) parent implicitly; an
+            # exception passes through its exit, which notes the failed
+            # stage (jobs.py reads it for /3/Jobs failed_stage)
+            with telemetry.span(self.prefix + name,
+                                parent=self.parent_span) as sp:
+                yield sp
+        finally:
+            self._accumulate(name, sp.duration_s if sp is not None
+                             else time.perf_counter() - t0)
 
     def _accumulate(self, name: str, dt: float):
         if name not in self.phases:

@@ -23,6 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from h2o3_tpu import telemetry
 from h2o3_tpu.jobs import Job
+from h2o3_tpu.log import Profile
 from h2o3_tpu.models.distributions import get_distribution
 from h2o3_tpu.models.model_base import (Model, ModelBuilder, ScoreKeeper,
                                         TrainingSpec, compute_metrics)
@@ -243,6 +244,7 @@ class GBMModel(TreeScoringOptionsMixin, Model):
         return m
 
 
+@jax.named_scope("gbm.chunk")     # a stable name in the ops' metadata
 def _gbm_chunk_body(codes_rm, codes_t, margin, y, w, vrm, vmargin, base_key,
                     lr0, hdelta, root_lo, root_hi, nb_f, mono, sets,
                     start_idx, n_active, sample_rate, col_rate, anneal,
@@ -479,98 +481,102 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         K = spec.nclasses if spec.nclasses > 2 else 1
         task = ("binomial" if spec.nclasses == 2
                 else "multinomial" if K > 1 else "regression")
-        nbins = int(p["nbins"])
-        hist_type = (p.get("histogram_type") or "uniform_adaptive").lower()
-        t_bin0 = time.time()           # span wall anchor
-        t_bin0_m = time.monotonic()    # duration clock (NTP-immune)
-        # packed binned-code hot path (ISSUE 12): bin ONCE per train
-        # into int8/int16 codes and run the fused binned level kernel —
-        # the default wherever compiled pallas runs. histogram_type=
-        # 'random' keeps the adaptive kernel (per-tree grid phase needs
-        # per-level rebinning, which packing removes by design).
-        packed_req = packed_codes_requested(p) and hist_type != "random"
-        if (packed_req
-                and not binned_feasible(
-                    packed_bins_upper_bound(spec, p), spec.n_features,
-                    int(p["max_depth"]))
-                and hist_type in ADAPTIVE_HIST_TYPES
-                and adaptive_feasible(spec, p, int(p["max_depth"]))):
-            # cheap pre-gate from the cat domains alone: packing CANNOT
-            # come in under its lane/VMEM caps, so take the adaptive
-            # kernel without paying the O(rows*F) sketch + digitise
-            packed_req = False
-        # uniform_adaptive (reference default) runs the fused per-node
-        # adaptive kernel on raw features; the global-sketch path handles
-        # quantiles_global and nbins beyond the adaptive kernel's 254 cap
-        adaptive = (hist_type in ADAPTIVE_HIST_TYPES + ("random",)
-                    and not packed_req
-                    and adaptive_feasible(spec, p, int(p["max_depth"])))
-        packed = False
-        pc = None
-        if adaptive:
-            bm = None
-            cfg, root_lo, root_hi, nb_f = adaptive_setup(
-                spec, p, int(p["max_depth"]))
-        else:
-            # device-side sketch: X never leaves HBM (the old path
-            # device_get the whole matrix just to run np.quantile on it)
-            # packed mode skips the int32 transposed pallas operand
-            # (with_t): pack_codes supersedes it with the int8/int16
-            # layouts, and building a rows*F*4 copy just to drop it
-            # would cost the HBM the packing saves
-            bm = bin_matrix_device(spec.X, spec.names,
-                                   spec.is_cat, spec.nrow, nbins=max(nbins, 2),
-                                   nbins_cats=int(p["nbins_cats"]),
-                                   histogram_type=hist_type,
-                                   with_t=not packed_req)
-            packed = (packed_req
-                      and binned_feasible(bm.n_bins, bm.n_features,
-                                          int(p["max_depth"])))
-            if (not packed and packed_req
+        # the stages' clock: each phase is a live span under the thread's
+        # train.train span, and train_profile is their durations
+        prof = Profile()
+        with prof.phase("bin"):
+            nbins = int(p["nbins"])
+            hist_type = (p.get("histogram_type") or "uniform_adaptive").lower()
+            # packed binned-code hot path (ISSUE 12): bin ONCE per train
+            # into int8/int16 codes and run the fused binned level kernel —
+            # the default wherever compiled pallas runs. histogram_type=
+            # 'random' keeps the adaptive kernel (per-tree grid phase needs
+            # per-level rebinning, which packing removes by design).
+            packed_req = packed_codes_requested(p) and hist_type != "random"
+            if (packed_req
+                    and not binned_feasible(
+                        packed_bins_upper_bound(spec, p), spec.n_features,
+                        int(p["max_depth"]))
                     and hist_type in ADAPTIVE_HIST_TYPES
                     and adaptive_feasible(spec, p, int(p["max_depth"]))):
-                # packing infeasible (sketch bin count past the 254-lane
-                # cap / VMEM): fall back to the fused ADAPTIVE kernel,
-                # not the slow matmul path the sketch would otherwise
-                # route to
-                adaptive = True
+                # cheap pre-gate from the cat domains alone: packing CANNOT
+                # come in under its lane/VMEM caps, so take the adaptive
+                # kernel without paying the O(rows*F) sketch + digitise
+                packed_req = False
+            # uniform_adaptive (reference default) runs the fused per-node
+            # adaptive kernel on raw features; the global-sketch path handles
+            # quantiles_global and nbins beyond the adaptive kernel's 254 cap
+            adaptive = (hist_type in ADAPTIVE_HIST_TYPES + ("random",)
+                        and not packed_req
+                        and adaptive_feasible(spec, p, int(p["max_depth"])))
+            packed = False
+            pc = None
+            if adaptive:
                 bm = None
                 cfg, root_lo, root_hi, nb_f = adaptive_setup(
                     spec, p, int(p["max_depth"]))
-            if packed:
-                pc = pack_codes(bm)
-                # free the int32 code view: the packed layouts replace
-                # it (1-2 bytes/value x2 <= half the f32 X footprint);
-                # only bm.edges / n_bins are read from here on
-                bm.codes = CodesView(rm=pc.rm, t=None)
-            if not adaptive:
-                cfg = TreeConfig(max_depth=int(p["max_depth"]),
-                                 n_bins=bm.n_bins,
-                                 n_features=bm.n_features,
-                                 min_rows=float(p["min_rows"]),
-                                 min_split_improvement=float(p["min_split_improvement"]),
-                                 reg_lambda=float(p.get("reg_lambda", 0.0)),
-                                 reg_alpha=float(p.get("reg_alpha", 0.0)),
-                                 col_rate_change=float(
-                                     p.get("col_sample_rate_change_per_level",
-                                           1.0) or 1.0),
-                                 hist_method=p.get("hist_kernel", "auto"),
-                                 histogram_precision=str(
-                                     p.get("histogram_precision",
-                                           "auto")).lower())
-                root_lo = jnp.zeros(cfg.n_features, jnp.float32)
-                root_hi = jnp.zeros(cfg.n_features, jnp.float32)
-                nb_f = jnp.zeros(cfg.n_features, jnp.float32)
-        # the sketch and digitise above are dispatched, not done: wait for
-        # them here so bin_s carries them. The loop-entry fence below
-        # absorbed them otherwise, in no span at all (about 11 s of a
-        # 13.5 s warm train at 10M x 28 on the v5e, PR 22)
-        jax.block_until_ready(  # h2o3-lint: allow[transfer-seam] bin-stage timing fence: replaces time the loop-entry fence already waited, unattributed
-            (root_lo, root_hi) if adaptive else pc if packed else bm.codes)
-        t_bin = time.monotonic() - t_bin0_m
-        # same clocks feed train_profile AND the spans (parented under
-        # the Profile's train phase span via the thread-local stack)
-        telemetry.record_span("train.bin", t_bin0, t_bin)
+            else:
+                # device-side sketch: X never leaves HBM (the old path
+                # device_get the whole matrix just to run np.quantile on it)
+                # packed mode skips the int32 transposed pallas operand
+                # (with_t): pack_codes supersedes it with the int8/int16
+                # layouts, and building a rows*F*4 copy just to drop it
+                # would cost the HBM the packing saves
+                bm = bin_matrix_device(spec.X, spec.names,
+                                       spec.is_cat, spec.nrow, nbins=max(nbins, 2),
+                                       nbins_cats=int(p["nbins_cats"]),
+                                       histogram_type=hist_type,
+                                       with_t=not packed_req, prof=prof)
+                packed = (packed_req
+                          and binned_feasible(bm.n_bins, bm.n_features,
+                                              int(p["max_depth"])))
+                if (not packed and packed_req
+                        and hist_type in ADAPTIVE_HIST_TYPES
+                        and adaptive_feasible(spec, p, int(p["max_depth"]))):
+                    # packing infeasible (sketch bin count past the 254-lane
+                    # cap / VMEM): fall back to the fused ADAPTIVE kernel,
+                    # not the slow matmul path the sketch would otherwise
+                    # route to
+                    adaptive = True
+                    bm = None
+                    cfg, root_lo, root_hi, nb_f = adaptive_setup(
+                        spec, p, int(p["max_depth"]))
+                if packed:
+                    with prof.phase("bin.pack"):
+                        pc = pack_codes(bm)
+                        # free the int32 code view: the packed layouts
+                        # replace it (1-2 bytes/value x2 <= half the f32 X
+                        # footprint); only bm.edges / n_bins are read from
+                        # here on
+                        bm.codes = CodesView(rm=pc.rm, t=None)
+                        # this path's bin fence (see the other paths' below),
+                        # inside the phase whose device work it waits for
+                        jax.block_until_ready(pc)  # h2o3-lint: allow[transfer-seam] bin-stage timing fence: replaces time the loop-entry fence already waited, unattributed
+                if not adaptive:
+                    cfg = TreeConfig(max_depth=int(p["max_depth"]),
+                                     n_bins=bm.n_bins,
+                                     n_features=bm.n_features,
+                                     min_rows=float(p["min_rows"]),
+                                     min_split_improvement=float(p["min_split_improvement"]),
+                                     reg_lambda=float(p.get("reg_lambda", 0.0)),
+                                     reg_alpha=float(p.get("reg_alpha", 0.0)),
+                                     col_rate_change=float(
+                                         p.get("col_sample_rate_change_per_level",
+                                               1.0) or 1.0),
+                                     hist_method=p.get("hist_kernel", "auto"),
+                                     histogram_precision=str(
+                                         p.get("histogram_precision",
+                                               "auto")).lower())
+                    root_lo = jnp.zeros(cfg.n_features, jnp.float32)
+                    root_hi = jnp.zeros(cfg.n_features, jnp.float32)
+                    nb_f = jnp.zeros(cfg.n_features, jnp.float32)
+            # the work above is dispatched, not done: wait for it here so
+            # bin_s carries it. The loop-entry fence absorbed it otherwise,
+            # in no span at all (about 11 s of a 13.5 s warm train at
+            # 10M x 28 on the v5e, PR 22)
+            if not packed:
+                jax.block_until_ready(  # h2o3-lint: allow[transfer-seam] bin-stage timing fence: replaces time the loop-entry fence already waited, unattributed
+                    (root_lo, root_hi) if adaptive else bm.codes)
         y, w = spec.y, spec.w
         padded = spec.X.shape[0]
         if spec.offset is not None and K > 1:
@@ -822,122 +828,162 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                 warn("%s: in-training checkpoint commit failed: %s",
                      self.algo, e)
 
-        t_loop0 = time.time()          # span wall anchor
-        t_loop0_m = time.monotonic()
-        score_s = 0.0
-        # pipelined boosting: dispatch chunk k+1 BEFORE blocking on chunk
-        # k's score scalars, so the metric fetch overlaps device compute.
-        # With early stopping on, chunk k+1 is SPECULATIVE: a stop verdict
-        # discards it (margins roll back to chunk k's outputs), keeping
-        # the built-tree count identical to the serial loop.
-        while disp < ntrees_new and not stopped:
-            c = min(chunk, ntrees_new - disp)
-            if score_each and c == chunk:
-                # full score intervals compile at their EXACT length: an
-                # off-bucket interval (say 6) repeats every chunk, and
-                # rounding it up would pay masked trees on EVERY chunk —
-                # one compile per interval value instead
-                bucket = c
-            else:
-                # single-shot lengths (the non-scoring whole-train chunk,
-                # any final partial interval) round up to a shared bucket
-                # so grid/AutoML ntrees variants reuse the executable;
-                # masked waste is bounded by ONE chunk per train
-                bucket = chunk_bucket(c)
-            # ONE spelling of the executable cache key, shared by the
-            # dispatch and the cost capture below — the two must
-            # describe the SAME executable or the accounting drifts
-            lru_key = (mesh, cfg, K, dist_name,
-                       float(p["tweedie_power"]),
-                       float(p.get("quantile_alpha", 0.5)),
-                       srpc, na_bin, bucket, has_valid, has_t,
-                       adaptive, packed, has_mono, has_sets, donate)
-            def _dispatch(lru_key=lru_key, c=c):
-                # compile + execute behind the fault seam: both the
-                # executable build and the chunk dispatch may fail
-                # transiently (the injected faults reproduce that)
-                from h2o3_tpu import faults
-                if faults.ACTIVE:
-                    faults.check("compile", pipeline="train")
-                step = _compiled_chunk(*lru_key)
-                if faults.ACTIVE:
-                    faults.check("execute", pipeline="train")
-                    if nd > 1:
-                        # ICI collective seam: the per-level histogram
-                        # psum rides inside this dispatch on a multi-
-                        # shard mesh — a transient interconnect failure
-                        # surfaces here and retries like any other
-                        # transient execute error
-                        faults.check("collective", pipeline="train")
-                operands = (
-                    Xtr, codes_t_arg, margin, yf, w, vtrain, vmargin,
-                    key, jnp.float32(lr), huber_delta,
-                    root_lo, root_hi, nb_f, mono_arr, sets_arr,
-                    jnp.int32(start_trees + disp), jnp.int32(c),
-                    rate_t, col_rate_t, anneal_t)
-                # AOT handle on this chunk executable, by shape only: it
-                # pins no device buffer and never reads a margin the
-                # dispatch below has donated. Only a committed operand
-                # keeps its sharding — the scalars and dummies follow
-                # the mesh as they do in the call. The cost capture
-                # lowers through it; chip_smoke.py reads the compiled HLO
-                self.chunk_lowering = partial(step.lower, *(
-                    jax.ShapeDtypeStruct(
-                        a.shape, a.dtype,
-                        sharding=a.sharding if a.committed else None)
-                    for a in operands))
-                return step(*operands)
-            try:
-                # transient device failures retry with backoff; donated
-                # operand buffers cannot be replayed, so donation (TPU,
-                # no early stopping) disables the retry path
-                nm, nv, chunk_trees = retry_transient(
-                    _dispatch, site="train.execute",
-                    attempts=1 if donate else 3)
-                # dispatch is async — this clock starts when the chunk
-                # is enqueued, not when it completes, so THIS chunk's
-                # cold-bucket compile stays out of its own step numbers;
-                # a later chunk's compile delaying the observation is
-                # caught by shardstats' staleness check instead
-                t_disp = time.perf_counter()
-            except BaseException:
-                # commit the already-computed in-flight chunk and leave
-                # a resumable checkpoint before the error propagates —
-                # a mid-train kill then resumes from the committed
-                # prefix instead of tree 0 (`margin` still holds that
-                # chunk's outputs; it is only rebound after dispatch)
+        chunks = 0              # dispatched chunks
+        with prof.phase("loop") as sp_loop:
+            # pipelined boosting: dispatch chunk k+1 BEFORE blocking on chunk
+            # k's score scalars, so the metric fetch overlaps device compute.
+            # With early stopping on, chunk k+1 is SPECULATIVE: a stop verdict
+            # discards it (margins roll back to chunk k's outputs), keeping
+            # the built-tree count identical to the serial loop.
+            while disp < ntrees_new and not stopped:
+                c = min(chunk, ntrees_new - disp)
+                if score_each and c == chunk:
+                    # full score intervals compile at their EXACT length: an
+                    # off-bucket interval (say 6) repeats every chunk, and
+                    # rounding it up would pay masked trees on EVERY chunk —
+                    # one compile per interval value instead
+                    bucket = c
+                else:
+                    # single-shot lengths (the non-scoring whole-train chunk,
+                    # any final partial interval) round up to a shared bucket
+                    # so grid/AutoML ntrees variants reuse the executable;
+                    # masked waste is bounded by ONE chunk per train
+                    bucket = chunk_bucket(c)
+                # ONE spelling of the executable cache key, shared by the
+                # dispatch and the cost capture below — the two must
+                # describe the SAME executable or the accounting drifts
+                lru_key = (mesh, cfg, K, dist_name,
+                           float(p["tweedie_power"]),
+                           float(p.get("quantile_alpha", 0.5)),
+                           srpc, na_bin, bucket, has_valid, has_t,
+                           adaptive, packed, has_mono, has_sets, donate)
+                def _dispatch(lru_key=lru_key, c=c):
+                    # compile + execute behind the fault seam: both the
+                    # executable build and the chunk dispatch may fail
+                    # transiently (the injected faults reproduce that)
+                    from h2o3_tpu import faults
+                    if faults.ACTIVE:
+                        faults.check("compile", pipeline="train")
+                    step = _compiled_chunk(*lru_key)
+                    if faults.ACTIVE:
+                        faults.check("execute", pipeline="train")
+                        if nd > 1:
+                            # ICI collective seam: the per-level histogram
+                            # psum rides inside this dispatch on a multi-
+                            # shard mesh — a transient interconnect failure
+                            # surfaces here and retries like any other
+                            # transient execute error
+                            faults.check("collective", pipeline="train")
+                    operands = (
+                        Xtr, codes_t_arg, margin, yf, w, vtrain, vmargin,
+                        key, jnp.float32(lr), huber_delta,
+                        root_lo, root_hi, nb_f, mono_arr, sets_arr,
+                        jnp.int32(start_trees + disp), jnp.int32(c),
+                        rate_t, col_rate_t, anneal_t)
+                    # AOT handle on this chunk executable, by shape only: it
+                    # pins no device buffer and never reads a margin the
+                    # dispatch below has donated. Only a committed operand
+                    # keeps its sharding — the scalars and dummies follow
+                    # the mesh as they do in the call. The cost capture
+                    # lowers through it; chip_smoke.py reads the compiled HLO
+                    self.chunk_lowering = partial(step.lower, *(
+                        jax.ShapeDtypeStruct(
+                            a.shape, a.dtype,
+                            sharding=a.sharding if a.committed else None)
+                        for a in operands))
+                    return step(*operands)
+                try:
+                    # transient device failures retry with backoff; donated
+                    # operand buffers cannot be replayed, so donation (TPU,
+                    # no early stopping) disables the retry path
+                    nm, nv, chunk_trees = retry_transient(
+                        _dispatch, site="train.execute",
+                        attempts=1 if donate else 3)
+                    # dispatch is async — this clock starts when the chunk
+                    # is enqueued, not when it completes, so THIS chunk's
+                    # cold-bucket compile stays out of its own step numbers;
+                    # a later chunk's compile delaying the observation is
+                    # caught by shardstats' staleness check instead
+                    t_disp = time.perf_counter()
+                except BaseException:
+                    # commit the already-computed in-flight chunk and leave
+                    # a resumable checkpoint before the error propagates —
+                    # a mid-train kill then resumes from the committed
+                    # prefix instead of tree 0 (`margin` still holds that
+                    # chunk's outputs; it is only rebound after dispatch)
+                    if inflight is not None:
+                        all_trees.append((inflight["trees"], inflight["c"]))
+                        built += inflight["c"]
+                        inflight = None
+                        if ckpt_on:
+                            commit_ckpt(margin)
+                    raise
+                if perf_acc is not None:
+                    # per-executable FLOP/byte attribution: ONE trace+lower
+                    # per (config, bucket) key for the process lifetime (NO
+                    # backend compile — the zero-recompile guards never see
+                    # it); warm dispatches pay a dict lookup. scale=bucket:
+                    # HLO cost analysis counts the tree-scan body once, and
+                    # the executable runs it `bucket` times (masked trees
+                    # included — they compute). The capture wall is noted
+                    # so a cold key's trace+lower (host work inside the
+                    # measured loop) is excluded from device seconds.
+                    t_cap0 = time.perf_counter()
+                    perf_acc.add(telemetry.costmodel.executable_cost(
+                        ("gbm.chunk",) + lru_key, self.chunk_lowering,
+                        scale=bucket))
+                    perf_acc.note_capture_seconds(
+                        time.perf_counter() - t_cap0)
+                pend = None
+                if score_each:
+                    pend = self._score_entry_dev(nv if has_valid else nm,
+                                                 sc_spec, dist, K,
+                                                 start_trees + disp + c,
+                                                 want_auc=want_auc)
                 if inflight is not None:
+                    # commit the previous chunk; its metric scalars land
+                    # while the device crunches the chunk just dispatched
                     all_trees.append((inflight["trees"], inflight["c"]))
                     built += inflight["c"]
-                    inflight = None
-                    if ckpt_on:
-                        commit_ckpt(margin)
-                raise
-            if perf_acc is not None:
-                # per-executable FLOP/byte attribution: ONE trace+lower
-                # per (config, bucket) key for the process lifetime (NO
-                # backend compile — the zero-recompile guards never see
-                # it); warm dispatches pay a dict lookup. scale=bucket:
-                # HLO cost analysis counts the tree-scan body once, and
-                # the executable runs it `bucket` times (masked trees
-                # included — they compute). The capture wall is noted
-                # so a cold key's trace+lower (host work inside the
-                # measured loop) is excluded from device seconds.
-                t_cap0 = time.perf_counter()
-                perf_acc.add(telemetry.costmodel.executable_cost(
-                    ("gbm.chunk",) + lru_key, self.chunk_lowering,
-                    scale=bucket))
-                perf_acc.note_capture_seconds(
-                    time.perf_counter() - t_cap0)
-            pend = None
-            if score_each:
-                pend = self._score_entry_dev(nv if has_valid else nm,
-                                             sc_spec, dist, K,
-                                             start_trees + disp + c,
-                                             want_auc=want_auc)
-            if inflight is not None:
-                # commit the previous chunk; its metric scalars land
-                # while the device crunches the chunk just dispatched
+                    trees_since_ckpt += inflight["c"]
+                    if nd > 1 and telemetry.enabled():
+                        shard_obs.append(partn.observe_step(
+                            inflight["trees"], inflight["t_disp"],
+                            algo=self.algo))
+                    if score_each:
+                        with prof.phase("score"):
+                            keeper.record(
+                                self._score_entry_fetch(inflight["pend"]))
+                        if keeper.rounds > 0 and keeper.should_stop():
+                            # discard the speculative dispatch: the margin/
+                            # vmargin locals still hold the COMMITTED chunk's
+                            # outputs (they are only rebound to the new
+                            # dispatch below), so breaking here is the
+                            # rollback — nm/nv are simply never used
+                            stopped = True
+                            break
+                    if ckpt_on and trees_since_ckpt >= ckpt_interval:
+                        commit_ckpt(margin)   # margin = committed chunk's
+                        trees_since_ckpt = 0
+                inflight = {"trees": chunk_trees, "c": c, "pend": pend,
+                            "t_disp": t_disp}
+                margin, vmargin = nm, nv
+                disp += c
+                chunks += 1
+                lr *= anneal ** c
+                # progress by DISPATCHED trees: the committed count lags one
+                # chunk behind and would sit at 0 through a one-chunk train
+                job.set_progress(0.5 * disp / ntrees_new)
+                if job.cancel_requested or job.preempt_requested:
+                    break
+            # checkpoint-based preemption (ISSUE 15): the scheduler asked
+            # this train to yield — commit the prefix as a DKV checkpoint
+            # (below) and unwind; user cancel wins and keeps its semantics.
+            # A preempt that raced the last chunk (every tree dispatched) is
+            # moot: the train just finishes.
+            preempting = (job.preempt_requested and not job.cancel_requested
+                          and not stopped and disp < ntrees_new)
+            if not stopped and inflight is not None:
                 all_trees.append((inflight["trees"], inflight["c"]))
                 built += inflight["c"]
                 trees_since_ckpt += inflight["c"]
@@ -946,92 +992,53 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                         inflight["trees"], inflight["t_disp"],
                         algo=self.algo))
                 if score_each:
-                    t_s0 = time.monotonic()
-                    keeper.record(self._score_entry_fetch(inflight["pend"]))
-                    score_s += time.monotonic() - t_s0
-                    if keeper.rounds > 0 and keeper.should_stop():
-                        # discard the speculative dispatch: the margin/
-                        # vmargin locals still hold the COMMITTED chunk's
-                        # outputs (they are only rebound to the new
-                        # dispatch below), so breaking here is the
-                        # rollback — nm/nv are simply never used
-                        stopped = True
-                        break
-                if ckpt_on and trees_since_ckpt >= ckpt_interval:
-                    commit_ckpt(margin)   # margin = committed chunk's
-                    trees_since_ckpt = 0
-            inflight = {"trees": chunk_trees, "c": c, "pend": pend,
-                        "t_disp": t_disp}
-            margin, vmargin = nm, nv
-            disp += c
-            lr *= anneal ** c
-            # progress by DISPATCHED trees: the committed count lags one
-            # chunk behind and would sit at 0 through a one-chunk train
-            job.set_progress(0.5 * disp / ntrees_new)
-            if job.cancel_requested or job.preempt_requested:
-                break
-        # checkpoint-based preemption (ISSUE 15): the scheduler asked
-        # this train to yield — commit the prefix as a DKV checkpoint
-        # (below) and unwind; user cancel wins and keeps its semantics.
-        # A preempt that raced the last chunk (every tree dispatched) is
-        # moot: the train just finishes.
-        preempting = (job.preempt_requested and not job.cancel_requested
-                      and not stopped and disp < ntrees_new)
-        if not stopped and inflight is not None:
-            all_trees.append((inflight["trees"], inflight["c"]))
-            built += inflight["c"]
-            trees_since_ckpt += inflight["c"]
-            if nd > 1 and telemetry.enabled():
-                shard_obs.append(partn.observe_step(
-                    inflight["trees"], inflight["t_disp"],
-                    algo=self.algo))
-            if score_each:
-                t_s0 = time.monotonic()
-                keeper.record(self._score_entry_fetch(inflight["pend"]))
-                score_s += time.monotonic() - t_s0
-            if (ckpt_on and trees_since_ckpt > 0) \
-                    or (preempting and built > 0):
-                # final commit covers cancellation too: a cancelled job
-                # leaves a checkpoint at its committed tree count. A
-                # PREEMPTED train commits even without a checkpoint dir
-                # (DKV-only artifact) — that checkpoint's exact f32
-                # margin is what makes the scheduler's resume
-                # bit-identical
-                commit_ckpt(margin)
-        if preempting:
-            from h2o3_tpu.jobs import JobPreempted
-            raise JobPreempted(
-                f"gbm train preempted at {built} committed trees"
-                + (f": {job.preempt_reason}" if job.preempt_reason
-                   else ""))
+                    with prof.phase("score"):
+                        keeper.record(
+                            self._score_entry_fetch(inflight["pend"]))
+                if (ckpt_on and trees_since_ckpt > 0) \
+                        or (preempting and built > 0):
+                    # final commit covers cancellation too: a cancelled job
+                    # leaves a checkpoint at its committed tree count. A
+                    # PREEMPTED train commits even without a checkpoint dir
+                    # (DKV-only artifact) — that checkpoint's exact f32
+                    # margin is what makes the scheduler's resume
+                    # bit-identical
+                    commit_ckpt(margin)
+            if preempting:
+                from h2o3_tpu.jobs import JobPreempted
+                raise JobPreempted(
+                    f"gbm train preempted at {built} committed trees"
+                    + (f": {job.preempt_reason}" if job.preempt_reason
+                       else ""))
 
-        jax.block_until_ready(margin)  # h2o3-lint: allow[transfer-seam] train-loop timing fence: the loop span must cover device completion, not dispatch
-        t_loop = time.monotonic() - t_loop0_m
-        telemetry.record_span("train.loop", t_loop0, t_loop,
-                              trees=built)
-        if score_s:
-            telemetry.record_span("train.score", t_loop0, score_s)
-        t_fin0 = time.time()           # span wall anchor
-        t_fin0_m = time.monotonic()
-        model = self._finalize(spec, valid_spec, dist_name, f0, all_trees, bm,
-                               cfg, K, built, margin,
-                               vmargin if has_valid else None, keeper,
-                               tree_offset=start_trees, prior=prior,
-                               dist=dist)
-        if ckpt_on:
-            # the finished model supersedes the in-training DKV entry —
-            # leaving it would accumulate partial-model copies (with
-            # dataset-sized resume margins) across trains and surface
-            # phantom models on GET /3/Models; disk artifacts remain
-            from h2o3_tpu import dkv
-            dkv.remove(f"{model.key}_ckpt")
-        t_fin = time.monotonic() - t_fin0_m
-        telemetry.record_span("train.finalize", t_fin0, t_fin)
+            jax.block_until_ready(margin)  # h2o3-lint: allow[transfer-seam] train-loop timing fence: the loop span must cover device completion, not dispatch
+            if sp_loop is not None:
+                sp_loop.attrs.update(trees=built, chunks=chunks)
+        with prof.phase("finalize"):
+            model = self._finalize(spec, valid_spec, dist_name, f0,
+                                   all_trees, bm, cfg, K, built, margin,
+                                   vmargin if has_valid else None, keeper,
+                                   tree_offset=start_trees, prior=prior,
+                                   dist=dist)
+            if ckpt_on:
+                # the finished model supersedes the in-training DKV
+                # entry — leaving it would accumulate partial-model
+                # copies (with dataset-sized resume margins) across
+                # trains and surface phantom models on GET /3/Models;
+                # disk artifacts remain
+                from h2o3_tpu import dkv
+                dkv.remove(f"{model.key}_ckpt")
+        t_loop = prof.phases["loop"]
         model.output["training_loop_seconds"] = t_loop
+        # the stage split that travels with the model: the phases' span
+        # durations (model_base adds spec_s, queue_s, total_s, other_s)
         model.output["train_profile"] = {
-            "bin_s": round(t_bin, 4), "loop_s": round(t_loop, 4),
-            "score_s": round(score_s, 4),
-            "finalize_s": round(t_fin, 4)}
+            f"{key}_s": round(prof.phases.get(phase, 0.0), 4)
+            for key, phase in (
+                ("bin", "bin"), ("sketch", "bin.sketch"),
+                ("digitize", "bin.digitize"), ("pack", "bin.pack"),
+                ("loop", "loop"), ("score", "score"),
+                ("finalize", "finalize"))}
         if perf_acc is not None:
             # measured device time = the loop wall (dispatches pipeline;
             # the block_until_ready fence above makes it device-

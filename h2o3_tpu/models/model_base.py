@@ -404,15 +404,30 @@ class Model:
     def predict(self, frame: Frame) -> Frame:
         """Bulk scoring → prediction Frame (BigScore analog). Output
         schema mirrors the reference: regression → 'predict'; classif →
-        'predict' + one prob column per class."""
-        X = adapt_test_matrix(self, frame)
-        out = self._predict_matrix(X, offset=self._frame_offset(frame))
-        nrow = frame.nrow
+        'predict' + one prob column per class.
+
+        Spanned at the boundaries where the work stops: ``score.adapt``
+        (host), ``score.dispatch`` (trace, lower, load and enqueue: it
+        returns before the device is done), ``score.fetch`` (the wait
+        for the device and the D2H) and ``score.frame`` (host, and the
+        result columns' uploads)."""
+        with _tel.span("score.predict", rows=frame.nrow, model=self.key):
+            with _tel.span("score.adapt"):
+                X = adapt_test_matrix(self, frame)
+                offset = self._frame_offset(frame)
+            with _tel.span("score.dispatch"):
+                out = self._predict_matrix(X, offset=offset)
+            with _tel.span("score.fetch"):
+                host = np.asarray(
+                    _tel.device_get(out, pipeline="score"))[:frame.nrow]
+            with _tel.span("score.frame"):
+                return self._prediction_frame(host)
+
+    def _prediction_frame(self, host: np.ndarray) -> Frame:
+        """The fetched scores as the prediction Frame."""
         if self.nclasses <= 1:
-            pv = np.asarray(_tel.device_get(out, pipeline="score"))[:nrow]
-            return Frame(["predict"], [Vec.from_numpy(pv)])
-        probs = self._correct_probabilities(
-            np.asarray(_tel.device_get(out, pipeline="score"))[:nrow])
+            return Frame(["predict"], [Vec.from_numpy(host)])
+        probs = self._correct_probabilities(host)
         lbl = np.argmax(probs, axis=1).astype(np.int32)
         names = ["predict"] + [f"p{d}" for d in self.response_domain]
         vecs = [Vec.from_numpy(lbl, vtype=T_ENUM, domain=self.response_domain)]
@@ -883,6 +898,7 @@ class ModelBuilder:
         run) and the H2O3_SCHED=0 escape run the pre-scheduler inline/
         daemon-thread path: queueing a child while the parent blocks on
         it would deadlock the parent against its own admission."""
+        t_call = time.perf_counter()
         y = y or self.params.get("response_column")
         training_frame = training_frame if training_frame is not None else \
             self.params.get("training_frame")
@@ -933,6 +949,7 @@ class ModelBuilder:
             if not background:
                 sched.scheduler().run_to_completion(entry)
                 self.model = self._join_typed(job)
+                self._close_train_profile(t_call)
             return self
         # inline path (nested build or scheduler disabled)
         if self._resuming:
@@ -942,7 +959,20 @@ class ModelBuilder:
                 background=background)
         if not background:
             self.model = self._join_typed(job)
+            self._close_train_profile(t_call)
         return self
+
+    def _close_train_profile(self, t_call: float) -> None:
+        """A foreground train's whole call beside its stages: ``total_s``
+        from entry to return, and ``other_s``, what the span tree leaves
+        unexplained (between the stages, job hand-off, wrap-up)."""
+        tp = self.model.output.get("train_profile")
+        if tp is None:
+            return
+        tp["total_s"] = round(time.perf_counter() - t_call, 4)
+        tp["other_s"] = round(tp["total_s"] - sum(
+            tp.get(k, 0.0) for k in ("queue_s", "spec_s", "bin_s",
+                                     "loop_s", "finalize_s")), 4)
 
     def _join_typed(self, job: Job):
         """Foreground-train result: parameter-validation failures (the
@@ -974,6 +1004,10 @@ class ModelBuilder:
         # nesting does not carry across threads)
         sp_root = telemetry.open_span(f"train.{self.algo}")
         prof = Profile(parent_span=sp_root)
+        # the scheduler's share: submit → admission → dispatch, measured
+        # by the job (jobs.mark_dispatched) and over before this body ran
+        if job.queue_wait_s:
+            prof.add("queue", job.queue_wait_s)
         timeline_record("train_start", f"{self.algo}")
         self._warn_compat_params()
         try:
@@ -1100,6 +1134,10 @@ class ModelBuilder:
                         self._attach_cv(model, training_frame, y, x,
                                         *fold_pass)
             model.output["profile"] = prof.to_dict()
+            if "train_profile" in model.output:
+                model.output["train_profile"].update(
+                    queue_s=round(prof.phases.get("queue", 0.0), 4),
+                    spec_s=round(prof.phases["spec"], 4))
             if rec_key is not None:
                 # DELIBERATE completion (DONE or a cooperative cancel
                 # that finalized a partial model): the manifest's job is

@@ -15,6 +15,7 @@ XLA). Split "bin t" means: left ⇔ code < t ⇔ raw < edges[t-1].
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from functools import lru_cache, partial
 from typing import List, NamedTuple, Optional, Sequence
@@ -145,9 +146,16 @@ def _np_quantile_lerp(a: np.ndarray, b: np.ndarray, t: np.ndarray) -> np.ndarray
 def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
                       nrow: int, nbins: int = 255, nbins_cats: int = 1024,
                       histogram_type: str = "quantiles_global",
-                      with_t: bool = True) -> BinnedMatrix:
+                      with_t: bool = True, prof=None) -> BinnedMatrix:
     """Device-side global sketch: the same edges as :func:`bin_matrix`
     (bit-exact — parity-tested) WITHOUT a ``device_get`` of the full X.
+
+    A trainer's ``prof`` (its ``log.Profile``) times the two halves as
+    phases ``bin.sketch`` and ``bin.digitize`` (spans
+    ``train.bin.sketch``, ``train.bin.digitize``). The sketch ends at the
+    fetch of its stats, a sync already; the digitise ends at a fence on
+    the codes, which costs one dispatch latency and tells the two device
+    programs' times apart.
 
     The device sorts each feature once and the host fetches only O(F)
     stats plus the 2·(nbins-1) quantile neighbour values per feature; the
@@ -177,6 +185,24 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
                           histogram_type=histogram_type, with_t=with_t,
                           X_host=np.asarray(telemetry.device_get(
                               X, pipeline="train"), np.float32))
+    phase = prof.phase if prof is not None else contextlib.nullcontext
+    with phase("bin.sketch"):
+        edges, n_bins_eff = _device_sketch_edges(
+            X, is_cat, nrow, nbins, nbins_cats, histogram_type)
+    with phase("bin.digitize"):
+        codes = make_codes_view(digitize_with_edges(X, edges, n_bins_eff),
+                                with_t=with_t)
+        jax.block_until_ready(codes)  # h2o3-lint: allow[transfer-seam] digitise timing fence: between two device programs on one stream, so bin.digitize and bin.pack each carry their own
+    return BinnedMatrix(codes=codes, n_bins=n_bins_eff, edges=edges,
+                        names=list(names), is_categorical=list(is_cat),
+                        nrow=nrow)
+
+
+def _device_sketch_edges(X, is_cat: Sequence[bool], nrow: int, nbins: int,
+                         nbins_cats: int, histogram_type: str):
+    """(edges, effective bin count) of :func:`bin_matrix_device`: one
+    device sort, the fetch of its O(F) stats, the host's float64 lerp."""
+    from h2o3_tpu import telemetry
     F = X.shape[1]
     Xs, nfin_d, fmin_d, fmax_d = _sketch_stats(X, jnp.int32(nrow))
     # ONE counted fetch of the O(F) sketch stats (transfer-seam)
@@ -245,11 +271,7 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
         raise ValueError(
             f"effective bin count {n_bins_eff} exceeds the 14-bit routing "
             f"limit; lower nbins_cats (reference default is 1024)")
-    codes = make_codes_view(digitize_with_edges(X, edges, n_bins_eff),
-                            with_t=with_t)
-    return BinnedMatrix(codes=codes, n_bins=n_bins_eff, edges=edges,
-                        names=list(names), is_categorical=list(is_cat),
-                        nrow=nrow)
+    return edges, n_bins_eff
 
 
 def bin_matrix(X, names: Sequence[str], is_cat: Sequence[bool], nrow: int,

@@ -1005,6 +1005,7 @@ def binned_level_tpu_t(ct, nid, ghw, tables, n_prev: int, n_nodes: int,
             transcendentals=0),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="binned_level_tpu_t",
     )(ct, nid[None, :], ghw, tabs)
     return nid2[0], hist.reshape(3, n_nodes, F, W)
 
@@ -1110,6 +1111,7 @@ def binned_level_tpu_stripe(ct, nid, ghw, tables, n_prev: int,
             transcendentals=0),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="binned_level_tpu_stripe",
     )(ct, nid[None, :], ghw, tabs)
     return nid2[0], hist.reshape(3, n_nodes, 2 * F2, W)[:, :, :F, :]
 
@@ -1313,6 +1315,7 @@ def binned_route_only_tpu_t(ct, nid, tables, n_prev: int, level_base: int,
         out_shape=jax.ShapeDtypeStruct((1, rows), jnp.int32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="binned_route_only_tpu_t",
     )(ct, nid[None, :], tabs)
     return nid2[0]
 

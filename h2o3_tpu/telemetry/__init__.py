@@ -7,7 +7,16 @@ device-aware collectors (XLA compile counter, compile-cache hit/miss,
 h2d/d2h transfer bytes, device memory) — the single producer behind
 ``GET /metrics`` (Prometheus), ``GET /3/Telemetry`` (JSON snapshot) and
 ``GET /3/Timeline?format=trace`` (Perfetto), and the data source the
-profiler tools (tools/profile_*.py) and bench rounds read.
+profiler tools (tools/profile_*.py) and the benchmark's per-layer
+readers (benchmark/harness/readers/) read.
+
+Where a span lands: ``span()`` reaches the ``h2o3_span_seconds``
+histogram (/metrics, ``stage_seconds``), the finished-span ring
+(``finished_spans``, ``/3/Timeline?format=trace``), the Flow timeline if
+it is a root, and, while a ``jax.profiler`` session runs, the calling
+thread's host line of the profiler's trace. ``open_span()``,
+``record_span()`` and ``fold_span()`` reach the first three only
+(spans.py says why).
 
 ``H2O3_TELEMETRY=0`` turns every producer into a checked no-op (one
 attribute load + branch — guarded by tests/test_telemetry.py's

@@ -1,11 +1,21 @@
 """Device-aware collectors: the telemetry the JVM-era tools can't see.
 
 - **Compile counter** (production promotion of tests/_compile_counter.py):
-  a ``jax.monitoring`` duration listener counts every XLA backend
-  compile (``/jax/core/compile/backend_compile_duration``) into
-  ``h2o3_xla_compiles_total`` + a duration histogram — the warm-path
-  zero-compile guarantee the test harness proves is now a metric
-  production can watch.
+  a ``jax.monitoring`` duration listener counts every
+  ``/jax/core/compile/backend_compile_duration`` event into
+  ``h2o3_xla_compiles_total`` + a duration histogram. JAX wraps that
+  event around ``compile_or_get_cached``, so it fires once per jit-cache
+  miss that reached the backend, whether the executable was built or
+  loaded from the persistent cache: zero over a window is the warm-path
+  guarantee the test harness proves, as a metric production can watch.
+- **Jit stages**: what the host pays on a jit-cache miss, counted where
+  it happens: spans ``jit.trace`` (jaxpr trace), ``jit.lower`` (jaxpr to
+  MLIR module), ``jit.load`` (executable read from the persistent cache)
+  and ``jit.build`` (backend compile with no cache hit inside it), one
+  of each under the span that was open on the calling thread
+  (``spans.fold_span``): its seconds are the stage's host time there,
+  attr ``n`` the number of events; ``h2o3_span_seconds{span="jit.*"}``
+  sums them for /metrics. None of these events fires on a warm dispatch.
 - **Compile-cache hit/miss**: the persistent-compile-cache events
   (``/jax/compilation_cache/cache_hits`` / ``cache_misses``).
 - **Transfer bytes**: ``record_h2d``/``record_d2h`` counters called from
@@ -18,6 +28,7 @@
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, List, Optional
 
 from h2o3_tpu.telemetry.registry import on_reset, registry
@@ -28,12 +39,22 @@ _INSTALLED = [False]
 BACKEND_COMPILE_SUFFIX = "backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+# emitted on a persistent-cache hit only, on the compiling thread, inside
+# the backend_compile_duration event that then closes around it
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+JIT_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    CACHE_RETRIEVAL_EVENT: "load",
+}
+_TLS = threading.local()
 
 
 def _compiles():
     return registry().counter(
         "h2o3_xla_compiles_total",
-        help="XLA backend compiles in this process")
+        help="executables built or loaded from the persistent cache "
+             "(one per jit-cache miss that reached the backend)")
 
 
 def _cache_hits():
@@ -49,11 +70,25 @@ def _cache_misses():
 
 
 def _duration_listener(key: str, dur: float, **_kw) -> None:
-    if key.endswith(BACKEND_COMPILE_SUFFIX):
+    dur = float(dur)
+    stage = JIT_STAGE_EVENTS.get(key)
+    if stage is None:
+        if not key.endswith(BACKEND_COMPILE_SUFFIX):
+            return
         _compiles().inc()
         registry().histogram(
             "h2o3_xla_compile_seconds",
-            help="XLA backend compile durations").observe(float(dur))
+            help="XLA backend compile durations").observe(dur)
+        loaded, _TLS.loaded = getattr(_TLS, "loaded", False), False
+        if loaded:
+            return              # counted when its retrieval time arrived
+        stage = "build"
+    elif key == CACHE_RETRIEVAL_EVENT:
+        _TLS.loaded = True
+    from h2o3_tpu.telemetry.spans import fold_span
+    fold_span(
+        f"jit.{stage}",
+        time.time() - dur, dur)  # h2o3-lint: allow[monotonic-durations] wall START anchor reconstructed from a duration JAX reports after the fact
 
 
 def _event_listener(key: str, **_kw) -> None:

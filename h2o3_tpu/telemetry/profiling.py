@@ -9,12 +9,17 @@ lives here once:
     with profile("warm_train"):            # no-op unless a dir resolves
         gbm.train(...)
 
-``profile(name, trace_dir=...)`` wraps the block in
-``jax.profiler.trace`` writing to ``<dir>/<name>`` — open the dump with
+``profile(name, trace_dir=...)`` wraps the block in a
+``jax.profiler`` trace writing to ``<dir>/<name>`` — open the dump with
 xprof/tensorboard (``python -m xprof.server DIR`` or
 ``tensorboard --logdir DIR``) for kernel-level attribution (per-level
 fused-histogram kernels, the ICI psum all-reduce on the device
-timeline). Trace-dir resolution, in priority order:
+timeline). The trace is started with the Python tracer off and the host
+tracer at level 2, as ``benchmark/run.py`` starts its own: the host
+lines then hold the program's ``telemetry.span`` annotations, the span
+tree an operator sees on ``/3/Timeline?format=trace``, beside the device
+planes and not buried in one event per Python frame. Trace-dir
+resolution, in priority order:
 
 1. the explicit ``trace_dir=`` argument;
 2. ``--xprof-trace [DIR]`` on ``sys.argv`` (the shared tools/ CLI
@@ -78,7 +83,10 @@ class profile:
         try:
             import jax
             os.makedirs(self.dir, exist_ok=True)
-            jax.profiler.start_trace(self.dir)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0    # the spans, not the frames
+            opts.host_tracer_level = 2
+            jax.profiler.start_trace(self.dir, profiler_options=opts)
             self._active = True
             _LAST_DIR[0] = self.dir
             self._log(f"xprof: tracing '{self.name}' -> {self.dir}")

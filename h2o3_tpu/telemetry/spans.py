@@ -1,16 +1,37 @@
 """End-to-end spans: nested timing contexts with cross-thread handoff.
 
 A ``span("train.gbm.loop", job=...)`` context manager times a stage and
-records it three ways:
+records it in four sinks:
 
-- a per-name duration histogram in the metrics registry
-  (``h2o3_span_seconds{span=...}``) — the aggregate view the profiler
+- **histogram**: a per-name duration histogram in the metrics registry
+  (``h2o3_span_seconds{span=...}``), the aggregate view the profiler
   tools and /metrics read;
-- an entry in a bounded ring of finished spans — the raw view behind
-  ``GET /3/Timeline?format=trace`` (Chrome-trace/Perfetto export);
-- for ROOT spans (no parent), an event in the existing
-  ``log.timeline_record`` ring — so Flow's /3/Timeline finally shows
-  ingest and serve activity, not just model builds.
+- **ring**: an entry in a bounded ring of finished spans, the raw view
+  behind ``GET /3/Timeline?format=trace`` (Chrome-trace/Perfetto export)
+  and the benchmark's ``span_ring`` reader;
+- **timeline**: for ROOT spans (no parent), an event in the existing
+  ``log.timeline_record`` ring, so Flow's /3/Timeline shows ingest and
+  serve activity, not just model builds;
+- **profiler**: a ``jax.profiler.TraceAnnotation`` of the same name held
+  open for the block, so while a profiler session runs the span is an
+  event on the calling thread's host line, on the clock the device
+  planes share. A device-idle gap can then be named by the program's own
+  span. With no session the annotation is a flag test.
+
+Which call reaches which sink:
+
+=================  =========  ====  ========  ========
+call               histogram  ring  timeline  profiler
+=================  =========  ====  ========  ========
+``span()``         yes        yes   if root   yes
+``open_span()``    yes        yes   if root   no
+``record_span()``  yes        yes   if root   no
+``fold_span()``    yes        yes   if root   no
+=================  =========  ====  ========  ========
+
+``open_span`` may finish on another thread and ``record_span`` writes an
+interval that is already over; a profiler annotation has to be entered
+and left on one thread while the work runs, so neither can make one.
 
 Parentage: within a thread, nesting is implicit (a thread-local stack).
 Across threads — the micro-batcher's submit/batch/collect trio, the
@@ -21,10 +42,15 @@ one thread and pass it as ``span(..., parent=handle)`` or
 valid after it finishes; linking to a finished parent is fine (the
 batcher's collector thread finishes children after the batch root).
 
-Pipelines that already keep wall-clock stage timers (ingest's
-LAST_PROFILE, gbm's train_profile) record those SAME intervals via
-``record_span`` — one clock feeds both the legacy dicts and the spans,
-so the REST-reported and tool-reported stage splits cannot disagree.
+Stage splits that travel with a result (``log.Profile``, and through it
+gbm's ``train_profile``) take each stage's seconds from the span that
+timed it, so the REST-reported and tool-reported splits cannot disagree.
+``record_span`` is for intervals nobody could wrap: one that ends on
+another thread (the batcher) or a duration reported after the fact.
+``fold_span`` is ``record_span`` for reports that come by the hundred
+under one span (the collectors' ``jit.*``: JAX reports each trace, lower
+and load when it is over): they become ONE child a name of the span
+they fell in, so the ring keeps the spans an operator looks for.
 """
 from __future__ import annotations
 
@@ -79,8 +105,20 @@ def set_ring_capacity(cap: int) -> None:
             dropped += 1
     if dropped:
         _dropped_counter().inc(dropped)
+
+
 _IDS = itertools.count(1)
 _TLS = threading.local()
+# jax.profiler.TraceAnnotation, imported by the first live span: this
+# module stays importable without jax (tools/blackbox_read.py)
+_ANNOTATION: List[type] = []
+
+
+def _annotation(name: str):
+    if not _ANNOTATION:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION.append(TraceAnnotation)
+    return _ANNOTATION[0](name)
 
 # span-duration histogram bounds: 10µs (a serve decode) … 1000s (a cold
 # AutoML build)
@@ -109,7 +147,8 @@ def _span_hist(name: str):
 
 class Span:
     __slots__ = ("name", "attrs", "span_id", "parent_id", "thread_id",
-                 "t_wall", "t0", "duration_s", "trace_id")
+                 "t_wall", "t0", "duration_s", "trace_id", "annotation",
+                 "folded")
 
     def __init__(self, name: str, parent: Optional["Span"] = None,
                  attrs: Optional[Dict] = None):
@@ -121,6 +160,9 @@ class Span:
         self.t_wall = time.time()
         self.t0 = time.perf_counter()
         self.duration_s: Optional[float] = None
+        self.annotation = None      # span(): its open profiler annotation
+        # fold_span(): {name: [reports, [(start_wall, seconds), ...]]}
+        self.folded: Optional[Dict[str, list]] = None
         # trace linkage: the thread's bound trace id wins (the REST
         # handler / job thread bound it), else inherit the parent's —
         # which is how a child recorded on the batcher's collector
@@ -131,6 +173,11 @@ class Span:
     def finish(self) -> "Span":
         if self.duration_s is None:
             self.duration_s = time.perf_counter() - self.t0
+            if self.folded:
+                for name, (n, kept) in self.folded.items():
+                    record_span(name, kept[0][0], sum(d for _, d in kept),
+                                parent=self, n=n)
+                self.folded = None
             _record_finished(self)
         return self
 
@@ -215,7 +262,8 @@ def _record_finished(sp: Span) -> None:
 
 class _SpanContext:
     """Context manager wrapper: pushes/pops the thread-local stack so
-    nested ``span()`` calls parent implicitly."""
+    nested ``span()`` calls parent implicitly, and holds the profiler
+    annotation of the same name open for the block."""
     __slots__ = ("_span", "_name", "_parent", "_attrs")
 
     def __init__(self, name, parent, attrs):
@@ -229,7 +277,10 @@ class _SpanContext:
             return None
         parent = self._parent if self._parent is not None \
             else current_span()
+        annotation = _annotation(self._name)
+        annotation.__enter__()
         sp = Span(self._name, parent, self._attrs)
+        sp.annotation = annotation
         _stack().append(sp)
         self._span = sp
         return sp
@@ -242,13 +293,13 @@ class _SpanContext:
             sp.attrs["error"] = True
             _note_error_span(sp.name, exc_value)
         st = _stack()
-        # pop by identity — an exception may have skipped inner pops
-        while st:
-            top = st.pop()
-            if top is sp:
-                break
+        # pop by identity, innermost first: an exception may have skipped
+        # inner exits, and each span leaves its own annotation
+        while sp.annotation is not None:
+            top = st.pop() if st else sp
             top.finish()
-        sp.finish()
+            top.annotation.__exit__(exc_type, exc_value, tb)
+            top.annotation = None
         return False
 
 
@@ -262,7 +313,8 @@ def span(name: str, parent: Optional[Span] = None, **attrs) -> _SpanContext:
 def open_span(name: str, parent: Optional[Span] = None,
               **attrs) -> Optional[Span]:
     """Start a span WITHOUT entering the thread-local stack — for spans
-    that end on a different thread (the batcher's per-batch root).
+    that end on a different thread (the batcher's per-batch root), which
+    is also why it makes no profiler annotation.
     Finish with ``sp.finish()``. Returns None when telemetry is off."""
     if not registry().enabled:
         return None
@@ -271,9 +323,11 @@ def open_span(name: str, parent: Optional[Span] = None,
 
 def record_span(name: str, start_wall: float, duration_s: float,
                 parent: Optional[Span] = None, **attrs) -> Optional[Span]:
-    """Record an already-measured interval as a finished span (one clock
-    feeding both a legacy profile dict and the span ring). ``parent``
-    defaults to the calling thread's current span."""
+    """Record an already-measured interval as a finished span: one that
+    ended on another thread, or a duration reported after the fact.
+    ``parent`` defaults to the calling thread's current span. The
+    interval is over, so it cannot be a profiler annotation: it reaches
+    histogram, ring and timeline only."""
     if not registry().enabled:
         return None
     sp = Span(name, parent if parent is not None else current_span(), attrs)
@@ -281,6 +335,32 @@ def record_span(name: str, start_wall: float, duration_s: float,
     sp.duration_s = float(duration_s)
     _record_finished(sp)
     return sp
+
+
+def fold_span(name: str, start_wall: float, duration_s: float) -> None:
+    """Fold an already-measured interval into ONE child span ``name`` of
+    the calling thread's current span. The child is written when that
+    span finishes, with the intervals' summed seconds and their number as
+    attr ``n``, so a report that comes by the hundred (an un-jitted scan
+    traces 137 times a predict) costs the ring one entry a parent. An
+    interval that holds earlier ones of its name replaces them in the
+    sum: JAX reports an outer function's trace after the inner traces it
+    contains, and the seconds are host time, counted once. With no open
+    span the interval is recorded at once, as ``record_span`` does."""
+    if not registry().enabled:
+        return
+    parent = current_span()
+    if parent is None:
+        record_span(name, start_wall, duration_s, n=1)
+        return
+    if parent.folded is None:
+        parent.folded = {}
+    acc = parent.folded.setdefault(name, [0, []])
+    acc[0] += 1
+    kept = acc[1]
+    while kept and kept[-1][0] > start_wall:
+        kept.pop()
+    kept.append((start_wall, float(duration_s)))
 
 
 def finished_spans(n: Optional[int] = None) -> List[Span]:

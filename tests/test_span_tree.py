@@ -1,0 +1,351 @@
+"""One span tree on the profiler's clock (ISSUE 27).
+
+A train and a predict leave the span tree that ``models/gbm.py``,
+``ops/binning.py`` and ``models/model_base.py`` document, with the right
+parents; ``train_profile`` is those spans' durations and nothing else;
+the jit stages (trace, lower, load, build) are counted where they happen
+and land as one ``jit.*`` span a stage under the calling thread's span;
+every live span is an event of a ``jax.profiler`` trace; and
+``H2O3_TELEMETRY=0`` leaves ring, histograms and trace empty.
+"""
+import glob
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import h2o3_tpu as h2o
+from h2o3_tpu import telemetry
+from h2o3_tpu.models.gbm import H2OGradientBoostingEstimator
+
+ROWS, FEATURES = 200_000, 8
+
+
+@pytest.fixture(autouse=True)
+def _telemetry_on():
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    telemetry.install()
+    yield
+    telemetry.set_enabled(was)
+
+
+@pytest.fixture(scope="module")
+def frame():
+    rng = np.random.default_rng(27)
+    X = rng.normal(size=(ROWS, FEATURES)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] + 0.3 * rng.normal(size=ROWS) > 0)
+    cols = {f"x{i}": X[:, i] for i in range(FEATURES)}
+    cols["y"] = y.astype(np.float32)
+    return h2o.Frame.from_numpy(cols)
+
+
+def _train(frame):
+    """(estimator, the spans its train left), packed path on the CPU."""
+    telemetry.install()
+    est = H2OGradientBoostingEstimator(
+        ntrees=6, max_depth=3, distribution="bernoulli", seed=1,
+        packed_codes=True, score_tree_interval=3)
+    telemetry.clear_spans()
+    est.train(y="y", training_frame=frame)
+    return est, telemetry.finished_spans()
+
+
+@pytest.fixture(scope="module")
+def warm_train(frame):
+    """The second of two trains: nothing compiles in it."""
+    _train(frame)
+    return _train(frame)
+
+
+def _tree(spans):
+    """{name: [parent's name, ...]} and {name: [span, ...]}."""
+    by_id = {s.span_id: s for s in spans}
+    parents, named = {}, {}
+    for s in spans:
+        up = by_id.get(s.parent_id)
+        parents.setdefault(s.name, []).append(up.name if up else None)
+        named.setdefault(s.name, []).append(s)
+    return parents, named
+
+
+def test_a_train_leaves_the_span_tree_with_the_right_parents(warm_train):
+    est, spans = warm_train
+    parents, named = _tree(spans)
+    want = {"train.gbm": None, "train.queue": "train.gbm",
+            "train.spec": "train.gbm", "train.train": "train.gbm",
+            "train.bin": "train.train", "train.bin.sketch": "train.bin",
+            "train.bin.digitize": "train.bin", "train.bin.pack": "train.bin",
+            "train.loop": "train.train", "train.score": "train.loop",
+            "train.finalize": "train.train"}
+    for name, parent in want.items():
+        assert name in parents, f"no span {name}: {sorted(parents)}"
+        assert set(parents[name]) == {parent}, (name, parents[name])
+    # one span per wait on a score entry: 6 trees scored every 3
+    assert len(named["train.score"]) == 2
+    assert len(named["train.bin"]) == len(named["train.loop"]) == 1
+    loop = named["train.loop"][0]
+    assert loop.attrs["trees"] == 6 and loop.attrs["chunks"] == 2
+    # the stages follow one another inside train.train
+    order = [named[n][0] for n in ("train.bin.sketch", "train.bin.digitize",
+                                   "train.bin.pack", "train.loop",
+                                   "train.finalize")]
+    starts = [s.t0 for s in order]
+    assert starts == sorted(starts)
+    for a, b in zip(order, order[1:]):
+        assert a.t0 + a.duration_s <= b.t0 + 1e-6
+
+
+def test_train_profile_is_the_spans_durations(warm_train):
+    est, spans = warm_train
+    _, named = _tree(spans)
+    tp = est.model.output["train_profile"]
+    assert set(tp) == {"bin_s", "sketch_s", "digitize_s", "pack_s", "loop_s",
+                       "score_s", "finalize_s", "queue_s", "spec_s",
+                       "total_s", "other_s"}
+
+    def seconds(name):
+        return sum(s.duration_s for s in named[name])
+
+    for key, name in (("bin_s", "train.bin"), ("loop_s", "train.loop"),
+                      ("score_s", "train.score"),
+                      ("finalize_s", "train.finalize"),
+                      ("spec_s", "train.spec"), ("queue_s", "train.queue"),
+                      ("sketch_s", "train.bin.sketch"),
+                      ("digitize_s", "train.bin.digitize"),
+                      ("pack_s", "train.bin.pack")):
+        assert tp[key] == pytest.approx(seconds(name), abs=6e-5), key
+    assert est.model.output["training_loop_seconds"] == seconds("train.loop")
+    parts = tp["sketch_s"] + tp["digitize_s"] + tp["pack_s"]
+    assert parts <= tp["bin_s"] + 3e-4
+    # 5% of bin_s; 2 ms for the host lines between the three (gates,
+    # TreeConfig) where a loaded CPU makes bin_s small
+    assert tp["bin_s"] - parts <= 0.05 * tp["bin_s"] + 2e-3
+    assert tp["other_s"] >= 0.0
+    assert tp["total_s"] >= seconds("train.gbm")
+    stages = sum(tp[k] for k in ("queue_s", "spec_s", "bin_s", "loop_s",
+                                 "finalize_s", "other_s"))
+    assert stages == pytest.approx(tp["total_s"], abs=1e-3)
+
+
+def test_a_predict_leaves_its_four_children(frame, warm_train):
+    est, _ = warm_train
+    est.model.predict(frame)            # the ops outside the scan compile
+    telemetry.clear_spans()
+    pred = est.model.predict(frame)
+    assert pred.nrow == ROWS
+    parents, named = _tree(telemetry.finished_spans())
+    root, = named["score.predict"]
+    assert parents["score.predict"] == [None]
+    assert root.attrs == {"rows": ROWS, "model": est.model.key}
+    kids = ("score.adapt", "score.dispatch", "score.fetch", "score.frame")
+    for k in kids:
+        assert parents[k] == ["score.predict"], (k, parents.get(k))
+    total = sum(named[k][0].duration_s for k in kids)
+    assert total <= root.duration_s
+    assert total == pytest.approx(root.duration_s, rel=0.05)
+    # what the host pays on every call is under score.dispatch, by name:
+    # one span a stage, however many events it folds
+    jit = {n: p for n, p in parents.items() if n.startswith("jit.")}
+    assert "jit.trace" in jit and "jit.lower" in jit
+    assert all(p == ["score.dispatch"] for p in jit.values()), jit
+    assert named["jit.trace"][0].attrs["n"] > 1
+    # so a predict costs the ring its five spans and one a stage it paid
+    assert len(telemetry.finished_spans()) <= 5 + 4
+    in_jit = sum(named[n][0].duration_s for n in jit)
+    assert 0 < in_jit <= named["score.dispatch"][0].duration_s
+
+
+def _jit_spans(stage, parent=None):
+    return [s for s in telemetry.finished_spans()
+            if s.name == f"jit.{stage}"
+            and (parent is None or s.parent_id == parent.span_id)]
+
+
+def _jit_histogram(stage):
+    """(count, seconds) of ``h2o3_span_seconds{span="jit.<stage>"}``."""
+    h = telemetry.stage_seconds("jit.").get(f"jit.{stage}")
+    return (h["count"], h["seconds"]) if h else (0, 0.0)
+
+
+def test_reports_under_one_span_fold_into_one_child_counted_once():
+    from h2o3_tpu.telemetry.spans import fold_span
+    telemetry.clear_spans()
+    with telemetry.span("t.fold") as parent:
+        fold_span("t.fold.x", 10.0, 1.0)
+        fold_span("t.fold.x", 11.5, 0.5)
+        fold_span("t.fold.x", 9.0, 4.0)     # holds the two before it
+        fold_span("t.fold.x", 14.0, 1.0)
+        fold_span("t.fold.y", 12.0, 0.25)
+        assert len(telemetry.finished_spans()) == 0     # not yet written
+    x, y, top = telemetry.finished_spans()
+    assert top is parent
+    assert (x.name, x.parent_id, x.attrs) == ("t.fold.x", top.span_id,
+                                              {"n": 4})
+    assert (x.t_wall, x.duration_s) == (9.0, 5.0)
+    assert (y.name, y.attrs, y.duration_s) == ("t.fold.y", {"n": 1}, 0.25)
+    # under no span a report is a span of its own
+    fold_span("t.fold.x", 20.0, 2.0)
+    alone = telemetry.finished_spans()[-1]
+    assert (alone.parent_id, alone.attrs, alone.duration_s) == (0, {"n": 1},
+                                                                2.0)
+    assert telemetry.stage_seconds("t.fold.")["t.fold.x"] == {
+        "count": 2, "seconds": 7.0}
+
+
+def test_an_unjitted_scan_traces_on_every_call_a_jitted_one_once():
+    def scan_sum(xs):
+        def body(c, x):     # a new closure a call, as the scorer's one_tree
+            return jax.lax.add(c, x), c
+        return jax.lax.scan(body, jnp.float32(0), xs)
+
+    xs = jnp.arange(16, dtype=jnp.float32)
+    scan_sum(xs)            # all but the scan itself is in JAX's caches now
+    telemetry.clear_spans()
+    _, seconds0 = _jit_histogram("trace")
+    with telemetry.span("t.unjitted.once") as once:
+        scan_sum(xs)
+    with telemetry.span("t.unjitted.twice") as twice:
+        scan_sum(xs)
+        scan_sum(xs)
+    for stage in ("trace", "lower"):
+        one, = _jit_spans(stage, once)
+        two, = _jit_spans(stage, twice)
+        assert one.attrs["n"] >= 1 and two.attrs["n"] == 2 * one.attrs["n"]
+    jitted = jax.jit(scan_sum)
+    with telemetry.span("t.jitted.first") as first:
+        jitted(xs)
+    with telemetry.span("t.jitted.again") as again:
+        jitted(xs)
+    traced, = _jit_spans("trace", first)    # scan_sum and traces inside it
+    assert traced.attrs["n"] >= 1
+    assert _jit_spans("lower", first)[0].attrs == {"n": 1}  # one program
+    assert not [s for s in telemetry.finished_spans()
+                if s.parent_id == again.span_id]
+    # what /metrics exports of the stage is the spans' seconds
+    assert _jit_histogram("trace")[1] - seconds0 == pytest.approx(
+        sum(s.duration_s for s in _jit_spans("trace")), abs=5e-6)
+
+
+def test_a_trace_inside_a_trace_is_counted_but_its_seconds_are_not():
+    salt = float(time.time_ns() % 1_000_003)
+
+    @jax.jit
+    def inner(x):
+        return jnp.cumsum(x) * salt
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + inner(x * 2.0)
+
+    telemetry.clear_spans()
+    with telemetry.span("t.nested") as parent:
+        t0 = time.perf_counter()
+        outer(jnp.arange(8, dtype=jnp.float32))
+        wall = time.perf_counter() - t0
+    trace, = _jit_spans("trace", parent)
+    assert trace.attrs["n"] >= 2           # outer and, inside it, inner
+    stages = [s for s in telemetry.finished_spans()
+              if s.name.startswith("jit.") and s.parent_id == parent.span_id]
+    assert sum(s.duration_s for s in stages) <= wall
+
+
+def test_a_persistent_cache_hit_is_a_load_not_a_build():
+    assert jax.config.jax_compilation_cache_dir, "conftest sets the cache"
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    salt = float(time.time_ns() % 1_000_003)    # a program no run has cached
+
+    @jax.jit
+    def salted(x):
+        return jnp.sin(x) * salt + jnp.cumsum(x)
+
+    def events(parent):
+        return {st: sum(s.attrs["n"] for s in _jit_spans(st, parent))
+                for st in ("build", "load")}
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        x = jnp.arange(8, dtype=jnp.float32)
+        compiles = telemetry.registry().value("h2o3_xla_compiles_total")
+        with telemetry.span("t.cold") as cold:
+            first = np.asarray(salted(x))
+        jax.clear_caches()              # the jit cache, not the directory
+        with telemetry.span("t.cached") as cached:
+            again = np.asarray(salted(x))
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          floor)
+    np.testing.assert_array_equal(first, again)
+    assert events(cold) == {"build": 1, "load": 0}
+    assert events(cached) == {"build": 0, "load": 1}
+    # the old counter counts both, as its help text now says
+    assert telemetry.registry().value("h2o3_xla_compiles_total") \
+        - compiles == 2
+
+
+def _host_event_names(trace_dir):
+    from jax.profiler import ProfileData
+    found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert found, f"no trace under {trace_dir}"
+    return {e.name for plane in ProfileData.from_file(found[-1]).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events}
+
+
+def _harness_trace(path):
+    """As ``benchmark/run.py:start_trace`` starts its trace."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(path, profiler_options=opts)
+
+
+@pytest.mark.parametrize("how", ["harness_options", "telemetry_profile"])
+def test_a_profiler_trace_of_a_predict_holds_the_programs_spans(
+        how, frame, warm_train, tmp_path):
+    est, _ = warm_train
+    if how == "harness_options":
+        _harness_trace(str(tmp_path))
+        try:
+            est.model.predict(frame)
+        finally:
+            jax.profiler.stop_trace()
+    else:
+        with telemetry.profile("predict", trace_dir=str(tmp_path),
+                               log=lambda *a: None):
+            est.model.predict(frame)
+    names = _host_event_names(str(tmp_path))
+    assert {"score.predict", "score.adapt", "score.dispatch", "score.fetch",
+            "score.frame"} <= names
+    # the spans, not one event per Python frame
+    assert not any(n.startswith("$") for n in names)
+
+
+def test_telemetry_off_leaves_ring_histograms_and_trace_empty(
+        frame, warm_train, tmp_path):
+    est, _ = warm_train
+
+    def jit_events():
+        return telemetry.stage_seconds("jit.")
+
+    telemetry.clear_spans()
+    before = jit_events()
+    telemetry.set_enabled(False)
+    try:
+        _harness_trace(str(tmp_path))
+        try:
+            with telemetry.span("t.off") as sp:
+                est.model.predict(frame)        # retraces its scan
+        finally:
+            jax.profiler.stop_trace()
+        assert sp is None
+    finally:
+        telemetry.set_enabled(True)
+    assert telemetry.finished_spans() == []
+    assert jit_events() == before
+    names = _host_event_names(str(tmp_path))
+    assert not any(n.startswith(("score.", "jit.", "t.off")) for n in names)

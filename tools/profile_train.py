@@ -347,10 +347,11 @@ def main():
         "spmd": model.output.get("spmd"),
         # hot-loop representation (ISSUE 12): what the level kernel
         # streamed — the packed int8/int16 path vs f32, with the
-        # cost-analysis-grounded bytes per (row x tree). The xprof
-        # capture above names the kernel itself (`_kernel_bt` for the
-        # binned path, `_kernel_t` for the f32 adaptive path) on the
-        # device timeline.
+        # cost-analysis-grounded bytes per (row x tree). In the xprof
+        # capture above the packed path's kernels are the custom calls
+        # named `binned_level_tpu_t` / `binned_level_tpu_stripe` /
+        # `binned_route_only_tpu_t` on the device's op line (their
+        # pallas_call `name=`, seen on the v5e, PERF.md PR 27).
         "packed_codes": model.output.get("packed_codes"),
         # multi-level fusion (ISSUE 17): how many tree levels each
         # device dispatch covered — max_depth on the dense path (the
