@@ -339,12 +339,14 @@ def digitize_codes_host(X_host, edges: List[np.ndarray], n_bins_eff: int):
     convention (NA = reserved bin W-1, dtype from
     hist_adaptive.code_dtype so host and device packing can never
     diverge) — the memory-pressure half of the streamed packed path:
-    the full X never uploads. Searchsorts the same inf-PADDED edge
-    matrix as the device :func:`digitize_with_edges`, so +inf values
-    land in the shared lane ``max_e`` on every feature (bit-matching
-    the dense packed codes — a per-feature unpadded searchsorted would
-    merge +inf with the top finite bin on short-edge features and
-    break streamed-vs-dense parity AND train-vs-score routing).
+    the full X never uploads. Searchsorts (numpy, side="right") the
+    same inf-PADDED edge matrix whose edges <= x the device
+    :func:`digitize_with_edges` counts - the same number - so +inf
+    values land in the shared lane ``max_e`` on every feature
+    (bit-matching the dense packed codes — a per-feature unpadded
+    searchsorted would merge +inf with the top finite bin on short-edge
+    features and break streamed-vs-dense parity AND train-vs-score
+    routing).
     Column-at-a-time so the temporaries stay O(rows). Returns
     (codes [rows, F], W)."""
     from h2o3_tpu.ops.hist_adaptive import code_dtype, pick_W
@@ -508,22 +510,53 @@ def pack_codes_for(X, bm: "BinnedMatrix", W: Optional[int] = None):
     return _repack_codes(c, na=bm.n_bins, W=W, dt=code_dtype(W))
 
 
-@jax.jit
-def _searchsorted_cols(emat, x):
-    # vmap over features: edges [F, E], x [rows, F] → codes [rows, F]
-    return jax.vmap(lambda e, c: jnp.searchsorted(e, c, side="right"),
-                    in_axes=(0, 1), out_axes=1)(emat, x)
+# Edges counted per pass over the matrix: up to this many compares are
+# unrolled into one elementwise fusion; a wider edge matrix adds one pass
+# (x read, count read and written) per further block of this many.
+_EDGE_BLOCK = 32
 
 
-def _digitize(x, emat, nbins, dtype):
-    codes = _searchsorted_cols(emat, x)
-    codes = jnp.where(jnp.isnan(x), nbins, codes)
-    return codes.astype(dtype)
+@partial(jax.jit, static_argnames=("dtype",))
+def _digitize(x, emat, nbins, *, dtype):
+    """f32 [rows, F] matrix and inf-padded edges [F, E] -> codes [rows, F]
+    of ``dtype``, as ONE device program: code = the number of the
+    feature's edges <= x (ties go right, +inf counts every pad lane and
+    lands in the shared lane ``max_e``), NaN -> ``nbins`` (a traced
+    scalar: one executable serves every bin count of a dtype).
+
+    A count, not a search: compares and adds are elementwise along rows,
+    so the program has no gather and, up to ``_EDGE_BLOCK`` edges, no
+    loop; ``jnp.searchsorted``'s default method is a ``while`` of
+    log2(E) gathers, and a gather over 10M rows costs ~10 ns an element
+    on a TPU (14 s of a 20 s default GBM train, PERF.md PR 28). Wider
+    edge matrices (identity-binned categoricals, E up to 1023) add the
+    same compares a block of edges at a time, so temporaries stay
+    O(rows * F) on every backend - a broadcast [rows, F, E] compare
+    reduced over E is materialised whole by XLA's CPU backend."""
+    E = emat.shape[1]
+
+    def count(acc, cols):                  # cols [F, k]: k unrolled compares
+        for j in range(cols.shape[1]):
+            acc = acc + (cols[:, j] <= x)
+        return acc
+
+    acc = jnp.zeros(x.shape, jnp.int32)
+    full = (E - 1) // _EDGE_BLOCK          # whole blocks before the last
+    if full:
+        acc = jax.lax.fori_loop(
+            0, full, lambda i, a: count(a, jax.lax.dynamic_slice_in_dim(
+                emat, i * _EDGE_BLOCK, _EDGE_BLOCK, axis=1)), acc)
+    acc = count(acc, emat[:, full * _EDGE_BLOCK:])
+    return jnp.where(jnp.isnan(x), nbins, acc).astype(dtype)
 
 
 def digitize_with_edges(X, edges: List[np.ndarray], nbins: int) -> jax.Array:
     """Digitise a new matrix with previously-computed edges (validation /
-    scoring frames share the training sketch, like XGBoost's global hist)."""
+    scoring frames share the training sketch, like XGBoost's global hist).
+    The device counts, per value, the feature's inf-padded edges <= it
+    (:func:`_digitize`); the result equals ``np.searchsorted(edges_f,
+    col, side="right")`` with NaN -> ``nbins`` for every element, which
+    is what the host digitise (:func:`digitize_codes_host`) computes."""
     F = len(edges)
     max_e = max((len(e) for e in edges), default=0)
     emat = np.full((F, max(max_e, 1)), np.inf, dtype=np.float32)
@@ -531,7 +564,7 @@ def digitize_with_edges(X, edges: List[np.ndarray], nbins: int) -> jax.Array:
         emat[f, : len(e)] = e
     dtype = jnp.uint8 if nbins < 256 else jnp.int32
     return _digitize(jnp.asarray(X, dtype=jnp.float32), jnp.asarray(emat),
-                     nbins, dtype)
+                     np.int32(nbins), dtype=dtype)
 
 
 def split_threshold(bm: BinnedMatrix, feature: int, bin_idx: int) -> float:

@@ -427,6 +427,124 @@ def test_host_sketch_matches_bin_matrix_and_device_digitise():
     np.testing.assert_array_equal(host[~na], dev[~na])
 
 
+# ------------------------------------ the device digitise is a count
+
+
+def _emat(edges):
+    """The inf-padded edge matrix ``digitize_with_edges`` builds."""
+    out = np.full((len(edges), max(max(map(len, edges)), 1)), np.inf,
+                  np.float32)
+    for f, e in enumerate(edges):
+        out[f, : len(e)] = e
+    return out
+
+
+def _searchsorted_truth(X, edges, nbins):
+    em = _emat(edges)
+    want = np.stack([np.searchsorted(em[f], X[:, f], side="right")
+                     for f in range(X.shape[1])], axis=1)
+    return np.where(np.isnan(X), nbins, want)
+
+
+# (widest feature's edge count, nbins): 32/33 and 64/65 straddle the
+# edge block of the count; nbins < 256 gives uint8 codes, above int32
+_DIGITISE_CASES = [(0, 20), (1, 20), (13, 14), (13, 20), (19, 20),
+                   (19, 300), (32, 40), (33, 40), (64, 255), (65, 70),
+                   (254, 255), (254, 1024), (1023, 1024)]
+
+
+@pytest.mark.parametrize("max_e,nbins", _DIGITISE_CASES)
+def test_device_digitise_equals_numpy_searchsorted(max_e, nbins):
+    """Every element of the device digitise equals numpy's
+    ``searchsorted(side="right")`` on the inf-padded edges with NaN ->
+    nbins: ragged edge counts (the widest, none, one, half), NaN, +-inf,
+    signed zeros, values exactly on edges, and a row count no tile
+    divides."""
+    from h2o3_tpu.ops.binning import digitize_with_edges
+    rng = np.random.default_rng(100 + max_e)
+    rows = 1003
+    counts = [max_e, 0, min(1, max_e), max_e // 2, max_e]
+    edges = [np.unique(rng.normal(size=4 * n + 4).astype(np.float32))[:n]
+             for n in counts]
+    assert [len(e) for e in edges] == counts
+    X = rng.normal(size=(rows, len(edges))).astype(np.float32)
+    X[rng.random(X.shape) < 0.05] = np.nan
+    X[3], X[4], X[5], X[6] = np.inf, -np.inf, 0.0, -0.0
+    for f, e in enumerate(edges):           # ties: values ON an edge
+        if len(e):
+            X[10:10 + min(len(e), 200), f] = e[:200]
+    got = np.asarray(digitize_with_edges(X, edges, nbins))
+    assert got.dtype == (np.uint8 if nbins < 256 else np.int32)
+    np.testing.assert_array_equal(got, _searchsorted_truth(X, edges, nbins))
+    assert (got[3] == max(max_e, 1)).all()  # +inf: the shared pad lane
+
+
+@pytest.mark.parametrize("nbins", [20, 300])
+@pytest.mark.parametrize("value,codes", [
+    (np.nan, None),                 # NA bin on every feature
+    (np.inf, [3, 3, 3]),            # every pad lane counts: lane max_e
+    (-np.inf, [0, 0, 0]),
+    (2.0, [2, 0, 0]),               # on an edge: ties go right
+    (np.nextafter(np.float32(2.0), np.float32(0)), [1, 0, 0]),
+    (5.0, [3, 0, 1]),
+    (-0.0, [0, 0, 0]),
+])
+def test_device_digitise_by_hand(value, codes, nbins):
+    """Edges [1, 2, 3], none, [5]: the codes written out by hand."""
+    from h2o3_tpu.ops.binning import digitize_with_edges
+    edges = [np.array([1, 2, 3], np.float32), np.empty(0, np.float32),
+             np.array([5], np.float32)]
+    X = np.full((7, 3), value, np.float32)
+    got = np.asarray(digitize_with_edges(X, edges, nbins))
+    want = [nbins] * 3 if codes is None else codes
+    assert got.dtype == (np.uint8 if nbins < 256 else np.int32)
+    np.testing.assert_array_equal(got, np.tile(want, (7, 1)))
+
+
+def test_device_digitise_is_one_program_without_gather_or_loop():
+    """The guard against a silent return to a gather loop
+    (``jnp.searchsorted``'s default method) on a JAX upgrade: at the
+    default shape class (28 features, 19 edges, uint8) the compiled
+    digitise holds no ``while`` and no ``gather``; a whole
+    ``digitize_with_edges`` call is ONE executable from the f32 matrix
+    to the codes (no eager select or cast after it), and a second call
+    with the same shapes compiles and traces nothing."""
+    from h2o3_tpu import telemetry
+    from h2o3_tpu.ops import binning
+    rng = np.random.default_rng(28)
+    rows, F, E = 4099, 28, 19
+    X = jnp.asarray(rng.normal(size=(rows, F)).astype(np.float32))
+    edges = [np.sort(rng.normal(size=E).astype(np.float32))
+             for _ in range(F)]
+    first = []
+    with count_compiles(first):
+        cold = binning.digitize_with_edges(X, edges, 20)
+    assert len(first) == 1, f"digitise ran {len(first)} programs"
+    hlo = binning._digitize.lower(
+        X, jnp.asarray(_emat(edges)), np.int32(20),
+        dtype=jnp.uint8).compile().as_text()
+    assert "while(" not in hlo and "gather(" not in hlo
+    assert " u8[4099,28]" in hlo            # the codes leave it narrowed
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    telemetry.install()
+    try:
+        compiles = telemetry.registry().value("h2o3_xla_compiles_total")
+        telemetry.clear_spans()
+        with telemetry.span("t.digitize") as warm_span:
+            # another bin count of the same dtype: nbins is traced
+            warm = binning.digitize_with_edges(X, edges, 21)
+        assert telemetry.registry().value("h2o3_xla_compiles_total") \
+            == compiles
+        assert not [s for s in telemetry.finished_spans()
+                    if s.name.startswith("jit.")
+                    and s.parent_id == warm_span.span_id]
+    finally:
+        telemetry.set_enabled(was)
+    assert cold.dtype == warm.dtype == jnp.uint8
+    np.testing.assert_array_equal(np.asarray(cold), np.asarray(warm))
+
+
 # ------------------------------------------------- sharded (slow tier)
 
 
