@@ -1,7 +1,6 @@
 PARAM_DEFAULTS = {
     'categorical_encoding': 'auto',
     'quiet_mode': True,
-    'min_child_weight': 1.0,
     'eta': 0.3,
     'subsample': 1.0,
     'colsample_bylevel': 1.0,
@@ -33,7 +32,7 @@ PARAM_DEFAULTS = {
     'calibration_method': 'auto',
     'ntrees': 50,
     'max_depth': 6,
-    'min_rows': 1.0,
+    'min_rows': 0.0,
     'learn_rate': 0.3,
     'learn_rate_annealing': 1.0,
     'sample_rate': 1.0,
@@ -64,6 +63,7 @@ PARAM_DEFAULTS = {
     'hist_kernel': 'auto',
     'histogram_precision': 'auto',
     'packed_codes': 'auto',
+    'min_child_weight': 1.0,
     'reg_alpha': 0.0,
 }
 
@@ -106,15 +106,6 @@ class GeneratedH2OXGBoostEstimator(_Base):
     @quiet_mode.setter
     def quiet_mode(self, value):
         self.params['quiet_mode'] = value
-
-    @property
-    def min_child_weight(self):
-        """Type: float; default: 1.0."""
-        return self.params.get('min_child_weight')
-
-    @min_child_weight.setter
-    def min_child_weight(self, value):
-        self.params['min_child_weight'] = value
 
     @property
     def eta(self):
@@ -397,7 +388,7 @@ class GeneratedH2OXGBoostEstimator(_Base):
 
     @property
     def min_rows(self):
-        """Type: float; default: 1.0."""
+        """Type: float; default: 0.0."""
         return self.params.get('min_rows')
 
     @min_rows.setter
@@ -673,6 +664,15 @@ class GeneratedH2OXGBoostEstimator(_Base):
     @packed_codes.setter
     def packed_codes(self, value):
         self.params['packed_codes'] = value
+
+    @property
+    def min_child_weight(self):
+        """Type: float; default: 1.0."""
+        return self.params.get('min_child_weight')
+
+    @min_child_weight.setter
+    def min_child_weight(self, value):
+        self.params['min_child_weight'] = value
 
     @property
     def reg_alpha(self):

@@ -30,6 +30,7 @@ from h2o3_tpu.models.model_base import (Model, ModelBuilder, ScoreKeeper,
 from h2o3_tpu.models.tree import (ADAPTIVE_HIST_TYPES,
                                   TreeConfig, adaptive_feasible,
                                   adaptive_setup, binned_feasible,
+                                  binned_method,
                                   packed_bins_upper_bound,
                                   chunk_bucket,
                                   collect_chunk_trees, grow_tree,
@@ -37,6 +38,7 @@ from h2o3_tpu.models.tree import (ADAPTIVE_HIST_TYPES,
                                   levels_per_pass,
                                   packed_codes_requested, predict_binned,
                                   predict_raw_stacked, predict_raw_tree)
+from h2o3_tpu.ops.hist_adaptive import binned_level_plan
 from h2o3_tpu.ops.binning import (CodesView, bin_matrix_device,
                                   digitize_with_edges, make_codes_view,
                                   pack_codes, pack_codes_for,
@@ -560,6 +562,8 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                                      min_split_improvement=float(p["min_split_improvement"]),
                                      reg_lambda=float(p.get("reg_lambda", 0.0)),
                                      reg_alpha=float(p.get("reg_alpha", 0.0)),
+                                     min_child_weight=float(
+                                         p.get("min_child_weight", 0.0)),
                                      col_rate_change=float(
                                          p.get("col_sample_rate_change_per_level",
                                                1.0) or 1.0),
@@ -570,6 +574,10 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                     root_lo = jnp.zeros(cfg.n_features, jnp.float32)
                     root_hi = jnp.zeros(cfg.n_features, jnp.float32)
                     nb_f = jnp.zeros(cfg.n_features, jnp.float32)
+            # the level kernel, feature block and row tile the packed
+            # levels will run: for the loop span and the model's record
+            level_plan = binned_level_plan(
+                pc.W, cfg.n_features, binned_method(cfg)) if packed else None
             # the work above is dispatched, not done: wait for it here so
             # bin_s carries it. The loop-entry fence absorbed it otherwise,
             # in no span at all (about 11 s of a 13.5 s warm train at
@@ -1014,6 +1022,9 @@ class H2OGradientBoostingEstimator(ModelBuilder):
             jax.block_until_ready(margin)  # h2o3-lint: allow[transfer-seam] train-loop timing fence: the loop span must cover device completion, not dispatch
             if sp_loop is not None:
                 sp_loop.attrs.update(trees=built, chunks=chunks)
+                if packed:
+                    sp_loop.attrs.update(W=pc.W, code_bytes=pc.itemsize,
+                                         **level_plan)
         with prof.phase("finalize"):
             model = self._finalize(spec, valid_spec, dist_name, f0,
                                    all_trees, bm, cfg, K, built, margin,
@@ -1055,7 +1066,7 @@ class H2OGradientBoostingEstimator(ModelBuilder):
             packed, dtype=pc.rm.dtype if packed else None,
             W=pc.W if packed else None,
             bytes_per_value=pc.itemsize if packed else None,
-            n_bins=bm.n_bins if packed else None)
+            n_bins=bm.n_bins if packed else None, plan=level_plan)
         # the dense chunk body traces its whole level loop into ONE
         # executable — every level rides a single dispatch (the fused
         # shape the streamed driver's L-level windows approximate)
@@ -1163,6 +1174,7 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                 min_split_improvement=float(p["min_split_improvement"]),
                 reg_lambda=float(p.get("reg_lambda", 0.0)),
                 reg_alpha=float(p.get("reg_alpha", 0.0)),
+                min_child_weight=float(p.get("min_child_weight", 0.0)),
                 hist_method=p.get("hist_kernel", "auto"),
                 histogram_precision=str(
                     p.get("histogram_precision", "auto")).lower())

@@ -55,6 +55,9 @@ class TreeConfig:
     min_split_improvement: float = 1e-5
     reg_lambda: float = 0.0
     reg_alpha: float = 0.0   # L1 on leaf values (xgboost semantics)
+    # XGBoost's min_child_weight: each child's HESSIAN sum must reach it
+    # (0 = no such bound; min_rows bounds the row weights)
+    min_child_weight: float = 0.0
     mtries: int = 0          # >0: random feature subset PER NODE per level
                              # (DRF mtries, hex/tree/drf/DRF.java)
     # col_sample_rate_change_per_level (hex/tree/DTree.java:57):
@@ -141,6 +144,9 @@ def _find_splits(trip, cfg: TreeConfig, col_mask, mono=None,
         gain = (_leaf_score2(gl, hl, cfg) + _leaf_score2(gr, hr, cfg)
                 - parent[..., None])
         ok = (wl >= cfg.min_rows) & (wr >= cfg.min_rows)
+        if cfg.min_child_weight > 0:
+            ok = (ok & (hl >= cfg.min_child_weight)
+                  & (hr >= cfg.min_child_weight))
         if mono is not None:
             c = mono.astype(jnp.float32)[None, :, None]      # [1,F,1]
             vl = _leaf_value(gl, hl, cfg)
@@ -535,6 +541,8 @@ def adaptive_setup(spec, params, max_depth: int, mtries: int = 0):
                      min_split_improvement=float(p["min_split_improvement"]),
                      reg_lambda=float(p.get("reg_lambda", 0.0)),
                      reg_alpha=float(p.get("reg_alpha", 0.0)),
+                     min_child_weight=float(
+                         p.get("min_child_weight", 0.0)),
                      mtries=mtries,
                      col_rate_change=float(
                          p.get("col_sample_rate_change_per_level", 1.0)
@@ -895,6 +903,12 @@ def _fused_binned_window(cfg: TreeConfig, d0: int, Lw: int, W: int,
     return jax.jit(window)
 
 
+def binned_method(cfg: TreeConfig) -> str:
+    """``hist_kernel`` as the packed level's dispatch reads it."""
+    return (cfg.hist_method if cfg.hist_method in ("pallas", "scatter")
+            else "scatter" if cfg.hist_method == "matmul" else "auto")
+
+
 def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
                      axis_name=None, key=None, mono=None, sets=None,
                      model_axis=None, ct=None):
@@ -927,8 +941,7 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
     M = cfg.n_nodes
     rows, F = codes_rm.shape
     W = pick_W(cfg.n_bins)
-    method = (cfg.hist_method if cfg.hist_method in ("pallas", "scatter")
-              else "scatter" if cfg.hist_method == "matmul" else "auto")
+    method = binned_method(cfg)
     mxu_dtype = _hist_mxu_dtype(cfg, rows)
     find_cfg = dc_replace(cfg, n_bins=W - 1)   # NA lane at W-1
 

@@ -15,14 +15,31 @@ shared TreeConfig/GBM knobs:
   subsample            -> sample_rate
   colsample_bytree     -> col_sample_rate_per_tree
   colsample_bylevel    -> col_sample_rate
-  max_bins             -> nbins
+  max_bins             -> nbins = max_bins - 2 real bins. The default 256
+                          is 254 real bins and the NA lane in a 256-lane
+                          (W=256) int16 code: the packed level kernel's
+                          widest shape (ops/hist_adaptive.py)
   min_split_improvement<- gamma
-  reg_lambda (1.0)     -> L2 on leaf values  (XGBoost default, not 0)
-  reg_alpha            -> L1 soft-threshold on leaf values
-  min_child_weight     -> min_rows (hessian-weight bound approximated by
-                          the row-weight bound, exact for unit hessians)
-  tree_method auto/hist-> uniform_adaptive / quantiles_global histograms
+  reg_lambda (1.0)     -> L2 in gain and leaf values: G²/(H+λ), -η·T(G)/(H+λ)
+                          (XGBoost default, not 0)
+  reg_alpha            -> L1 soft-threshold T on G in gain and leaf values
+  min_child_weight     -> TreeConfig.min_child_weight: each child's HESSIAN
+                          sum must reach it, as XGBoost defines it (for a
+                          bernoulli response a row's hessian is p(1-p) <=
+                          1/4, so this is at least 4x a row count).
+                          ``min_rows`` is H2O-3's alias for it, as in
+                          XGBoostParameters: either spelling sets the
+                          hessian bound, the two given with different
+                          values raise, and no bound on a child's row
+                          count remains (``params["min_rows"]`` is 0)
+  tree_method hist/approx -> quantiles_global: one global quantile sketch,
+                          features binned once into packed codes (exact
+                          sorted quantiles, not XGBoost's weighted sketch)
+  tree_method auto/exact  -> uniform_adaptive per-node histograms, 62 bins
   booster              -> gbtree only (dart/gblinear raise)
+
+``f0`` is the GBM prior (the response's log-odds), not XGBoost's
+``base_score=0.5``.
 """
 from __future__ import annotations
 
@@ -69,6 +86,12 @@ class H2OXGBoostEstimator(H2OGradientBoostingEstimator):
                     return params[nm]
             return default
 
+        if ("min_rows" in params and "min_child_weight" in params
+                and params["min_rows"] != params["min_child_weight"]):
+            raise ValueError(
+                "min_rows is an alias of min_child_weight: "
+                f"{params['min_rows']!r} and {params['min_child_weight']!r} "
+                f"cannot both hold")
         max_bins = int(pick("max_bins", "nbins", default=256))
         gbm_params = dict(GBM_DEFAULTS)
         gbm_params.update(dict(
@@ -86,7 +109,9 @@ class H2OXGBoostEstimator(H2OGradientBoostingEstimator):
             # the full global-sketch bin budget
             nbins=(min(max_bins - 2, 62) if hist == "uniform_adaptive"
                    else min(max_bins - 2, 1022)),
-            min_rows=float(pick("min_child_weight", "min_rows", default=1.0)),
+            min_rows=0.0,
+            min_child_weight=float(
+                pick("min_child_weight", "min_rows", default=1.0)),
             min_split_improvement=float(
                 pick("gamma", "min_split_improvement", default=0.0)),
             reg_lambda=float(pick("reg_lambda", default=1.0)),

@@ -185,10 +185,17 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
                           histogram_type=histogram_type, with_t=with_t,
                           X_host=np.asarray(telemetry.device_get(
                               X, pipeline="train"), np.float32))
-    phase = prof.phase if prof is not None else contextlib.nullcontext
-    with phase("bin.sketch"):
+    phase = (prof.phase if prof is not None
+             else lambda name: contextlib.nullcontext())
+    with phase("bin.sketch") as sp:
         edges, n_bins_eff = _device_sketch_edges(
             X, is_cat, nrow, nbins, nbins_cats, histogram_type)
+        if sp is not None:
+            # which edge rule the sort served, and the widest edge list
+            sp.attrs.update(
+                edges=("uniform" if histogram_type in (
+                    "uniform_adaptive", "uniform") else "quantile"),
+                n_edges=max((len(e) for e in edges), default=0))
     with phase("bin.digitize"):
         codes = make_codes_view(digitize_with_edges(X, edges, n_bins_eff),
                                 with_t=with_t)
@@ -368,15 +375,17 @@ def digitize_codes_host(X_host, edges: List[np.ndarray], n_bins_eff: int):
 
 def packed_codes_record(enabled: bool, dtype=None, W: int = None,
                         bytes_per_value: int = None,
-                        n_bins: int = None) -> dict:
+                        n_bins: int = None, plan: dict = None) -> dict:
     """The ONE spelling of ``model.output['packed_codes']`` — GBM dense,
     GBM streamed and DRF all emit it through here so bench.py /
-    profile_train.py key parsing can never meet a drifted copy."""
+    profile_train.py key parsing can never meet a drifted copy. ``plan``
+    (``hist_adaptive.binned_level_plan``) names the level kernel as the
+    device trace does, with its feature block and row tile."""
     if not enabled:
         return {"enabled": False}
     return {"enabled": True, "dtype": str(np.dtype(dtype)), "W": int(W),
             "bytes_per_value": int(bytes_per_value), "n_bins": int(n_bins),
-            "kernel": "binned_level"}
+            "kernel": "binned_level", **(plan or {})}
 
 
 def make_codes_view(codes_rm, tile: int = 2048, mesh=None,

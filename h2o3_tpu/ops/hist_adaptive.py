@@ -1362,6 +1362,16 @@ def binned_level_kernel(W: int, F: int, method: str = "auto") -> str:
     return "binned_level_tpu_t"
 
 
+def binned_level_plan(W: int, F: int, method: str = "auto") -> dict:
+    """What the levels of a dense packed train run, for its records
+    (``model.output["packed_codes"]``, the ``train.loop`` span): the
+    kernel as the device trace names it, and its blocking: every body
+    takes all F features in one block, ``TILE`` rows a grid step."""
+    body = binned_level_kernel(W, F, method)
+    return {"kernel": body, "feature_block": F,
+            "row_tile": 0 if body == "binned_level_xla" else TILE}
+
+
 def binned_level(codes_rm, nid, ghw, tables, n_prev: int, n_nodes: int,
                  level_base: int, W: int, method: str = "auto",
                  mxu_dtype=jnp.bfloat16, ct=None, qs=None):

@@ -114,8 +114,25 @@ def test_xgboost_estimator_param_mapping():
     assert p["col_sample_rate_per_tree"] == 0.7
     assert p["reg_lambda"] == 2.0
     assert p["reg_alpha"] == 0.1
-    assert p["min_rows"] == 3.0
+    # XGBoost's bound on a child's hessian sum, not a row count
+    assert p["min_child_weight"] == 3.0 and p["min_rows"] == 0.0
     assert p["min_split_improvement"] == 0.01
+
+
+@pytest.mark.parametrize("given,want", [
+    ({}, 1.0), ({"min_rows": 5.0}, 5.0), ({"min_child_weight": 2.0}, 2.0),
+    ({"min_rows": 4.0, "min_child_weight": 4.0}, 4.0),
+    ({"min_rows": 10.0, "min_child_weight": 1.0}, None)])
+def test_xgboost_min_rows_is_an_alias_of_min_child_weight(given, want):
+    """Either spelling sets the hessian bound and no row-count bound is
+    left; the two given with different values raise."""
+    if want is None:
+        with pytest.raises(ValueError, match="alias"):
+            H2OXGBoostEstimator(**given)
+        return
+    p = H2OXGBoostEstimator(**given).params
+    assert (p["min_child_weight"], p["min_rows"]) == (want, 0.0)
+    assert "min_child_weight" not in H2OXGBoostEstimator()._compat_defaults
 
 
 def test_xgboost_trains_binomial():

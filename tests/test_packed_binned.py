@@ -31,7 +31,8 @@ from h2o3_tpu.models.tree import (TreeConfig, binned_feasible, grow_tree,
 from h2o3_tpu.ops.binning import (_edges_host, bin_matrix,
                                   digitize_codes_host, pack_codes,
                                   pack_codes_for)
-from h2o3_tpu.ops.hist_adaptive import (binned_level_tpu_i8,
+from h2o3_tpu.ops.hist_adaptive import (binned_level_plan,
+                                        binned_level_tpu_i8,
                                         binned_level_tpu_t,
                                         binned_level_xla,
                                         binned_route_only_tpu_t,
@@ -125,6 +126,101 @@ def test_code_dtype_and_feasibility():
     assert code_dtype(256) == jnp.int16
     assert binned_feasible(14, 28, 6)
     assert not binned_feasible(300, 28, 6)       # past the lane cap
+
+
+# ------------------------------- W=256, int16 codes (XGBoost hist)
+
+
+def _wide_inputs(F, N, rows=1536, pad_rows=512, seed=0):
+    """int16 codes with the NA lane at W=256, integer g (every f32 sum
+    exact), all-NA pad rows with no mass, ``N`` nodes at their level."""
+    W = 256
+    rng = np.random.default_rng(seed + 31 * F + N)
+    codes = rng.integers(0, 254, size=(rows + pad_rows, F)).astype(np.int16)
+    codes[rng.random(codes.shape) < 0.05] = W - 1
+    codes[rows:] = W - 1
+    n_prev, base = N // 2, N - 1
+    nid = (base - n_prev + rng.integers(0, max(n_prev, 1), rows + pad_rows)
+           ).astype(np.int32)
+    nid[rows:] = 0                                   # pads sit at the root
+    ghw = np.stack([rng.integers(-8, 9, rows + pad_rows),
+                    rng.integers(1, 4, rows + pad_rows),
+                    np.ones(rows + pad_rows)]).astype(np.float32)
+    ghw[:, rows:] = 0.0
+    n = max(n_prev, 1)
+    tables = (jnp.asarray(rng.integers(0, F, n).astype(np.float32)),
+              jnp.asarray(rng.integers(1, 254, n).astype(np.float32)),
+              jnp.asarray((rng.random(n) < 0.5).astype(np.float32)),
+              jnp.ones(n, jnp.float32))
+    return (jnp.asarray(codes), jnp.asarray(codes.T), jnp.asarray(nid),
+            jnp.asarray(ghw), tables, n_prev, base)
+
+
+@pytest.mark.parametrize("N", [1, 2, 4, 8, 16, 32])
+@pytest.mark.parametrize("F", [28, 5, 1])
+def test_level_equals_scatter_at_w256_int16(F, N):
+    """254 bins and the NA lane in 256 lanes of int16 codes: bit-equal
+    histograms and node ids to the scatter reference at every level of a
+    depth-6 tree, pad rows carrying nothing."""
+    codes, ct, nid, ghw, tables, n_prev, base = _wide_inputs(F, N)
+    assert ct.dtype == code_dtype(256) == jnp.int16
+    nid_t, hist_t = binned_level_tpu_t(
+        ct, nid, ghw, tables, n_prev, N, base, 256, tile=512,
+        interpret=True, mxu_dtype=jnp.float32)
+    nid_x, hist_x = binned_level_xla(codes, nid, ghw, tables, n_prev, N,
+                                     base, 256)
+    np.testing.assert_array_equal(np.asarray(nid_t), np.asarray(nid_x))
+    np.testing.assert_array_equal(np.asarray(hist_t), np.asarray(hist_x))
+    assert float(hist_x[2].sum()) == 1536.0 * F
+
+
+@pytest.mark.parametrize("F", [28, 5, 1])
+def test_route_only_and_leaf_totals_at_int16_w256(F):
+    from h2o3_tpu.models.tree import _segment_totals
+    N = 64
+    codes, ct, nid, ghw, tables, n_prev, base = _wide_inputs(F, N, seed=3)
+    r_t = binned_route_only_tpu_t(ct, nid, tables, n_prev, base, 256,
+                                  tile=512, interpret=True)
+    r_x = binned_route_only_xla(codes, nid, tables, n_prev, base, 256)
+    np.testing.assert_array_equal(np.asarray(r_t), np.asarray(r_x))
+    tot = []
+    for routed in (r_t, r_x):
+        local = routed - base
+        inside = (local >= 0) & (local < N)
+        tot.append(_segment_totals(jnp.clip(local, 0, N - 1), inside,
+                                   ghw[0], ghw[1], ghw[2], N))
+    for a, b in zip(*tot):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert float(tot[0][2].sum()) == 1536.0          # pad rows carry nothing
+
+
+@pytest.mark.parametrize("W,F,method,kernel,tile", [
+    (256, 28, "pallas", "binned_level_tpu_t", 8192),
+    (32, 28, "pallas", "binned_level_tpu_t", 8192),
+    (16, 1, "pallas", "binned_level_tpu_t", 8192),
+    (256, 28, "scatter", "binned_level_xla", 0)])
+def test_level_plan_names_what_the_dispatch_runs(monkeypatch, W, F, method,
+                                                 kernel, tile):
+    from h2o3_tpu.ops import hist_adaptive as ha
+    monkeypatch.setattr(ha, "TILE", 8192)        # the chip's, whatever the env
+    assert binned_level_plan(W, F, method) == {
+        "kernel": kernel, "feature_block": F, "row_tile": tile}
+    assert kernel == ha.binned_level_kernel(W, F, method)
+
+
+@pytest.mark.parametrize("n_bins,F,depth,want", [
+    (254, 28, 6, True),      # 2 x [96, 7168] f32 = 5.5 MB
+    (254, 28, 10, True),     # 88 MB
+    (254, 28, 11, False),    # 176 MB
+    (254, 300, 7, False),    # 118 MB
+    (254, 300, 6, True),     # 59 MB
+    (30, 28, 13, True),      # W=32: 88 MB
+    (30, 28, 14, False)])
+def test_feasible_counts_both_accumulators(n_bins, F, depth, want):
+    """Scratch and output block [3 * 2^(D-1), F * W] f32 against 96 MiB:
+    the count that held on the chip at F=28, W=256, depth 6 (PERF.md,
+    PR 29: Mosaic streams the one-hot and never holds it whole)."""
+    assert binned_feasible(n_bins, F, depth) is want
 
 
 # ------------------------------------------- grower vs grow_tree parity

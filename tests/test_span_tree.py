@@ -89,6 +89,14 @@ def test_a_train_leaves_the_span_tree_with_the_right_parents(warm_train):
     assert len(named["train.bin"]) == len(named["train.loop"]) == 1
     loop = named["train.loop"][0]
     assert loop.attrs["trees"] == 6 and loop.attrs["chunks"] == 2
+    # the packed path says what its levels ran, as the model's record does
+    pc = est.model.output["packed_codes"]
+    for key in ("W", "kernel", "feature_block", "row_tile"):
+        assert loop.attrs[key] == pc[key], key
+    assert loop.attrs["code_bytes"] == pc["bytes_per_value"] == 1
+    assert (pc["W"], pc["feature_block"]) == (32, FEATURES)
+    sketch = named["train.bin.sketch"][0]
+    assert (sketch.attrs["edges"], sketch.attrs["n_edges"]) == ("uniform", 19)
     # the stages follow one another inside train.train
     order = [named[n][0] for n in ("train.bin.sketch", "train.bin.digitize",
                                    "train.bin.pack", "train.loop",

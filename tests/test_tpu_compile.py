@@ -62,13 +62,15 @@ def _level_operands(code_dtype, n_prev):
             ((3, ROWS), jnp.float32), *_tables(n_prev)]
 
 
-def _binned_t(level):
+def _binned_t(level, width=W, code_dtype=jnp.int8):
+    """``width`` 256 with int16 codes is XGBoost-hist's shape: 254 bins
+    and the NA lane."""
     n_prev, n_nodes, base = level
 
     def fn(ct, nid, ghw, *tables):
         return ha.binned_level_tpu_t(ct, nid, ghw, tables, n_prev, n_nodes,
-                                     base, W, tile=ha.TILE)
-    return fn, _level_operands(jnp.int8, n_prev)
+                                     base, width, tile=ha.TILE)
+    return fn, _level_operands(code_dtype, n_prev)
 
 
 def _binned_stripe(level, mxu_dtype=jnp.bfloat16):
@@ -82,11 +84,11 @@ def _binned_stripe(level, mxu_dtype=jnp.bfloat16):
     return fn, _level_operands(jnp.int8, n_prev)
 
 
-def _binned_route():
+def _binned_route(width=W, code_dtype=jnp.int8):
     def fn(ct, nid, *tables):
-        return ha.binned_route_only_tpu_t(ct, nid, tables, 32, 63, W,
+        return ha.binned_route_only_tpu_t(ct, nid, tables, 32, 63, width,
                                           tile=ha.TILE)
-    return fn, [((F, ROWS), jnp.int8), ((ROWS,), jnp.int32), *_tables(32)]
+    return fn, [((F, ROWS), code_dtype), ((ROWS,), jnp.int32), *_tables(32)]
 
 
 def _adaptive_t(level):
@@ -132,6 +134,8 @@ CASES = {
     "binned_level_tpu_stripe-level5-f32":
         lambda: _binned_stripe(LEVEL5, jnp.float32),
     "binned_route_only_tpu_t": _binned_route,
+    "binned_level_tpu_t-w256-level5": lambda: _binned_t(LEVEL5, 256, jnp.int16),
+    "binned_route_only_tpu_t-w256": lambda: _binned_route(256, jnp.int16),
     "adaptive_level_tpu_t-level5": lambda: _adaptive_t(LEVEL5),
     "hist_pallas3": _hist_pallas3,
     "predict_raw_stacked-bucket64": _serve_scorer,
