@@ -38,7 +38,7 @@ from h2o3_tpu.models.tree import (ADAPTIVE_HIST_TYPES,
                                   chunk_bucket,
                                   collect_chunk_trees, grow_tree,
                                   grow_tree_adaptive, grow_tree_binned,
-                                  packed_codes_requested,
+                                  node_lookup, packed_codes_requested,
                                   predict_raw_stacked)
 from h2o3_tpu.ops.binning import (CodesView, bin_matrix_device,
                                   make_codes_view, pack_codes,
@@ -232,7 +232,7 @@ def _drf_chunk_body(codes_rm, codes_t, y, w, oob_num, oob_cnt, base_key,
         if K == 1:
             yf = y.astype(jnp.float32)
             tree, nid = build(-(yf * wt), wt, wt, col_mask, key_m)
-            pred = tree["value"][nid]
+            pred = node_lookup(tree["value"], nid)
             oob_num = oob_num + jnp.where(live_oob, pred, 0.0)
             oob_cnt = oob_cnt + live_oob.astype(jnp.float32)
             trees.append(tree)
@@ -242,7 +242,7 @@ def _drf_chunk_body(codes_rm, codes_t, y, w, oob_num, oob_cnt, base_key,
                 yk = (y == k).astype(jnp.float32)
                 tree, nid = build(-(yk * wt), wt, wt, col_mask,
                                   jax.random.fold_in(key_m, k))
-                preds.append(tree["value"][nid])
+                preds.append(node_lookup(tree["value"], nid))
                 trees.append(tree)
             pk = jnp.stack(preds, axis=1)
             oob_num = oob_num + jnp.where(live_oob[:, None], pk, 0.0)

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from h2o3_tpu import telemetry
+from h2o3_tpu.models.tree import node_lookup
 
 # stream-buffer depth for non-resident chunks: the upload of chunk k+1
 # rides under chunk k's level kernel (double buffer)
@@ -58,10 +59,12 @@ def _record_h2d(nbytes: int) -> None:
 @jax.jit
 def _apply_leaf(margin, lr, value, nid):
     """margin += lr · value[nid], jitted as ONE expression so XLA makes
-    the same gather+FMA fusion decision as the dense chunk body's
-    in-scan `margin + lr_t * tree["value"][nid]` — the eager two-op
-    form rounds twice and breaks dense/streamed bit parity."""
-    return margin + lr * value[nid]
+    the same lookup+FMA fusion decision as the dense chunk body's
+    in-scan `margin + lr_t * node_lookup(tree["value"], nid)` (the same
+    helper, so the same select or gather by the tree's size) — the
+    eager two-op form rounds twice and breaks dense/streamed bit
+    parity."""
+    return margin + lr * node_lookup(value, nid)
 
 
 class _ChunkHandle:

@@ -46,6 +46,69 @@ NA_SHIFT = 28
 SPLIT_SHIFT = 29
 
 
+# largest node table looked up by selection (node_lookup): the largest
+# complete tree at which the select was still 2x faster than the gather
+# on the v5e at 10M rows (31.3 against 71.6 ms at 8191 entries, 62.6
+# against 71.6 at 16383: tools/micro_node_lookup.py, PERF.md §6 "PR 30").
+NODE_SELECT_MAX = 8191
+# entries a select tree is unrolled over; larger tables loop over blocks
+_SELECT_BLOCK = 128
+
+
+def node_lookup_form(n_nodes: int) -> str:
+    """Which form node_lookup runs for a table of ``n_nodes`` entries."""
+    return "select" if n_nodes <= NODE_SELECT_MAX else "gather"
+
+
+def _select_tree(entries, idx):
+    """``entries[idx]`` for idx in [0, len(entries)) as a tree of selects
+    on idx's bits, low bit first: n - 1 selects on scalars of the table,
+    all in one elementwise fusion over the rows."""
+    vals = [entries[m] for m in range(entries.shape[0])]
+    bit = 0
+    while len(vals) > 1:
+        odd = ((idx >> bit) & 1).astype(bool)
+        # an entry with no partner passes up: no index inside the table
+        # has this bit set from there
+        vals = [jnp.where(odd, vals[j + 1], vals[j])
+                if j + 1 < len(vals) else vals[j]
+                for j in range(0, len(vals), 2)]
+        bit += 1
+    return jnp.broadcast_to(vals[0], idx.shape)
+
+
+def node_lookup(table, nid):
+    """``table[nid]`` bit for bit: a per-row lookup into a per-tree node
+    table ([M]; ``nid`` is [rows] int32 in [0, M)).
+
+    Up to NODE_SELECT_MAX entries the value is SELECTED, not gathered: a
+    tree of selects on ``nid``'s bits over the table's entries as
+    scalars, block by block above _SELECT_BLOCK entries, so it fuses into
+    elementwise passes over the rows and no [rows, M] array exists.
+    Nothing is computed on the values, so -0.0, inf, NaN and denormals
+    pass through. Left to itself XLA:TPU keeps a real gather from 65
+    entries (80 ms a tree at 10M rows and 127 nodes, against 0.4 ms
+    selected) and below that, inside the boost chunk's scan, expands it
+    into one pass over the rows per entry (31 ms at 63 nodes against
+    0.25 ms): PERF.md §6, PR 30. Larger tables (DRF's depth-16 heaps)
+    keep the gather, which does not grow with M. The rule reads M alone,
+    a static shape."""
+    M = table.shape[0]
+    if node_lookup_form(M) == "gather":
+        return table[nid]
+    B = _SELECT_BLOCK
+    if M <= B:
+        return _select_tree(table, nid)
+    n_blocks = -(-M // B)
+    blocks = jnp.pad(table, (0, n_blocks * B - M)).reshape(n_blocks, B)
+    low, high = nid & (B - 1), nid >> (B.bit_length() - 1)
+
+    def block(k, acc):
+        return jnp.where(high == k, _select_tree(blocks[k], low), acc)
+    return jax.lax.fori_loop(0, n_blocks, block,
+                             jnp.zeros(nid.shape, table.dtype))
+
+
 @dataclass(frozen=True)
 class TreeConfig:
     max_depth: int
@@ -1072,7 +1135,7 @@ def predict_raw_tree(X, tree, max_depth: int):
         xv = jnp.take_along_axis(X, f[:, None], axis=1)[:, 0]
         go_right = jnp.where(jnp.isnan(xv), ~nl, xv >= th)
         nid = jnp.where(s, 2 * nid + 1 + go_right.astype(jnp.int32), nid)
-    return tree["value"][nid], nid
+    return node_lookup(tree["value"], nid), nid
 
 
 def grow_tree_spmd(codes, g, h, w, cfg: TreeConfig, col_mask,
@@ -1199,8 +1262,10 @@ def predict_binned(codes, tree, max_depth: int, na_bin: int):
             | (tree["na_left"].astype(jnp.int32) << NA_SHIFT)
             | (tree["is_split"].astype(jnp.int32) << SPLIT_SHIFT))
     nid = jnp.zeros(rows, jnp.int32)
-    for _ in range(max_depth):
-        rw = word[nid]
+    for d in range(max_depth):
+        # before level d's step a row sits above level d + 1: only
+        # those nodes' words can be named
+        rw = node_lookup(word[:2 ** (d + 1) - 1], nid)
         f = rw & FEAT_MASK
         b = (rw >> BIN_SHIFT) & BIN_MASK
         nl = ((rw >> NA_SHIFT) & 1).astype(bool)
@@ -1210,7 +1275,7 @@ def predict_binned(codes, tree, max_depth: int, na_bin: int):
         is_na = c == na_bin
         go_right = jnp.where(is_na, ~nl, c >= b)
         nid = jnp.where(s, 2 * nid + 1 + go_right.astype(jnp.int32), nid)
-    return tree["value"][nid], nid
+    return node_lookup(tree["value"], nid), nid
 
 
 def predict_raw_stacked(X, feat, thr, na_left, is_split, value, max_depth: int):
@@ -1527,8 +1592,8 @@ def grow_tree_adaptive_streamed(chunks, dist, lr, cfg: TreeConfig,
             "is_split": is_split, "value": value, "gain": gain_arr,
             "node_w": node_w}
     # final route + margin update: one fused device pass per chunk (the
-    # deepest values stay on device — same f32 gather+FMA as the dense
-    # chunk body's `margin + lr_t * tree["value"][nid]`)
+    # deepest values stay on device — same f32 lookup+FMA as the dense
+    # chunk body's `margin + lr_t * node_lookup(tree["value"], nid)`)
     value_dev = jnp.asarray(value)
     lr_t = jnp.float32(lr)
     for ch in chunks.level_pass():

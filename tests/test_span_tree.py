@@ -91,8 +91,11 @@ def test_a_train_leaves_the_span_tree_with_the_right_parents(warm_train):
     assert loop.attrs["trees"] == 6 and loop.attrs["chunks"] == 2
     # the packed path says what its levels ran, as the model's record does
     pc = est.model.output["packed_codes"]
-    for key in ("W", "kernel", "feature_block", "row_tile"):
+    for key in ("W", "kernel", "feature_block", "row_tile", "leaf_lookup",
+                "n_nodes"):
         assert loop.attrs[key] == pc[key], key
+    # depth 3: 15 nodes, whose values the margin update selects
+    assert (pc["leaf_lookup"], pc["n_nodes"]) == ("select", 15)
     assert loop.attrs["code_bytes"] == pc["bytes_per_value"] == 1
     assert (pc["W"], pc["feature_block"]) == (32, FEATURES)
     sketch = named["train.bin.sketch"][0]

@@ -12,12 +12,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from h2o3_tpu.models.tree import predict_raw_stacked
+from h2o3_tpu.models.tree import node_lookup, predict_raw_stacked
 from h2o3_tpu.ops import hist_adaptive as ha
 from h2o3_tpu.ops import hist_pallas
 from h2o3_tpu.ops.binning import stripe_pair_codes
 
 F = 28                    # HIGGS width, the bench and chip_smoke shape
+BENCH_ROWS = 10_002_432   # the benchmark's 10M rows, padded to the tile
 W = 16                    # nbins=14 -> 16 lanes per feature
 ROWS = 8 * ha.TILE
 ROOT = (0, 1, 0)          # (n_prev, n_nodes, level_base)
@@ -151,3 +152,21 @@ def test_compiles_for_v5e(case, one_chip, no_persistent_cache):
     compiled = jax.jit(fn).lower(*shapes).compile()
     n_mosaic = compiled.as_text().count('custom_call_target="tpu_custom_call"')
     assert (n_mosaic > 0) == (case in PALLAS), (case, n_mosaic)
+
+
+@pytest.mark.parametrize("nodes", [127, 63])
+def test_margin_update_selects_the_leaf_value_on_v5e(nodes, one_chip,
+                                                     no_persistent_cache):
+    """The margin update at the benchmark's rows, depth 6 and depth 5:
+    the leaf's value is selected in a fusion, with no gather over the rows
+    (XLA:TPU keeps one from 65 entries) and no [rows, nodes] temporary."""
+    def fn(margin, lr, value, nid):
+        return margin + lr * node_lookup(value, nid)
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+              for shape, dtype in [((BENCH_ROWS,), jnp.float32),
+                                   ((), jnp.float32),
+                                   ((nodes,), jnp.float32),
+                                   ((BENCH_ROWS,), jnp.int32)]]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "gather(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * BENCH_ROWS * 4
