@@ -38,9 +38,8 @@ above). Measured: ~79M rows/s/chip — past the doubled MXU bound's
 knee, now co-limited by the one-hot build + routing VPU work. Other
 tested escapes — int8 fixed-point contraction (1.33x bare-matmul win,
 eaten by Mosaic's lack of i8 select/mul forcing i32 operand builds;
-H2O3_HIST_I8 opt-in keeps it), lane-gather range lookups (Mosaic
-declines), tile resizing (flat) — are recorded in tools/ and
-ops/hist_adaptive.py.
+removed in PR 31, git history keeps it), lane-gather range lookups
+(Mosaic declines), tile resizing (flat) — are recorded in tools/.
 
 Prints exactly one JSON line on stdout.
 """

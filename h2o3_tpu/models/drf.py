@@ -31,18 +31,12 @@ from h2o3_tpu import telemetry
 from h2o3_tpu.jobs import Job
 from h2o3_tpu.models.model_base import (Model, ModelBuilder, ScoreKeeper,
                                         TrainingSpec, compute_metrics)
-from h2o3_tpu.models.tree import (ADAPTIVE_HIST_TYPES,
-                                  TreeConfig, adaptive_feasible,
-                                  adaptive_setup, binned_feasible,
-                                  packed_bins_upper_bound,
-                                  chunk_bucket,
-                                  collect_chunk_trees, grow_tree,
-                                  grow_tree_adaptive, grow_tree_binned,
-                                  node_lookup, packed_codes_requested,
-                                  predict_raw_stacked)
-from h2o3_tpu.ops.binning import (CodesView, bin_matrix_device,
-                                  make_codes_view, pack_codes,
-                                  packed_codes_record)
+from h2o3_tpu.log import Profile
+from h2o3_tpu.models.tree import (chunk_bucket, collect_chunk_trees,
+                                  grow_tree, grow_tree_adaptive,
+                                  grow_tree_binned, node_lookup,
+                                  predict_raw_stacked, prepare_tree_inputs)
+from h2o3_tpu.ops.binning import CodesView
 from h2o3_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS, current_mesh,
                                     n_data_shards, n_model_shards,
                                     spmd_enabled)
@@ -299,78 +293,20 @@ class H2ORandomForestEstimator(ModelBuilder):
                 f"max_depth {depth} exceeds the static-tree cap "
                 f"{MAX_DEPTH_CAP} (complete-binary-array trees; the "
                 f"reference's default 20 relies on dynamic node allocation)")
-        nbins = int(p["nbins"])
-        hist_type = (p.get("histogram_type") or "uniform_adaptive").lower()
-        # packed binned-code hot path (ISSUE 12) — same gating as GBM:
-        # default wherever compiled pallas runs; 'random' keeps the
-        # adaptive kernel (per-tree grid phase needs per-level rebinning)
-        packed_req = packed_codes_requested(p) and hist_type != "random"
-        if (packed_req
-                and not binned_feasible(
-                    packed_bins_upper_bound(spec, p), spec.n_features,
-                    depth)
-                and hist_type in ADAPTIVE_HIST_TYPES
-                and adaptive_feasible(spec, p, depth)):
-            # cheap pre-gate from the cat domains (see models/gbm.py)
-            packed_req = False
-        adaptive = (hist_type in ADAPTIVE_HIST_TYPES
-                    and not packed_req
-                    and adaptive_feasible(spec, p, depth))
-        packed = False
-        pc = None
         mtries = int(p.get("mtries", -1) or -1)
         F = spec.n_features
         if mtries <= 0:
             # reference defaults: sqrt(p) classification, p/3 regression
             mtries = (max(1, int(np.sqrt(F))) if spec.nclasses > 1
                       else max(1, F // 3))
-        if adaptive:
-            bm = None
-            cfg, root_lo, root_hi, nb_f = adaptive_setup(
-                spec, p, depth, mtries=min(mtries, F))
-        else:
-            # device-side sketch (ops/binning.bin_matrix_device): no
-            # device_get of the full X
-            # packed mode skips the int32 transposed operand — the
-            # packed layouts supersede it (see models/gbm.py)
-            bm = bin_matrix_device(spec.X, spec.names,
-                                   spec.is_cat, spec.nrow, nbins=max(nbins, 2),
-                                   nbins_cats=int(p["nbins_cats"]),
-                                   histogram_type=hist_type,
-                                   with_t=not packed_req)
-            packed = (packed_req
-                      and binned_feasible(bm.n_bins, bm.n_features, depth))
-            if (not packed and packed_req
-                    and hist_type in ADAPTIVE_HIST_TYPES
-                    and adaptive_feasible(spec, p, depth)):
-                # packing infeasible (sketch bin count past the 254-lane
-                # cap / VMEM): fall back to the fused adaptive kernel,
-                # not the slow matmul path (see models/gbm.py)
-                adaptive = True
-                bm = None
-                cfg, root_lo, root_hi, nb_f = adaptive_setup(
-                    spec, p, depth, mtries=min(mtries, F))
-            if packed:
-                pc = pack_codes(bm)
-                # free the int32 code view (see models/gbm.py)
-                bm.codes = CodesView(rm=pc.rm, t=None)
-            if not adaptive:
-                cfg = TreeConfig(max_depth=depth, n_bins=bm.n_bins,
-                                 n_features=bm.n_features,
-                                 min_rows=float(p["min_rows"]),
-                                 min_split_improvement=float(p["min_split_improvement"]),
-                                 reg_lambda=float(p.get("reg_lambda", 0.0)),
-                                 mtries=min(mtries, bm.n_features),
-                                 col_rate_change=float(
-                                     p.get("col_sample_rate_change_per_level",
-                                           1.0) or 1.0),
-                                 hist_method=p.get("hist_kernel", "auto"),
-                                 histogram_precision=str(
-                                     p.get("histogram_precision",
-                                           "auto")).lower())
-                root_lo = jnp.zeros(cfg.n_features, jnp.float32)
-                root_hi = jnp.zeros(cfg.n_features, jnp.float32)
-                nb_f = jnp.zeros(cfg.n_features, jnp.float32)
+        # the bin stage every dense tree trainer shares (models/tree.py).
+        # 'random' is binned by the global sketch here: the forest's
+        # randomness is its rows and mtries, not the grid's phase
+        inputs = prepare_tree_inputs(spec, p, depth, prof=Profile(),
+                                     mtries=mtries, random_is_adaptive=False)
+        adaptive, packed = inputs.adaptive, inputs.packed
+        cfg, bm = inputs.cfg, inputs.bm
+        root_lo, root_hi, nb_f = inputs.root_lo, inputs.root_hi, inputs.nb_f
         mesh = current_mesh()
         nd = n_data_shards(mesh)
         padded = spec.X.shape[0]
@@ -387,13 +323,7 @@ class H2ORandomForestEstimator(ModelBuilder):
         ntrees_new = ntrees - start_trees
         sample_rate = float(p["sample_rate"])
         col_rate = float(p.get("col_sample_rate_per_tree", 1.0))
-        Xtr = spec.X if adaptive else (pc.rm if packed else bm.codes.rm)
-        if packed:
-            has_t = pc.t is not None
-            codes_t_arg = pc.t if has_t else Xtr
-        else:
-            has_t = (not adaptive) and bm.codes.t is not None
-            codes_t_arg = bm.codes.t if has_t else Xtr
+        Xtr, codes_t_arg, has_t, _ = inputs.operands(spec.X)
         # data-sharded from the start so every chunk (not just the 2nd+)
         # sees identically-sharded carry operands — one executable per
         # bucket (see the margin pinning note in models/gbm.py)
@@ -595,11 +525,7 @@ class H2ORandomForestEstimator(ModelBuilder):
                 from h2o3_tpu.log import warn
                 warn("drf: final in-training checkpoint failed: %s", e)
         model.output["training_loop_seconds"] = t_loop
-        model.output["packed_codes"] = packed_codes_record(
-            packed, dtype=pc.rm.dtype if packed else None,
-            W=pc.W if packed else None,
-            bytes_per_value=pc.itemsize if packed else None,
-            n_bins=bm.n_bins if packed else None)
+        model.output["packed_codes"] = inputs.record()
         # the DRF chunk body (like GBM dense) traces its whole level
         # loop into one executable — all levels per dispatch
         model.output["levels_per_dispatch"] = int(cfg.max_depth)

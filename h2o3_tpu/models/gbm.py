@@ -27,22 +27,16 @@ from h2o3_tpu.log import Profile
 from h2o3_tpu.models.distributions import get_distribution
 from h2o3_tpu.models.model_base import (Model, ModelBuilder, ScoreKeeper,
                                         TrainingSpec, compute_metrics)
-from h2o3_tpu.models.tree import (ADAPTIVE_HIST_TYPES,
-                                  TreeConfig, adaptive_feasible,
-                                  adaptive_setup, binned_feasible,
-                                  binned_method,
-                                  packed_bins_upper_bound,
-                                  chunk_bucket,
+from h2o3_tpu.models.tree import (adaptive_setup, chunk_bucket,
                                   collect_chunk_trees, grow_tree,
                                   grow_tree_adaptive, grow_tree_binned,
                                   levels_per_pass, node_lookup,
-                                  node_lookup_form,
                                   packed_codes_requested, predict_binned,
-                                  predict_raw_stacked, predict_raw_tree)
-from h2o3_tpu.ops.hist_adaptive import binned_level_plan
-from h2o3_tpu.ops.binning import (CodesView, bin_matrix_device,
-                                  digitize_with_edges, make_codes_view,
-                                  pack_codes, pack_codes_for,
+                                  predict_raw_stacked, predict_raw_tree,
+                                  prepare_tree_inputs, tree_config,
+                                  tree_path)
+from h2o3_tpu.ops.binning import (CodesView, digitize_with_edges,
+                                  make_codes_view, pack_codes_for,
                                   packed_codes_record)
 from h2o3_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS, current_mesh,
                                     n_data_shards, n_model_shards,
@@ -491,109 +485,13 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         # the stages' clock: each phase is a live span under the thread's
         # train.train span, and train_profile is their durations
         prof = Profile()
-        with prof.phase("bin"):
-            nbins = int(p["nbins"])
-            hist_type = (p.get("histogram_type") or "uniform_adaptive").lower()
-            # packed binned-code hot path (ISSUE 12): bin ONCE per train
-            # into int8/int16 codes and run the fused binned level kernel —
-            # the default wherever compiled pallas runs. histogram_type=
-            # 'random' keeps the adaptive kernel (per-tree grid phase needs
-            # per-level rebinning, which packing removes by design).
-            packed_req = packed_codes_requested(p) and hist_type != "random"
-            if (packed_req
-                    and not binned_feasible(
-                        packed_bins_upper_bound(spec, p), spec.n_features,
-                        int(p["max_depth"]))
-                    and hist_type in ADAPTIVE_HIST_TYPES
-                    and adaptive_feasible(spec, p, int(p["max_depth"]))):
-                # cheap pre-gate from the cat domains alone: packing CANNOT
-                # come in under its lane/VMEM caps, so take the adaptive
-                # kernel without paying the O(rows*F) sketch + digitise
-                packed_req = False
-            # uniform_adaptive (reference default) runs the fused per-node
-            # adaptive kernel on raw features; the global-sketch path handles
-            # quantiles_global and nbins beyond the adaptive kernel's 254 cap
-            adaptive = (hist_type in ADAPTIVE_HIST_TYPES + ("random",)
-                        and not packed_req
-                        and adaptive_feasible(spec, p, int(p["max_depth"])))
-            packed = False
-            pc = None
-            if adaptive:
-                bm = None
-                cfg, root_lo, root_hi, nb_f = adaptive_setup(
-                    spec, p, int(p["max_depth"]))
-            else:
-                # device-side sketch: X never leaves HBM (the old path
-                # device_get the whole matrix just to run np.quantile on it)
-                # packed mode skips the int32 transposed pallas operand
-                # (with_t): pack_codes supersedes it with the int8/int16
-                # layouts, and building a rows*F*4 copy just to drop it
-                # would cost the HBM the packing saves
-                bm = bin_matrix_device(spec.X, spec.names,
-                                       spec.is_cat, spec.nrow, nbins=max(nbins, 2),
-                                       nbins_cats=int(p["nbins_cats"]),
-                                       histogram_type=hist_type,
-                                       with_t=not packed_req, prof=prof)
-                packed = (packed_req
-                          and binned_feasible(bm.n_bins, bm.n_features,
-                                              int(p["max_depth"])))
-                if (not packed and packed_req
-                        and hist_type in ADAPTIVE_HIST_TYPES
-                        and adaptive_feasible(spec, p, int(p["max_depth"]))):
-                    # packing infeasible (sketch bin count past the 254-lane
-                    # cap / VMEM): fall back to the fused ADAPTIVE kernel,
-                    # not the slow matmul path the sketch would otherwise
-                    # route to
-                    adaptive = True
-                    bm = None
-                    cfg, root_lo, root_hi, nb_f = adaptive_setup(
-                        spec, p, int(p["max_depth"]))
-                if packed:
-                    with prof.phase("bin.pack"):
-                        pc = pack_codes(bm)
-                        # free the int32 code view: the packed layouts
-                        # replace it (1-2 bytes/value x2 <= half the f32 X
-                        # footprint); only bm.edges / n_bins are read from
-                        # here on
-                        bm.codes = CodesView(rm=pc.rm, t=None)
-                        # this path's bin fence (see the other paths' below),
-                        # inside the phase whose device work it waits for
-                        jax.block_until_ready(pc)  # h2o3-lint: allow[transfer-seam] bin-stage timing fence: replaces time the loop-entry fence already waited, unattributed
-                if not adaptive:
-                    cfg = TreeConfig(max_depth=int(p["max_depth"]),
-                                     n_bins=bm.n_bins,
-                                     n_features=bm.n_features,
-                                     min_rows=float(p["min_rows"]),
-                                     min_split_improvement=float(p["min_split_improvement"]),
-                                     reg_lambda=float(p.get("reg_lambda", 0.0)),
-                                     reg_alpha=float(p.get("reg_alpha", 0.0)),
-                                     min_child_weight=float(
-                                         p.get("min_child_weight", 0.0)),
-                                     col_rate_change=float(
-                                         p.get("col_sample_rate_change_per_level",
-                                               1.0) or 1.0),
-                                     hist_method=p.get("hist_kernel", "auto"),
-                                     histogram_precision=str(
-                                         p.get("histogram_precision",
-                                               "auto")).lower())
-                    root_lo = jnp.zeros(cfg.n_features, jnp.float32)
-                    root_hi = jnp.zeros(cfg.n_features, jnp.float32)
-                    nb_f = jnp.zeros(cfg.n_features, jnp.float32)
-            # the level kernel, feature block and row tile the packed
-            # levels will run, and how the margin update reads a leaf's
-            # value (by the tree's size): for the loop span and the
-            # model's record
-            level_plan = {
-                **binned_level_plan(pc.W, cfg.n_features, binned_method(cfg)),
-                "leaf_lookup": node_lookup_form(cfg.n_nodes),
-                "n_nodes": cfg.n_nodes} if packed else None
-            # the work above is dispatched, not done: wait for it here so
-            # bin_s carries it. The loop-entry fence absorbed it otherwise,
-            # in no span at all (about 11 s of a 13.5 s warm train at
-            # 10M x 28 on the v5e, PR 22)
-            if not packed:
-                jax.block_until_ready(  # h2o3-lint: allow[transfer-seam] bin-stage timing fence: replaces time the loop-entry fence already waited, unattributed
-                    (root_lo, root_hi) if adaptive else bm.codes)
+        # the bin stage every dense tree trainer shares (models/tree.py):
+        # which grower, then the sketch, digitise and pack it calls for
+        inputs = prepare_tree_inputs(spec, p, int(p["max_depth"]), prof=prof,
+                                     random_is_adaptive=True)
+        adaptive, packed = inputs.adaptive, inputs.packed
+        cfg, bm, pc = inputs.cfg, inputs.bm, inputs.pc
+        root_lo, root_hi, nb_f = inputs.root_lo, inputs.root_hi, inputs.nb_f
         y, w = spec.y, spec.w
         padded = spec.X.shape[0]
         if spec.offset is not None and K > 1:
@@ -673,7 +571,8 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         # validation margin tracked with train edges
         mesh = current_mesh()
         nd = n_data_shards(mesh)
-        Xtr = spec.X if adaptive else (pc.rm if packed else bm.codes.rm)
+        # chunk operands; na_bin is the packed codes' reserved lane W-1
+        Xtr, codes_t_arg, has_t, na_bin = inputs.operands(spec.X)
         if Xtr.shape[0] % nd != 0:
             raise ValueError(
                 f"padded row count {Xtr.shape[0]} is not divisible by "
@@ -740,14 +639,6 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                   "larger interval", ckpt_interval,
                   int(ntrees_new / ckpt_interval))
         trees_since_ckpt = 0
-        if packed:
-            has_t = pc.t is not None
-            codes_t_arg = pc.t if has_t else Xtr
-            na_bin = pc.na_bin                   # reserved lane W-1
-        else:
-            has_t = (not adaptive) and bm.codes.t is not None
-            codes_t_arg = bm.codes.t if has_t else Xtr  # dummy otherwise
-            na_bin = 0 if adaptive else bm.na_bin
         # monotone constraints ({col: ±1}, hex/tree/DTree Constraints) and
         # interaction constraints ([[col,...],...], per-branch feature
         # allowance) ride as traced arrays through the chunk step
@@ -1030,10 +921,8 @@ class H2OGradientBoostingEstimator(ModelBuilder):
 
             jax.block_until_ready(margin)  # h2o3-lint: allow[transfer-seam] train-loop timing fence: the loop span must cover device completion, not dispatch
             if sp_loop is not None:
-                sp_loop.attrs.update(trees=built, chunks=chunks)
-                if packed:
-                    sp_loop.attrs.update(W=pc.W, code_bytes=pc.itemsize,
-                                         **level_plan)
+                sp_loop.attrs.update(trees=built, chunks=chunks,
+                                     **inputs.loop_attrs())
         with prof.phase("finalize"):
             model = self._finalize(spec, valid_spec, dist_name, f0,
                                    all_trees, bm, cfg, K, built, margin,
@@ -1071,11 +960,7 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         # hot-loop representation record (ISSUE 12): what the level
         # kernel actually streamed — bench.py and profile_train.py read
         # this for the bytes/row attribution
-        model.output["packed_codes"] = packed_codes_record(
-            packed, dtype=pc.rm.dtype if packed else None,
-            W=pc.W if packed else None,
-            bytes_per_value=pc.itemsize if packed else None,
-            n_bins=bm.n_bins if packed else None, plan=level_plan)
+        model.output["packed_codes"] = inputs.record()
         # the dense chunk body traces its whole level loop into ONE
         # executable — every level rides a single dispatch (the fused
         # shape the streamed driver's L-level windows approximate)
@@ -1156,7 +1041,13 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         # kernel (per-tree grid phase needs per-level rebinning).
         from h2o3_tpu.ops.binning import _edges_host, digitize_codes_host
         hist_type = (p.get("histogram_type") or "uniform_adaptive").lower()
-        packed = packed_codes_requested(p) and hist_type != "random"
+        depth = int(p["max_depth"])
+        # the dense trainers' rule (models/tree.py); whatever does not
+        # pack streams the f32 window through the adaptive kernels
+        path = partial(tree_path, hist_type, packed_codes_requested(p),
+                       n_features=spec.n_features, max_depth=depth,
+                       adaptive_fits=True)
+        packed = path(None) == "packed"
         bin_edges = None
         W = None
         if packed:
@@ -1168,31 +1059,19 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                 bin_edges, n_bins_eff = _edges_host(
                     X_host, rows, spec.is_cat, max(int(p["nbins"]), 2),
                     int(p.get("nbins_cats", 1024)), hist_type)
-                packed = binned_feasible(n_bins_eff, spec.n_features,
-                                         int(p["max_depth"]))
+                packed = path(n_bins_eff) == "packed"
             except ValueError:
                 packed = False      # bin count past the routing cap
             if packed:
                 codes_host, W = digitize_codes_host(X_host, bin_edges,
                                                     n_bins_eff)
         if packed:
-            cfg = TreeConfig(
-                max_depth=int(p["max_depth"]), n_bins=n_bins_eff,
-                n_features=spec.n_features,
-                min_rows=float(p["min_rows"]),
-                min_split_improvement=float(p["min_split_improvement"]),
-                reg_lambda=float(p.get("reg_lambda", 0.0)),
-                reg_alpha=float(p.get("reg_alpha", 0.0)),
-                min_child_weight=float(p.get("min_child_weight", 0.0)),
-                hist_method=p.get("hist_kernel", "auto"),
-                histogram_precision=str(
-                    p.get("histogram_precision", "auto")).lower())
+            cfg = tree_config(p, depth, n_bins_eff, spec.n_features)
             root_lo = root_hi = nb_f = None
             x_stream = codes_host
             x_itemsize = int(codes_host.dtype.itemsize)
         else:
-            cfg, root_lo, root_hi, nb_f = adaptive_setup(
-                spec, p, int(p["max_depth"]))
+            cfg, root_lo, root_hi, nb_f = adaptive_setup(spec, p, depth)
             x_stream = X_host
             x_itemsize = 4
         chunk_rows = int(max(min(

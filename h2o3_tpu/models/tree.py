@@ -24,13 +24,17 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import lru_cache, partial
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import os as _os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from h2o3_tpu.ops.binning import (CodesView, bin_matrix_device, pack_codes,
+                                  packed_codes_record)
+from h2o3_tpu.ops.hist_adaptive import binned_level_plan
 
 NEG_INF = -1e30
 
@@ -385,7 +389,6 @@ def grow_tree(codes, g, h, w, cfg: TreeConfig, col_mask, axis_name=None,
     ``model_axis`` shards the per-level split SEARCH over the mesh
     'model' axis (histograms stay data-psum'd and replicated across
     model shards; see _find_splits_sharded)."""
-    from h2o3_tpu.ops.binning import CodesView
     from h2o3_tpu.ops.histogram import build_histograms
 
     rm = codes.rm if isinstance(codes, CodesView) else codes
@@ -510,10 +513,9 @@ def grow_tree(codes, g, h, w, cfg: TreeConfig, col_mask, axis_name=None,
     return tree, nid
 
 
-# histogram_type values the fused ADAPTIVE kernel serves — ONE spelling
-# for the GBM/DRF packed-path gating and its infeasible-fallback rule
-# (GBM additionally allows 'random', which only the adaptive kernel's
-# per-tree grid phase can honor)
+# histogram_type values the fused ADAPTIVE kernel serves ('random' too,
+# for a trainer whose tree_path call says so: only that kernel's
+# per-tree grid phase can honor it)
 ADAPTIVE_HIST_TYPES = ("uniform_adaptive", "uniform", "auto", "round_robin")
 
 
@@ -587,6 +589,64 @@ def adaptive_feasible(spec, params, max_depth: int) -> bool:
     return level_bytes <= 96 * 2 ** 20
 
 
+def tree_config(params, max_depth: int, n_bins: int, n_features: int,
+                mtries: int = 0, random_grid: bool = False) -> TreeConfig:
+    """The ONE place a TreeConfig is built from an estimator's params:
+    the packed, sketch, adaptive and streamed setups all come through
+    here, so a new field is read once."""
+    p = params
+    return TreeConfig(
+        max_depth=max_depth, n_bins=n_bins, n_features=n_features,
+        min_rows=float(p["min_rows"]),
+        min_split_improvement=float(p["min_split_improvement"]),
+        reg_lambda=float(p.get("reg_lambda", 0.0)),
+        reg_alpha=float(p.get("reg_alpha", 0.0)),
+        min_child_weight=float(p.get("min_child_weight", 0.0)),
+        mtries=mtries,
+        col_rate_change=float(
+            p.get("col_sample_rate_change_per_level", 1.0) or 1.0),
+        hist_method=p.get("hist_kernel", "auto"),
+        random_grid=random_grid,
+        histogram_precision=str(
+            p.get("histogram_precision", "auto")).lower())
+
+
+def tree_path(hist_type: str, packed_requested: bool, n_bins: Optional[int],
+              n_features: int, max_depth: int, *, adaptive_fits: bool,
+              random_is_adaptive: bool = True) -> str:
+    """Which grower a tree train runs, as a pure function of what the
+    trainers know: ``"packed"`` (grow_tree_binned on int8/int16 codes),
+    ``"adaptive"`` (grow_tree_adaptive on raw features) or ``"sketch"``
+    (grow_tree on the global sketch's int32 codes).
+
+    ``packed_requested`` is :func:`packed_codes_requested`; ``n_bins`` is
+    the sketch's bin count, or a bound on it from the categorical domains
+    (:func:`packed_bins_upper_bound`) before the sketch has run, or None
+    where nothing is known yet (packing is then taken to fit);
+    ``adaptive_fits`` is :func:`adaptive_feasible`. The dense bin stage
+    asks twice, before the sketch with the bound (an "adaptive" there
+    saves the O(rows * F) sketch and digitise) and after it with the
+    count; the streamed driver asks with its host sketch's count.
+
+    - packed: requested, not ``random`` (its per-tree grid phase needs
+      the per-level rebinning that packing removes), and the deepest
+      level's accumulators fit (:func:`binned_feasible`);
+    - else adaptive: a uniform histogram type (``random`` among them
+      where ``random_is_adaptive``: GBM; DRF bins ``random`` by the
+      global sketch) whose kernel fits;
+    - else the global sketch: ``quantiles_global``, more than 254 bins,
+      or a depth whose level fits neither kernel."""
+    if (packed_requested and hist_type != "random"
+            and (n_bins is None
+                 or binned_feasible(n_bins, n_features, max_depth))):
+        return "packed"
+    uniform = ADAPTIVE_HIST_TYPES + (("random",) if random_is_adaptive
+                                     else ())
+    if hist_type in uniform and adaptive_fits:
+        return "adaptive"
+    return "sketch"
+
+
 def adaptive_setup(spec, params, max_depth: int, mtries: int = 0):
     """Shared GBM/DRF setup for the adaptive path: TreeConfig sized so
     enums get identity bins (card-1 real bins, capped by nbins_cats and
@@ -597,24 +657,10 @@ def adaptive_setup(spec, params, max_depth: int, mtries: int = 0):
     p = params
     nbins = int(p["nbins"])
     nbins_cats = int(p.get("nbins_cats", 1024))
-    cfg = TreeConfig(max_depth=max_depth,
-                     n_bins=_adaptive_n_bins_eff(spec, p),
-                     n_features=spec.n_features,
-                     min_rows=float(p["min_rows"]),
-                     min_split_improvement=float(p["min_split_improvement"]),
-                     reg_lambda=float(p.get("reg_lambda", 0.0)),
-                     reg_alpha=float(p.get("reg_alpha", 0.0)),
-                     min_child_weight=float(
-                         p.get("min_child_weight", 0.0)),
-                     mtries=mtries,
-                     col_rate_change=float(
-                         p.get("col_sample_rate_change_per_level", 1.0)
-                         or 1.0),
-                     hist_method=p.get("hist_kernel", "auto"),
-                     random_grid=(str(p.get("histogram_type", "")).lower()
-                                  == "random"),
-                     histogram_precision=str(
-                         p.get("histogram_precision", "auto")).lower())
+    cfg = tree_config(p, max_depth, _adaptive_n_bins_eff(spec, p),
+                      spec.n_features, mtries=mtries,
+                      random_grid=(str(p.get("histogram_type", "")).lower()
+                                   == "random"))
     if spec.X is None:           # streaming mode: ranges from host X
         import warnings
         with warnings.catch_warnings():
@@ -633,6 +679,135 @@ def adaptive_setup(spec, params, max_depth: int, mtries: int = 0):
     nb_f = jnp.where(cat, jnp.minimum(span, float(nbins_cats)),
                      float(nbins)).astype(jnp.float32)
     return cfg, root_lo, root_hi, nb_f
+
+
+class TreeInputs(NamedTuple):
+    """What :func:`prepare_tree_inputs` hands a dense tree trainer."""
+    mode: str                    # tree_path's answer
+    cfg: TreeConfig
+    bm: Optional[object]         # the sketch's BinnedMatrix; None on adaptive
+    pc: Optional[object]         # PackedCodes on the packed path
+    root_lo: jax.Array           # adaptive: the features' finite ranges
+    root_hi: jax.Array           # and per-feature bin counts (zeros
+    nb_f: jax.Array              # elsewhere: the chunk's operands)
+    level_plan: Optional[dict]   # packed: what its levels run
+
+    @property
+    def packed(self) -> bool:
+        return self.mode == "packed"
+
+    @property
+    def adaptive(self) -> bool:
+        return self.mode == "adaptive"
+
+    def operands(self, X):
+        """(row-major matrix, transposed operand or the same as a dummy,
+        whether that operand is real, the NA bin) of the chunk step."""
+        if self.adaptive:
+            return X, X, False, 0
+        codes = self.pc if self.packed else self.bm.codes
+        has_t = codes.t is not None
+        return (codes.rm, codes.t if has_t else codes.rm, has_t,
+                self.pc.na_bin if self.packed else self.bm.na_bin)
+
+    def loop_attrs(self) -> dict:
+        """The loop span's attributes: the record's keys."""
+        if not self.packed:
+            return {}
+        return {"W": self.pc.W, "code_bytes": self.pc.itemsize,
+                **self.level_plan}
+
+    def record(self) -> dict:
+        """``model.output["packed_codes"]``: what the level kernel
+        streamed, and the plan of its levels."""
+        if not self.packed:
+            return packed_codes_record(False)
+        return packed_codes_record(
+            True, dtype=self.pc.rm.dtype, W=self.pc.W,
+            bytes_per_value=self.pc.itemsize, n_bins=self.bm.n_bins,
+            plan=self.level_plan)
+
+
+def prepare_tree_inputs(spec, params, max_depth: int, *, prof,
+                        mtries: int = 0,
+                        random_is_adaptive: bool) -> TreeInputs:
+    """The bin stage of every dense tree trainer (GBM, XGBoost, DRF): the
+    path decision (:func:`tree_path`), the sketch, digitise and pack it
+    calls for, the TreeConfig, and the packed levels' plan.
+
+    ``mtries`` is DRF's per-node feature subset; ``random_is_adaptive``
+    is the trainer's reading of ``histogram_type="random"`` (see
+    tree_path). The trainer's ``prof`` (its ``log.Profile``) times the
+    stage as phase ``bin`` with ``bin.sketch``, ``bin.digitize``
+    (ops/binning.bin_matrix_device) and ``bin.pack`` inside it, each
+    ended by a fence on what it dispatched: the digitise's temporaries
+    are freed before the pack allocates, and the boost loop's clock
+    starts on a device with nothing of the bin stage in flight."""
+    p = params
+    F = spec.n_features
+    hist_type = (p.get("histogram_type") or "uniform_adaptive").lower()
+    path = partial(tree_path, hist_type, packed_codes_requested(p),
+                   n_features=F, max_depth=max_depth,
+                   adaptive_fits=adaptive_feasible(spec, p, max_depth),
+                   random_is_adaptive=random_is_adaptive)
+    with prof.phase("bin"):
+        # from the categorical domains alone: where packing cannot come in
+        # under its lane and VMEM caps, take the adaptive kernel without
+        # paying the sketch and digitise
+        mode = path(packed_bins_upper_bound(spec, p))
+        bm = pc = level_plan = None
+        if mode != "adaptive":
+            # device-side sketch: X never leaves HBM. While packing is
+            # still on offer the int32 transposed operand (with_t) is
+            # skipped: pack_codes supersedes it with the int8/int16
+            # layouts, and a rows*F*4 copy built to be dropped would cost
+            # the HBM the packing saves
+            bm = bin_matrix_device(
+                spec.X, spec.names, spec.is_cat, spec.nrow,
+                nbins=max(int(p["nbins"]), 2),
+                nbins_cats=int(p["nbins_cats"]), histogram_type=hist_type,
+                with_t=path(None) != "packed", prof=prof)
+            # the sketch's own bin count: past the 254-lane cap or VMEM,
+            # packing falls back to the fused adaptive kernel, not to the
+            # slow matmul path the sketch would otherwise route to
+            mode = path(bm.n_bins)
+        if mode == "adaptive":
+            bm = None
+            cfg, root_lo, root_hi, nb_f = adaptive_setup(
+                spec, p, max_depth, mtries=min(mtries, F))
+        else:
+            if mode == "packed":
+                with prof.phase("bin.pack"):
+                    pc = pack_codes(bm)
+                    # free the int32 code view: the packed layouts replace
+                    # it (1-2 bytes/value x2 <= half the f32 X footprint);
+                    # only bm.edges / n_bins are read from here on
+                    bm.codes = CodesView(rm=pc.rm, t=None)
+                    # this path's bin fence (see the other paths' below),
+                    # inside the phase whose device work it waits for
+                    jax.block_until_ready(pc)  # h2o3-lint: allow[transfer-seam] bin-stage timing fence: replaces time the loop-entry fence already waited, unattributed
+            cfg = tree_config(p, max_depth, bm.n_bins, bm.n_features,
+                              mtries=min(mtries, bm.n_features))
+            root_lo = jnp.zeros(cfg.n_features, jnp.float32)
+            root_hi = jnp.zeros(cfg.n_features, jnp.float32)
+            nb_f = jnp.zeros(cfg.n_features, jnp.float32)
+        if mode == "packed":
+            # the level kernel, feature block and row tile the packed
+            # levels will run, and how the margin update reads a leaf's
+            # value (by the tree's size): for the loop span and the
+            # model's record
+            level_plan = {
+                **binned_level_plan(pc.W, cfg.n_features, binned_method(cfg)),
+                "leaf_lookup": node_lookup_form(cfg.n_nodes),
+                "n_nodes": cfg.n_nodes}
+        else:
+            # the work above is dispatched, not done: wait for it here so
+            # bin_s carries it. The loop-entry fence absorbed it otherwise,
+            # in no span at all (about 11 s of a 13.5 s warm train at
+            # 10M x 28 on the v5e, PR 22)
+            jax.block_until_ready(  # h2o3-lint: allow[transfer-seam] bin-stage timing fence: replaces time the loop-entry fence already waited, unattributed
+                (root_lo, root_hi) if mode == "adaptive" else bm.codes)
+    return TreeInputs(mode, cfg, bm, pc, root_lo, root_hi, nb_f, level_plan)
 
 
 def grow_tree_adaptive(X, g, h, w, cfg: TreeConfig, col_mask, root_lo,
@@ -721,21 +896,6 @@ def grow_tree_adaptive(X, g, h, w, cfg: TreeConfig, col_mask, root_lo,
               or (method == "auto" and jax.default_backend() == "tpu"))
     Xt = X.T if on_tpu else None
 
-    # OPT-IN (H2O3_HIST_I8=1/2=terms): int8 fixed-point histogram path.
-    # The bare int8 MXU contraction measures 1.33x faster than bf16
-    # (tools/kern_mxu_probe.py) and single-term quantization matches the
-    # bf16 AUC on the bench (0.8357 vs 0.8358) — but in the FUSED kernel
-    # the int8 operand build (i32 masking + i8 narrowing; Mosaic won't
-    # legalize i8 muli or i1->i8-tiling selects) costs more than the MXU
-    # saves: 65.7M rows/s vs 68.6M bf16 on the 10M-row bench. Kept as an
-    # opt-in for future Mosaic versions with native i8 select.
-    qs = None
-    i8_terms = int(_os.environ.get("H2O3_HIST_I8", "0") or 0)
-    if (i8_terms and on_tpu and mxu_dtype == jnp.bfloat16
-            and rows <= 16_000_000):
-        from h2o3_tpu.ops.hist_adaptive import quantize_ghw_i8
-        qs = quantize_ghw_i8(ghw, terms=i8_terms)
-
     for d in range(D):
         N = 2 ** d
         base = N - 1
@@ -748,7 +908,7 @@ def grow_tree_adaptive(X, g, h, w, cfg: TreeConfig, col_mask, root_lo,
                           nb_f[None, :] / jnp.where(span > 0, span, 1.0), 0.0)
         nid, hist = adaptive_level(X, nid, ghw, tables, lo_d, inv_d,
                                    N // 2 if d else 0, N, base, W, method,
-                                   mxu_dtype=mxu_dtype, xt=Xt, qs=qs)
+                                   mxu_dtype=mxu_dtype, xt=Xt)
         if axis_name is not None:
             hist = jax.lax.psum(hist, axis_name)
         trip = (hist[0], hist[1], hist[2])
@@ -996,8 +1156,7 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
     the codes — no lo/inv rebinning anywhere, so the hot loop moves
     1-2 bytes/value instead of 4."""
     from h2o3_tpu.ops.hist_adaptive import (binned_level,
-                                            binned_route_only,
-                                            pallas_interpret, pick_W)
+                                            binned_route_only, pick_W)
     from dataclasses import replace as dc_replace
 
     D = cfg.max_depth
@@ -1024,18 +1183,6 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
     hi_b = jnp.full(1, BIGV)
     allowed = (jnp.ones((1, F), bool) if sets is not None else None)
 
-    on_tpu = (method == "pallas"
-              or (method == "auto" and (jax.default_backend() == "tpu"
-                                        or pallas_interpret())))
-    # opt-in int8-ghw fixed-point contraction — same contract as the
-    # adaptive path (H2O3_HIST_I8=1/2=terms, ops/hist_adaptive.py)
-    qs = None
-    i8_terms = int(_os.environ.get("H2O3_HIST_I8", "0") or 0)
-    if (i8_terms and on_tpu and mxu_dtype == jnp.bfloat16
-            and rows <= 16_000_000):
-        from h2o3_tpu.ops.hist_adaptive import quantize_ghw_i8
-        qs = quantize_ghw_i8(ghw, terms=i8_terms)
-
     if D == 0:
         g0 = g * (w > 0)
         h0 = h * (w > 0)
@@ -1057,7 +1204,7 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
         base = N - 1
         nid, hist = binned_level(codes_rm, nid, ghw, tables,
                                  N // 2 if d else 0, N, base, W, method,
-                                 mxu_dtype=mxu_dtype, ct=ct, qs=qs)
+                                 mxu_dtype=mxu_dtype, ct=ct)
         if axis_name is not None:
             hist = jax.lax.psum(hist, axis_name)
         trip = (hist[0], hist[1], hist[2])
@@ -1254,7 +1401,6 @@ def _segment_totals(lid, valid, g, h, w, n_seg: int):
 def predict_binned(codes, tree, max_depth: int, na_bin: int):
     """Prediction on a binned matrix (leaf lookup); one packed-word gather
     per level (see grow_tree routing)."""
-    from h2o3_tpu.ops.binning import CodesView
     rm = codes.rm if isinstance(codes, CodesView) else codes
     rows = rm.shape[0]
     word = (jnp.maximum(tree["feat"], 0)

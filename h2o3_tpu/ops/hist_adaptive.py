@@ -1,51 +1,62 @@
-"""Fused route + bin + histogram level kernel with per-node ADAPTIVE
-uniform bins — the r3 flagship tree kernel.
+"""The tree trainers' level kernels: route a level's rows, bin them and
+build the level's (g, h, w) histograms, one Pallas call a tree level.
 
-Reference semantics: hex/tree/DHistogram.java — H2O's default
-``histogram_type=UniformAdaptive`` re-bins every feature PER NODE over
-the node's value range with ``nbins`` uniform bins, refining resolution
-as the tree descends (DHistogram.java:48 ``_min/_maxEx`` per node;
-ScoreBuildHistogram2.java:121-301 builds (w, wY, wYY) per bin). This is
-unlike XGBoost's global 256-bin sketch: after d levels a feature's
-effective resolution is ~nbins·2^d.
+What the file holds, by who reaches it (models/tree.py ``tree_path``
+picks the grower; the dispatchers here pick the body):
 
-TPU re-design (one pallas kernel call per tree level), in the
-TRANSPOSED layout x_t [F, rows] — rows ride the 128-lane axis:
+- PACKED BINNED levels, ``binned_level`` / ``binned_route_only``
+  (grow_tree_binned on int8/int16 codes from ops/binning.pack_codes).
+  The benchmark's cells run exactly two bodies, named as the device
+  trace prints them: ``binned_level_tpu_t`` (body ``_kernel_bt``) for
+  levels 0..D-1 and ``binned_route_only_tpu_t`` (``_route_kernel_bt``)
+  for the leaves' routing. Off their path: ``binned_level_tpu_stripe``
+  (``_kernel_bt_stripe``) at W == 16 (nbins <= 14) where its probe
+  passes or ``H2O3_STRIPE`` says so, and the scatter references
+  ``binned_level_xla`` / ``binned_route_only_xla`` off the TPU.
+- f32 ADAPTIVE levels, ``adaptive_level`` / ``route_only``
+  (grow_tree_adaptive on raw features): ``packed_codes=False``,
+  ``histogram_type="random"``, categorical domains too wide to pack.
+  Transposed bodies ``_kernel_t`` / ``_route_kernel_t``; scatter
+  references ``adaptive_level_xla`` / ``route_only_xla``.
+- ROW-MAJOR f32 bodies ``_kernel`` / ``_route_kernel``: reached only
+  without a transposed operand: grow_tree_adaptive_streamed, tests.
+
+Reference semantics of the adaptive levels (hex/tree/DHistogram.java):
+H2O's default ``histogram_type=UniformAdaptive`` re-bins every feature
+PER NODE over the node's value range with ``nbins`` uniform bins
+(DHistogram.java:48; ScoreBuildHistogram2.java:121-301 builds (w, wY,
+wYY) per bin), so after d levels a feature's effective resolution is
+~nbins·2^d; the packed levels are XGBoost's global sketch, binned once.
+
+One level, in the TRANSPOSED layout x_t [F, rows] (rows on the 128-lane
+axis: a [rows, F] array tiles F onto the lanes, so F=28 reads waste
+100/128 of the HBM bandwidth; [F, rows] pads F only 28→32 sublanes):
   1. ROUTE: each row steps through the previous level's split tables
      (bf16-split [12, n_prev] = feat/thr/na_left/can, exact via
      _split3_bf16). The lookup is ONE merged one-hot matmul; the
      split-feature value is selected by compare-accumulate over F
      sublanes.
-  2. BIN:  b = isnan(x) ? W-1 : floor(clip((x - lo[n,f]) * inv[n,f]))
-     with per-(node, feature) range tables — one merged [6F, N] lookup
+  2. BIN (adaptive only; a packed code IS its bin):
+     b = isnan(x) ? W-1 : floor(clip((x - lo[n,f]) * inv[n,f]))
+     with per-(node, feature) range tables, one merged [6F, N] lookup
      matmul against the node one-hot.
   3. HIST: the bin row broadcasts to [F*W, tile] with a SUBLANE repeat
-     (cheap relayout; the row-major layout needed a selector matmul
-     and a 14MB f32 intermediate here), one-hots against a sublane
-     iota, then contracts against node-onehot × (g,h,w) on the MXU
-     (lane-dim contraction both sides), accumulating in VMEM.
+     (the row-major layout needs a selector matmul and a 14MB f32
+     intermediate here), one-hots against a sublane iota, then
+     contracts against node-onehot x (g,h,w) on the MXU (lane-dim
+     contraction both sides), accumulating in VMEM.
 
-Why transposed: a [rows, F] device array tiles F onto the 128-lane
-minor axis, so F=28 reads waste 100/128 of HBM bandwidth (measured 30
-GB/s useful vs 126 GB/s packed on v5e). [F, rows] packs rows into
-lanes; F pads only 28→32 sublanes. Layout + sublane-repeat together
-took the 10M-row bench from 21.7M to 68.1M rows/s/chip (vs_baseline
-0.87 → 2.72) at identical AUC. The row-major kernels are retained for
-parity tests.
-
-The cross-shard reduction (MRTask reduce tree / Rabit ring analog,
-water/MRTask.java:871, hex/tree/xgboost/rabit/RabitTrackerH2O.java) is a
-single ``lax.psum`` of the returned histogram by the caller.
+The cross-shard reduction (the MRTask reduce tree / Rabit ring analog)
+is a single ``lax.psum`` of the returned histogram by the caller.
 
 Deviation from the reference, documented: child ranges are derived from
 the parent's split point (split feature — exact) and the parent's
 occupied-bin range (other features — within one bin width), instead of
 re-measuring exact per-child min/max; and routing compares raw
-``x >= thr`` so training-time routing is bit-identical to scoring-time
-tree walks.
-
-W (bin lanes per feature) is static per compile: 32 / 64 / 128 / 256
-covering nbins <= 30 / 62 / 126 / 254; the last lane is the NA bin.
+``x >= thr``, so training-time routing is bit-identical to scoring-time
+tree walks. W (bin lanes per feature) is static per compile: 16 / 32 /
+64 / 128 / 256 cover nbins <= 14 / 30 / 62 / 126 / 254; the last lane
+is NA.
 """
 from __future__ import annotations
 
@@ -53,7 +64,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -281,7 +291,7 @@ def adaptive_level_xla(x, nid, ghw, tables, lo, inv, n_prev: int,
 
 def pallas_interpret() -> bool:
     """H2O3_PALLAS_INTERPRET=1 runs the pallas kernels through the
-    interpreter — lets the multichip dryrun execute the FLAGSHIP kernel
+    interpreter — lets the multichip dryrun execute the kernel
     path (routing + histogram + cross-shard psum) on the virtual CPU
     mesh, where compiled Mosaic is TPU-only (read at trace time)."""
     return _os.environ.get("H2O3_PALLAS_INTERPRET", "") == "1"
@@ -296,15 +306,12 @@ def _resolve_method(method: str) -> str:
 
 def adaptive_level(x, nid, ghw, tables, lo, inv, n_prev: int, n_nodes: int,
                    level_base: int, W: int, method: str = "auto",
-                   mxu_dtype=jnp.bfloat16, xt=None, qs=None):
+                   mxu_dtype=jnp.bfloat16, xt=None):
     """Dispatch: pallas on TPU (padding rows to the tile size), scatter-XLA
-    elsewhere. ``mxu_dtype`` picks the histogram contraction precision —
-    see the bf16 deviation bound in the module docstring. ``xt`` ([F,
-    rows], rows in LANES) selects the bandwidth-packed transposed kernel
-    (callers materialize the transpose once per tree loop). ``qs``
-    (optional (q [6, rows] int8, scales [3]) from quantize_ghw_i8)
-    enables the exact 2-term int8 fixed-point contraction for levels
-    with 6·n_nodes <= 128 — ~1.3x faster AND tighter error than bf16."""
+    elsewhere. ``mxu_dtype`` picks the histogram contraction precision
+    (tree._hist_mxu_dtype). ``xt`` ([F, rows], rows in LANES) selects the
+    bandwidth-packed transposed kernel (callers materialize the transpose
+    once per tree loop)."""
     method = _resolve_method(method)
     if method == "pallas":
         if xt is not None:
@@ -315,15 +322,6 @@ def adaptive_level(x, nid, ghw, tables, lo, inv, n_prev: int, n_nodes: int,
                              constant_values=jnp.nan)
                 nid = jnp.pad(nid, (0, pad))
                 ghw = jnp.pad(ghw, ((0, 0), (0, pad)))
-            if (qs is not None and qs[0].shape[0] * n_nodes <= 128
-                    and mxu_dtype == jnp.bfloat16):
-                q, scales = qs
-                if pad:
-                    q = jnp.pad(q, ((0, 0), (0, pad)))
-                nid2, hist = adaptive_level_tpu_i8(
-                    xt, nid, q, scales, tables, lo, inv, n_prev, n_nodes,
-                    level_base, W, interpret=pallas_interpret())
-                return nid2[:rows], hist
             nid2, hist = adaptive_level_tpu_t(xt, nid, ghw, tables, lo, inv,
                                               n_prev, n_nodes, level_base,
                                               W, mxu_dtype=mxu_dtype,
@@ -360,260 +358,7 @@ def pick_W(nbins: int) -> int:
                      f"cap; use histogram_type='quantiles_global'")
 
 
-def _totals_kernel(x_ref, nid_ref, ghw_ref, tabs_ref, nid_out, tot_out,
-                   acc_ref, *, n_prev: int, n_nodes: int, F: int, tile: int,
-                   n_row_tiles: int, level_base: int):
-    """Route one level then accumulate exact f32 (g,h,w) sums per node —
-    the deepest-level leaf statistics (no bin histogram, no bf16)."""
-    r = pl.program_id(0)
-
-    @pl.when(r == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...]
-    nid = nid_ref[0, :]
-    if n_prev > 0:
-        nid = _route(x, nid, tabs_ref, n_prev, level_base, tile, F)
-    nid_out[0, :] = nid
-    lid = nid - level_base
-    in_lvl = (lid >= 0) & (lid < n_nodes)
-    lidc = jnp.where(in_lvl, lid, 0)
-    onh = (jax.lax.broadcasted_iota(jnp.int32, (n_nodes, tile), 0)
-           == lidc[None, :])
-    onh_f = onh.astype(jnp.float32) * in_lvl.astype(jnp.float32)[None, :]
-    ghw = ghw_ref[...]
-    left = jnp.concatenate([onh_f * ghw[k, :][None, :] for k in range(3)],
-                           axis=0)                       # [3N, tile] f32
-    # all 128 lanes carry the same sum (single-lane stores are awkward in
-    # Mosaic); the caller reads lane 0
-    acc_ref[...] += jnp.broadcast_to(
-        jnp.sum(left, axis=1, keepdims=True), acc_ref.shape)
-
-    @pl.when(r == n_row_tiles - 1)
-    def _flush():
-        tot_out[...] = acc_ref[...]
-
-
-def leaf_totals_tpu(x, nid, ghw, tables, n_prev: int, n_nodes: int,
-                    level_base: int, tile: int = TILE,
-                    interpret: bool = False):
-    """Final-level route + exact per-leaf (g,h,w) totals.
-    Returns (nid', totals [3, n_nodes])."""
-    rows, F = x.shape
-    assert rows % tile == 0
-    n_row_tiles = rows // tile
-    tabs = _pack_tables(tables)
-    np1 = tabs.shape[1]
-    kern = functools.partial(_totals_kernel, n_prev=n_prev, n_nodes=n_nodes,
-                             F=F, tile=tile, n_row_tiles=n_row_tiles,
-                             level_base=level_base)
-    nid2, tot = pl.pallas_call(
-        kern,
-        grid=(n_row_tiles,),
-        in_specs=[
-            pl.BlockSpec((tile, F), lambda r: (r, 0)),
-            pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3, tile), lambda r: (0, r)),
-            pl.BlockSpec((12, np1), lambda r: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3 * n_nodes, 128), lambda r: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, rows), jnp.int32),
-            jax.ShapeDtypeStruct((3 * n_nodes, 128), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((3 * n_nodes, 128), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-    )(x, nid[None, :], ghw, tabs)
-    return nid2[0], tot[:, 0].reshape(3, n_nodes)
-
-
-def leaf_totals_xla(x, nid, ghw, tables, n_prev: int, n_nodes: int,
-                    level_base: int):
-    rows, F = x.shape
-    feat, thr, nal, can = tables
-    if n_prev > 0:
-        prev_base = level_base - n_prev
-        lid_p = jnp.clip(nid - prev_base, 0, n_prev - 1)
-        in_prev = (nid >= prev_base) & (nid < prev_base + n_prev)
-        f_r = feat[lid_p].astype(jnp.int32)
-        xsel = jnp.take_along_axis(x, f_r[:, None], axis=1)[:, 0]
-        go_right = jnp.where(jnp.isnan(xsel), nal[lid_p] < 0.5,
-                             xsel >= thr[lid_p])
-        child = 2 * nid + 1 + go_right.astype(jnp.int32)
-        nid = jnp.where(in_prev & (can[lid_p] > 0.5), child, nid)
-    lid = nid - level_base
-    in_lvl = (lid >= 0) & (lid < n_nodes)
-    lidc = jnp.where(in_lvl, lid, 0)
-    vw = jnp.where(in_lvl, 1.0, 0.0)
-    tot = jnp.zeros((n_nodes, 3), jnp.float32).at[lidc].add(
-        (ghw * vw[None, :]).T)
-    return nid, tot.T
-
-
-# ---------------- int8 fixed-point histogram path ----------------------
-#
-# The hist contraction's MXU time is ~independent of the M (=3N row)
-# dimension below 128 and scales with K·ceil(FW/512): every level costs
-# the same as the deepest one (measured: [6,8192]x[8192,896] takes 73%
-# of the [126,...] time — tools/kern_mxu_probe.py). int8 mode streams
-# ~1.33x faster than bf16, and the unused M rows are free — so levels
-# with 6N <= 128 run an EXACT 2-term int8 fixed-point contraction:
-#   q16 = clip(round(v/s), ±32639);  a = round(q16/256);  b = q16 - 256a
-#   hist = s·(256·Σ a·oh + Σ b·oh)      (both sums exact in int32)
-# Quantization error ≤ s/2 = max|v|/65278 ABSOLUTE per row — tighter
-# than the bf16 path's ~2^-9 RELATIVE per-product rounding for any
-# |v| ≳ max|v|/100. int32 accumulators cap shard rows at 16M for the
-# worst case (all rows in one bin at |a|=127); the caller gates on it.
-
-
-def quantize_ghw_i8(ghw, terms: int = 1):
-    """Per-tree int8 fixed-point encoding of (g, h, w) rows.
-
-    terms=1: q = round(v/s), s = max|v|/127 — error ≤ max|v|/254
-    absolute per row, comparable to bf16's 8-bit-mantissa relative
-    rounding; rows per component: 1 (M = 3N, same as bf16).
-    terms=2: 16-bit (a, b) pairs — error ≤ max|v|/65278, M = 6N.
-    Returns (q [3·terms, rows] int8, scales [3] f32)."""
-    amax = jnp.maximum(jnp.max(jnp.abs(ghw), axis=1), 1e-30)   # [3]
-    if terms == 1:
-        s = amax / 127.0
-        q = jnp.clip(jnp.round(ghw / s[:, None]), -127, 127
-                     ).astype(jnp.int8)
-        return q, s.astype(jnp.float32)
-    s = amax / 32639.0
-    q16 = jnp.clip(jnp.round(ghw / s[:, None]), -32639, 32639)
-    # floor((q16+128)/256) keeps b strictly in [-128, 127]: round-half-
-    # to-even on positive half-ties would give b=+128 → int8 saturation
-    a = jnp.floor((q16 + 128.0) / 256.0)
-    b = q16 - 256.0 * a
-    q = jnp.stack([a[0], b[0], a[1], b[1], a[2], b[2]]).astype(jnp.int8)
-    return q, s.astype(jnp.float32)
-
-
-def _kernel_t_i8(x_ref, nid_ref, q_ref, s_ref, tabs_ref, loinv_ref,
-                 nid_out, hist_out, acc_ref, *, n_prev: int, n_nodes: int,
-                 F: int, W: int, tile: int, n_row_tiles: int,
-                 level_base: int, terms: int):
-    r = pl.program_id(0)
-
-    @pl.when(r == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    xt = x_ref[...]                                  # [F, tile] f32
-    nid = nid_ref[0, :]
-    if n_prev > 0:
-        nid = _route_t(xt, nid, tabs_ref, n_prev, level_base, tile, F)
-    nid_out[0, :] = nid
-
-    lid = nid - level_base
-    in_lvl = (lid >= 0) & (lid < n_nodes)
-    lidm = jnp.where(in_lvl, lid, -1)
-    iota_n = jax.lax.broadcasted_iota(jnp.int32, (n_nodes, tile), 0)
-    onh_m = iota_n == lidm[None, :]                            # [N, tile] i1
-    onh_b = onh_m.astype(jnp.bfloat16)
-    if n_nodes == 1:
-        lr1 = loinv_ref[...].astype(jnp.float32)
-        lr = _unsplit3(lr1[:2 * F], lr1[2 * F:4 * F], lr1[4 * F:])
-        lo_r = jnp.broadcast_to(lr[:F], (F, tile))
-        inv_r = jnp.broadcast_to(lr[F:], (F, tile))
-    else:
-        lr3 = jax.lax.dot_general(loinv_ref[...], onh_b,
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        lr = _unsplit3(lr3[:2 * F], lr3[2 * F:4 * F], lr3[4 * F:])
-        lo_r = lr[:F]
-        inv_r = lr[F:]
-    bin_f = jnp.floor(jnp.clip((xt - lo_r) * inv_r, 0.0, float(W - 2)))
-    bin_v = jnp.where(jnp.isnan(xt), float(W - 1), bin_f)      # [F, tile]
-    b_all = jnp.repeat(bin_v, W, axis=0)
-    brow = jax.lax.broadcasted_iota(jnp.int32, (F * W, tile), 0)
-    oh_i = ((brow % W).astype(jnp.float32) == b_all).astype(jnp.int8)
-    q = q_ref[...].astype(jnp.int32)                 # [3·terms, tile] widened
-    # int8 vector multiply/select don't legalize in Mosaic (arith.muli /
-    # i1 relayout to the 32-sublane i8 tiling): mask in i32 where both
-    # patterns are legal, then narrow the result once
-    left32 = jnp.concatenate(
-        [jnp.where(onh_m, q[c, :][None, :], 0) for c in range(3 * terms)],
-        axis=0)                                      # [3·terms·N, tile] i32
-    left = left32.astype(jnp.int8)
-    acc_ref[...] += jax.lax.dot_general(
-        left, oh_i, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)            # [6N, FW] exact
-
-    @pl.when(r == n_row_tiles - 1)
-    def _flush():
-        acc = acc_ref[...].astype(jnp.float32)
-        s = s_ref[...]                               # [1, 3] f32
-        N = n_nodes
-        rows = []
-        for c in range(3):
-            if terms == 1:
-                rows.append(s[0, c] * acc[c * N:(c + 1) * N])
-            else:
-                hi = acc[2 * c * N:(2 * c + 1) * N]
-                lo = acc[(2 * c + 1) * N:(2 * c + 2) * N]
-                rows.append(s[0, c] * (256.0 * hi + lo))
-        hist_out[...] = jnp.concatenate(rows, axis=0)  # [3N, FW] f32
-
-
-def adaptive_level_tpu_i8(xt, nid, q, scales, tables, lo, inv, n_prev: int,
-                          n_nodes: int, level_base: int, W: int,
-                          tile: int = TILE, interpret: bool = False):
-    """int8 fixed-point transposed level (3·terms·n_nodes must be <= 128)."""
-    F, rows = xt.shape
-    terms = q.shape[0] // 3
-    assert rows % tile == 0, (rows, tile)
-    assert 3 * terms * n_nodes <= 128, (n_nodes, terms)
-    n_row_tiles = rows // tile
-    tabs = _pack_tables(tables)
-    np1 = tabs.shape[1]
-    loinv = _split3_bf16(jnp.concatenate([lo, inv], axis=1).T, axis=0)
-    kern = functools.partial(_kernel_t_i8, n_prev=n_prev, n_nodes=n_nodes,
-                             F=F, W=W, tile=tile, n_row_tiles=n_row_tiles,
-                             level_base=level_base, terms=terms)
-    nid2, hist = pl.pallas_call(
-        kern,
-        grid=(n_row_tiles,),
-        in_specs=[
-            pl.BlockSpec((F, tile), lambda r: (0, r)),
-            pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3 * terms, tile), lambda r: (0, r)),
-            pl.BlockSpec((1, 3), lambda r: (0, 0)),
-            pl.BlockSpec((12, np1), lambda r: (0, 0)),
-            pl.BlockSpec((6 * F, n_nodes), lambda r: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3 * n_nodes, F * W), lambda r: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, rows), jnp.int32),
-            jax.ShapeDtypeStruct((3 * n_nodes, F * W), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((3 * terms * n_nodes, F * W),
-                                   jnp.int32)],
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-    )(xt, nid[None, :], q, scales[None, :], tabs, loinv)
-    return nid2[0], hist.reshape(3, n_nodes, F, W)
-
-
-# ---------------- TRANSPOSED-LAYOUT kernels ----------------------------
-#
-# The row-major [rows, F] layout wastes HBM bandwidth at small F: device
-# arrays tile the MINOR dim to 128 lanes, so F=28 reads move 128/28 =
-# 4.6x the useful bytes (measured: 30 GB/s useful on v5e vs 126 GB/s at
-# F=128 — tools/ probes). The transposed [F, rows] layout puts ROWS in
-# lanes (full utilization; F pads only 28→32 sublanes) and maps the
-# kernel MORE naturally: the routing/range lookups already treat rows as
-# lanes, the bin one-hot becomes [F*W, tile] vs a sublane iota, and the
-# histogram contraction contracts the lane dim on both operands.
+# ---------- TRANSPOSED-LAYOUT kernels (why: the module docstring) ------
 
 def _route_t(xt, nid, tabs_ref, n_prev, level_base, tile, F):
     """Transposed routing: xt [F, tile] (rows in lanes)."""
@@ -785,9 +530,9 @@ def route_only_tpu_t(xt, nid, tables, n_prev: int, level_base: int,
 def _route_kernel(x_ref, nid_ref, tabs_ref, nid_out, *, n_prev: int,
                   level_base: int, F: int, tile: int):
     """Route one level, nothing else — the deepest-level pass when leaf
-    values come from the last histogram's selected splits (no totals
-    kernel; ~3x cheaper than a full level since the whole [tile, F*W]
-    one-hot stage is skipped)."""
+    values come from the last histogram's selected splits (~3x cheaper
+    than a full level since the whole [tile, F*W] one-hot stage is
+    skipped)."""
     x = x_ref[...]
     nid = nid_ref[0, :]
     nid = _route(x, nid, tabs_ref, n_prev, level_base, tile, F)
@@ -854,23 +599,6 @@ def route_only(x, nid, tables, n_prev: int, level_base: int,
     return route_only_xla(x, nid, tables, n_prev, level_base)
 
 
-def leaf_totals(x, nid, ghw, tables, n_prev: int, n_nodes: int,
-                level_base: int, method: str = "auto"):
-    method = _resolve_method(method)
-    if method == "pallas":
-        rows = x.shape[0]
-        pad = (-rows) % TILE
-        if pad:
-            x = jnp.pad(x, ((0, pad), (0, 0)), constant_values=jnp.nan)
-            nid = jnp.pad(nid, (0, pad))
-            ghw = jnp.pad(ghw, ((0, 0), (0, pad)))
-        nid2, tot = leaf_totals_tpu(x, nid, ghw, tables, n_prev, n_nodes,
-                                    level_base,
-                                    interpret=pallas_interpret())
-        return nid2[:rows], tot
-    return leaf_totals_xla(x, nid, ghw, tables, n_prev, n_nodes, level_base)
-
-
 # ---------------- PACKED BINNED-CODE kernels ---------------------------
 #
 # The global-sketch path bins features ONCE per train (ops/binning.py)
@@ -892,8 +620,8 @@ def leaf_totals(x, nid, ghw, tables, n_prev: int, n_nodes: int,
 #     the scatter reference and to predict_binned's host walk;
 #   - the histogram contraction is byte-for-byte the f32 kernel's
 #     (same [3N, tile] x [FW, tile]^T lane contraction), so the
-#     bf16 / f32-HIGHEST (histogram_precision) and opt-in int8-ghw
-#     fixed-point paths compose unchanged.
+#     bf16 / f32-HIGHEST choice (histogram_precision) composes
+#     unchanged.
 
 
 def code_dtype(W: int):
@@ -935,7 +663,7 @@ def _kernel_bt(c_ref, nid_ref, ghw_ref, tabs_ref, nid_out, hist_out,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # int8/int16 -> f32 once per tile in VMEM (int->float is legal in
-    # Mosaic via the i32 widening the i8-ghw path already uses)
+    # Mosaic through an i32 widening)
     cf = c_ref[...].astype(jnp.int32).astype(jnp.float32)    # [F, tile]
     nid = nid_ref[0, :]
     if n_prev > 0:
@@ -1158,102 +886,6 @@ def stripe_supported() -> bool:
     return _stripe_probe()
 
 
-def _kernel_bt_i8(c_ref, nid_ref, q_ref, s_ref, tabs_ref, nid_out,
-                  hist_out, acc_ref, *, n_prev: int, n_nodes: int, F: int,
-                  W: int, tile: int, n_row_tiles: int, level_base: int,
-                  terms: int):
-    """Binned level with the exact int8 fixed-point ghw contraction —
-    the _kernel_t_i8 composition minus the range-lookup stage."""
-    r = pl.program_id(0)
-
-    @pl.when(r == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    cf = c_ref[...].astype(jnp.int32).astype(jnp.float32)
-    nid = nid_ref[0, :]
-    if n_prev > 0:
-        nid = _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W)
-    nid_out[0, :] = nid
-
-    lid = nid - level_base
-    in_lvl = (lid >= 0) & (lid < n_nodes)
-    lidm = jnp.where(in_lvl, lid, -1)
-    iota_n = jax.lax.broadcasted_iota(jnp.int32, (n_nodes, tile), 0)
-    onh_m = iota_n == lidm[None, :]
-    b_all = jnp.repeat(cf, W, axis=0)
-    brow = jax.lax.broadcasted_iota(jnp.int32, (F * W, tile), 0)
-    oh_i = ((brow % W).astype(jnp.float32) == b_all).astype(jnp.int8)
-    q = q_ref[...].astype(jnp.int32)
-    left32 = jnp.concatenate(
-        [jnp.where(onh_m, q[c, :][None, :], 0) for c in range(3 * terms)],
-        axis=0)
-    left = left32.astype(jnp.int8)
-    acc_ref[...] += jax.lax.dot_general(
-        left, oh_i, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)
-
-    @pl.when(r == n_row_tiles - 1)
-    def _flush():
-        acc = acc_ref[...].astype(jnp.float32)
-        s = s_ref[...]
-        N = n_nodes
-        rows_ = []
-        for c in range(3):
-            if terms == 1:
-                rows_.append(s[0, c] * acc[c * N:(c + 1) * N])
-            else:
-                hi = acc[2 * c * N:(2 * c + 1) * N]
-                lo = acc[(2 * c + 1) * N:(2 * c + 2) * N]
-                rows_.append(s[0, c] * (256.0 * hi + lo))
-        hist_out[...] = jnp.concatenate(rows_, axis=0)
-
-
-def binned_level_tpu_i8(ct, nid, q, scales, tables, n_prev: int,
-                        n_nodes: int, level_base: int, W: int,
-                        tile: int = TILE, interpret: bool = False):
-    """int8 fixed-point binned level (3·terms·n_nodes must be <= 128)."""
-    F, rows = ct.shape
-    terms = q.shape[0] // 3
-    assert rows % tile == 0, (rows, tile)
-    assert 3 * terms * n_nodes <= 128, (n_nodes, terms)
-    n_row_tiles = rows // tile
-    tabs = _pack_tables(tables)
-    np1 = tabs.shape[1]
-    kern = functools.partial(_kernel_bt_i8, n_prev=n_prev, n_nodes=n_nodes,
-                             F=F, W=W, tile=tile, n_row_tiles=n_row_tiles,
-                             level_base=level_base, terms=terms)
-    nid2, hist = pl.pallas_call(
-        kern,
-        grid=(n_row_tiles,),
-        in_specs=[
-            pl.BlockSpec((F, tile), lambda r: (0, r)),
-            pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3 * terms, tile), lambda r: (0, r)),
-            pl.BlockSpec((1, 3), lambda r: (0, 0)),
-            pl.BlockSpec((12, np1), lambda r: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3 * n_nodes, F * W), lambda r: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, rows), jnp.int32),
-            jax.ShapeDtypeStruct((3 * n_nodes, F * W), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((3 * terms * n_nodes, F * W),
-                                   jnp.int32)],
-        cost_estimate=pl.CostEstimate(
-            flops=2 * 3 * terms * n_nodes * F * W * rows,
-            bytes_accessed=(rows * F * jnp.dtype(ct.dtype).itemsize
-                            + rows * (4 + 3 * terms)),
-            transcendentals=0),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-    )(ct, nid[None, :], q, scales[None, :], tabs)
-    return nid2[0], hist.reshape(3, n_nodes, F, W)
-
-
 def binned_level_xla(codes, nid, ghw, tables, n_prev: int, n_nodes: int,
                      level_base: int, W: int):
     """Pure-XLA reference/CPU path for the binned level (scatter-add
@@ -1374,44 +1006,33 @@ def binned_level_plan(W: int, F: int, method: str = "auto") -> dict:
 
 def binned_level(codes_rm, nid, ghw, tables, n_prev: int, n_nodes: int,
                  level_base: int, W: int, method: str = "auto",
-                 mxu_dtype=jnp.bfloat16, ct=None, qs=None):
-    """Dispatch the packed binned level: pallas on TPU (or interpret),
-    scatter-XLA elsewhere. ``ct`` is the pre-transposed [F, rows_p]
-    code matrix (built once per train by ops/binning.pack_codes);
-    without it the pallas path transposes on the fly (streamed
-    chunks). ``qs`` enables the exact int8-ghw contraction for levels
-    with 3·terms·n_nodes <= 128, same contract as adaptive_level."""
-    method = _resolve_method(method)
-    if method == "pallas":
-        if ct is None:
-            ct = codes_rm.T
-        rows = nid.shape[0]
-        ct, nid, ghw = _binned_pad(ct, nid, ghw, W)
-        pad = nid.shape[0] - rows
-        if (qs is not None and qs[0].shape[0] * n_nodes <= 128
-                and mxu_dtype == jnp.bfloat16):
-            q, scales = qs
-            if pad:
-                q = jnp.pad(q, ((0, 0), (0, pad)))
-            nid2, hist = binned_level_tpu_i8(
-                ct, nid, q, scales, tables, n_prev, n_nodes, level_base,
-                W, interpret=pallas_interpret())
-            return nid2[:rows], hist
-        if (binned_level_kernel(W, ct.shape[0], method)
-                == "binned_level_tpu_stripe"):
-            from h2o3_tpu.ops.binning import stripe_pair_codes
-            nid2, hist = binned_level_tpu_stripe(
-                stripe_pair_codes(ct, W), nid, ghw, tables, n_prev,
-                n_nodes, level_base, W, mxu_dtype=mxu_dtype,
-                interpret=pallas_interpret(), F=ct.shape[0])
-            return nid2[:rows], hist
-        nid2, hist = binned_level_tpu_t(ct, nid, ghw, tables, n_prev,
-                                        n_nodes, level_base, W,
-                                        mxu_dtype=mxu_dtype,
-                                        interpret=pallas_interpret())
-        return nid2[:rows], hist
-    return binned_level_xla(codes_rm, nid, ghw, tables, n_prev, n_nodes,
-                            level_base, W)
+                 mxu_dtype=jnp.bfloat16, ct=None):
+    """Dispatch the packed binned level: the scatter reference off the
+    TPU (or where ``method`` says so), the stripe kernel at W == 16 where
+    its probe passed, else ``binned_level_tpu_t``. ``ct`` is the
+    pre-transposed [F, rows_p] code matrix (built once per train by
+    ops/binning.pack_codes); without it the pallas path transposes on
+    the fly (streamed chunks)."""
+    body = binned_level_kernel(
+        W, codes_rm.shape[1] if ct is None else ct.shape[0], method)
+    if body == "binned_level_xla":
+        return binned_level_xla(codes_rm, nid, ghw, tables, n_prev, n_nodes,
+                                level_base, W)
+    if ct is None:
+        ct = codes_rm.T
+    rows = nid.shape[0]
+    ct, nid, ghw = _binned_pad(ct, nid, ghw, W)
+    if body == "binned_level_tpu_stripe":
+        from h2o3_tpu.ops.binning import stripe_pair_codes
+        nid2, hist = binned_level_tpu_stripe(
+            stripe_pair_codes(ct, W), nid, ghw, tables, n_prev, n_nodes,
+            level_base, W, mxu_dtype=mxu_dtype,
+            interpret=pallas_interpret(), F=ct.shape[0])
+    else:
+        nid2, hist = binned_level_tpu_t(
+            ct, nid, ghw, tables, n_prev, n_nodes, level_base, W,
+            mxu_dtype=mxu_dtype, interpret=pallas_interpret())
+    return nid2[:rows], hist
 
 
 def binned_route_only(codes_rm, nid, tables, n_prev: int, level_base: int,

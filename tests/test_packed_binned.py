@@ -32,12 +32,11 @@ from h2o3_tpu.ops.binning import (_edges_host, bin_matrix,
                                   digitize_codes_host, pack_codes,
                                   pack_codes_for)
 from h2o3_tpu.ops.hist_adaptive import (binned_level_plan,
-                                        binned_level_tpu_i8,
                                         binned_level_tpu_t,
                                         binned_level_xla,
                                         binned_route_only_tpu_t,
                                         binned_route_only_xla, code_dtype,
-                                        pick_W, quantize_ghw_i8)
+                                        pick_W)
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _compile_counter import count_compiles  # noqa: E402 — shared harness
@@ -103,21 +102,6 @@ def test_binned_route_only_bit_parity_interpret():
     r_x = binned_route_only_xla(jnp.asarray(codes), nid, tables, n_prev,
                                 base, 16)
     np.testing.assert_array_equal(np.asarray(r_t), np.asarray(r_x))
-
-
-def test_binned_i8_ghw_parity_interpret():
-    """The int8 fixed-point ghw contraction composes with the binned
-    kernel within its documented quantization bound."""
-    codes, ct, nid, ghw, tables, n_prev, N, base = _kernel_inputs(
-        seed=7, int_ghw=False)
-    q, s = quantize_ghw_i8(ghw, terms=2)
-    nid_i, hist_i = binned_level_tpu_i8(ct, nid, q, s, tables, n_prev, N,
-                                        base, 16, tile=1024, interpret=True)
-    nid_x, hist_x = binned_level_xla(jnp.asarray(codes), nid, ghw, tables,
-                                     n_prev, N, base, 16)
-    np.testing.assert_array_equal(np.asarray(nid_i), np.asarray(nid_x))
-    np.testing.assert_allclose(np.asarray(hist_i), np.asarray(hist_x),
-                               atol=5e-3, rtol=1e-4)
 
 
 def test_code_dtype_and_feasibility():

@@ -142,6 +142,35 @@ def test_train_profile_is_the_spans_durations(warm_train):
     assert stages == pytest.approx(tp["total_s"], abs=1e-3)
 
 
+def test_a_packed_drf_train_carries_the_bin_spans_and_gbms_record(
+        frame, warm_train):
+    """The bin stage is one function (tree.prepare_tree_inputs): a DRF
+    train leaves GBM's bin spans, each fenced, and GBM's record of what
+    its packed levels run."""
+    from h2o3_tpu.models.drf import H2ORandomForestEstimator
+    gbm, _ = warm_train
+    telemetry.clear_spans()
+    est = H2ORandomForestEstimator(ntrees=4, max_depth=3, seed=1,
+                                   packed_codes=True)
+    est.train(y="y", training_frame=frame)
+    parents, named = _tree(telemetry.finished_spans())
+    for name, parent in (("train.drf", None), ("train.train", "train.drf"),
+                         ("train.bin", "train.train"),
+                         ("train.bin.sketch", "train.bin"),
+                         ("train.bin.digitize", "train.bin"),
+                         ("train.bin.pack", "train.bin")):
+        assert set(parents.get(name, ())) == {parent}, (name, parents)
+    order = [named[n][0] for n in ("train.bin.sketch", "train.bin.digitize",
+                                   "train.bin.pack")]
+    for a, b in zip(order, order[1:]):
+        assert a.t0 + a.duration_s <= b.t0 + 1e-6
+    sketch = named["train.bin.sketch"][0]
+    assert (sketch.attrs["edges"], sketch.attrs["n_edges"]) == ("uniform", 19)
+    pc, want = est.model.output["packed_codes"], gbm.model.output["packed_codes"]
+    assert set(pc) == set(want)
+    assert pc == {**want, "n_nodes": 15}     # both depth 3, the same frame
+
+
 def test_a_predict_leaves_its_four_children(frame, warm_train):
     est, _ = warm_train
     est.model.predict(frame)            # the ops outside the scan compile
