@@ -370,6 +370,11 @@ class Model:
         [padded, K] class probabilities for classification."""
         raise NotImplementedError
 
+    def _score_attrs(self, X) -> dict:
+        """Attrs of predict's ``score.dispatch`` span: what a trace or
+        /3/Timeline should say of the program ``_predict_matrix(X)`` runs."""
+        return {}
+
     def _frame_offset(self, frame: Frame):
         """Offset vector for scoring. An offset-trained model requires the
         offset column at scoring time (adaptTestForTrain raises in the
@@ -408,14 +413,15 @@ class Model:
 
         Spanned at the boundaries where the work stops: ``score.adapt``
         (host), ``score.dispatch`` (trace, lower, load and enqueue: it
-        returns before the device is done), ``score.fetch`` (the wait
+        returns before the device is done; attrs ``_score_attrs``: which
+        form the scorer's program has), ``score.fetch`` (the wait
         for the device and the D2H) and ``score.frame`` (host, and the
         result columns' uploads)."""
         with _tel.span("score.predict", rows=frame.nrow, model=self.key):
             with _tel.span("score.adapt"):
                 X = adapt_test_matrix(self, frame)
                 offset = self._frame_offset(frame)
-            with _tel.span("score.dispatch"):
+            with _tel.span("score.dispatch", **self._score_attrs(X)):
                 out = self._predict_matrix(X, offset=offset)
             with _tel.span("score.fetch"):
                 host = np.asarray(

@@ -30,6 +30,7 @@ import os as _os
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 from h2o3_tpu.ops.binning import (CodesView, bin_matrix_device, pack_codes,
@@ -64,11 +65,38 @@ def node_lookup_form(n_nodes: int) -> str:
     return "select" if n_nodes <= NODE_SELECT_MAX else "gather"
 
 
+# The scorer's rule (predict_raw_stacked), from tools/micro_scorer.py on the
+# v5e (PERF.md §6 "PR 32"): the predicate form costs about 3 us a node
+# whatever the rows and 0.03-0.06 ns a node and row, the gather form about
+# 50 ns a row and level. Largest tree descended by predicates: the largest
+# complete one at which that was still 2x faster at 500k rows (66.6 against
+# 275.5 ms a tree at 8191 nodes) and at 10M (2.37 against 6.77 s); at 131071
+# nodes the gathers win (0.99 against 0.37 s).
+SCORER_PREDICATE_MAX = 8191
+# ... and fewest rows a node: every reading from there up had the predicates
+# ahead (1024 rows: 0.20 against 0.55 ms a tree at 63 nodes, 0.29 against
+# 0.37 at 127; 65536 rows: 18.7 against 32.6 at 8191), every one below the
+# gathers or a tie (1024 rows, 511 nodes: 0.88 against 0.47; 64 rows, a
+# serving bucket: 0.19 against 0.11 at 63 nodes, 12.5 against 0.13 at 8191)
+SCORER_ROWS_PER_NODE = 8
+# inner nodes of a level whose predicates are unrolled; a wider level
+# loops over blocks of this many
+_PREDICATE_BLOCK = 32
+
+
+def scorer_node_form(n_nodes: int, rows: int) -> str:
+    """Which form predict_raw_stacked's descent runs for trees of
+    ``n_nodes`` heap slots over ``rows`` rows: static shapes alone."""
+    return ("predicate" if n_nodes <= SCORER_PREDICATE_MAX
+            and rows >= SCORER_ROWS_PER_NODE * n_nodes else "gather")
+
+
 def _select_tree(entries, idx):
     """``entries[idx]`` for idx in [0, len(entries)) as a tree of selects
-    on idx's bits, low bit first: n - 1 selects on scalars of the table,
-    all in one elementwise fusion over the rows."""
-    vals = [entries[m] for m in range(entries.shape[0])]
+    on idx's bits, low bit first: n - 1 selects, all in one elementwise
+    fusion over the rows. ``entries`` is a table (its entries enter as
+    scalars) or a list of per-row arrays."""
+    vals = [entries[m] for m in range(len(entries))]
     bit = 0
     while len(vals) > 1:
         odd = ((idx >> bit) & 1).astype(bool)
@@ -1424,28 +1452,100 @@ def predict_binned(codes, tree, max_depth: int, na_bin: int):
     return node_lookup(tree["value"], nid), nid
 
 
+def _score_tree_gather(X, feat, thr, na_left, is_split, value,
+                       max_depth: int):
+    """A row's leaf value in one tree by per-row lookups: four node tables
+    and one element of ``X`` a level, then the value; flat in the tree's
+    size."""
+    nid = jnp.zeros(X.shape[0], jnp.int32)
+    for _ in range(max_depth):
+        f = feat[nid]
+        s = is_split[nid]
+        th = thr[nid]
+        nl = na_left[nid]
+        x = jnp.take_along_axis(X, jnp.maximum(f, 0)[:, None], axis=1)[:, 0]
+        go_right = jnp.where(jnp.isnan(x), ~nl, x >= th)
+        nid = jnp.where(s, 2 * nid + 1 + go_right.astype(jnp.int32), nid)
+    return value[nid]
+
+
+def _block_step(XT, feat, thr, right_if_na, is_split, low):
+    """(go_right, is_split) of every row at node ``low`` of a block of
+    nodes (tables [B], ``feat`` clipped at 0): each node's predicate over
+    ALL rows from scalars of its tables and one contiguous column of
+    ``XT``, then the row's own selected on ``low``'s bits. Plain ``lax``
+    calls: the body is traced again on every eager call."""
+    go = []
+    for j in range(feat.shape[0]):
+        x = lax.dynamic_index_in_dim(XT, feat[j], 0, keepdims=False)
+        go.append(lax.select(lax.ne(x, x),
+                             lax.broadcast(right_if_na[j], x.shape),
+                             lax.ge(x, lax.broadcast(thr[j], x.shape))))
+    return _select_tree(go, low), _select_tree(is_split, low)
+
+
+def _score_tree_predicates(XT, feat, thr, na_left, is_split, value,
+                           max_depth: int):
+    """``_score_tree_gather``'s values with no per-row index: level d's
+    2^d predicates are evaluated on whole columns and a row's own is
+    selected on the bits of its place in the level (unrolled up to
+    _PREDICATE_BLOCK nodes a level, a loop over blocks above), then the
+    value by ``node_lookup``. The same comparison on the same operands
+    and the same integer routing, so the bits are equal; O(nodes)
+    elementwise work a row."""
+    B = _PREDICATE_BLOCK
+    feat, na_left = jnp.maximum(feat, 0), ~na_left
+    nid = jnp.zeros(XT.shape[1], jnp.int32)
+    for d in range(max_depth):
+        base, n = 2 ** d - 1, 2 ** d
+        at = nid - base      # negative where the row stopped above
+        if n <= B:
+            go, s = _block_step(XT, *(t[base:base + n] for t in
+                                      (feat, thr, na_left, is_split)), at)
+        else:
+            low, high = at & (B - 1), at >> (B.bit_length() - 1)
+
+            def block(k, acc):
+                got = _block_step(XT, *(
+                    lax.dynamic_slice_in_dim(t, base + k * B, B)
+                    for t in (feat, thr, na_left, is_split)), low)
+                return tuple(jnp.where(high == k, g, a)
+                             for g, a in zip(got, acc))
+            none = jnp.zeros(nid.shape, bool)
+            go, s = lax.fori_loop(0, n // B, block, (none, none))
+        nid = jnp.where(s & (at >= 0), 2 * nid + 1 + go.astype(jnp.int32),
+                        nid)
+    return node_lookup(value, nid)
+
+
 def predict_raw_stacked(X, feat, thr, na_left, is_split, value, max_depth: int):
     """Scoring-time prediction on raw features for a stack of T trees.
 
     feat/thr/... are [T, M]; X is [rows, F] float32 with NaN=NA.
     Returns [rows, T] per-tree contributions; caller sums/weights.
-    The descent is T*D gathers — the score0 analog (hex/Model.java:2304,
-    GBM: walk CompressedTrees) vectorized over rows and trees."""
-    rows = X.shape[0]
+    The score0 analog (hex/Model.java:2304, GBM: walk CompressedTrees)
+    vectorized over rows, one tree a scan step.
+
+    Up to SCORER_PREDICATE_MAX nodes and from SCORER_ROWS_PER_NODE rows a
+    node the descent looks nothing up by row (``_score_tree_predicates``
+    on the transposed matrix, a bitcast under the TPU's layout of a
+    narrow matrix): a level's ``X[r, feat[nid[r]]]`` is an element
+    gather, 5-11 ms at 500k rows on the v5e, 88% of a predict's device
+    time (PERF.md §6, PR 32). Larger trees (DRF's deep heaps) and small
+    batches (the serving buckets) keep the gathers, 5 a level and one for
+    the value, whose cost has no per-node part. The rule
+    (``scorer_node_form``) reads static shapes; both forms give the same
+    bits."""
+    if scorer_node_form(feat.shape[1], X.shape[0]) == "predicate":
+        Xs, score_tree = X.T, _score_tree_predicates
+    else:
+        Xs, score_tree = X, _score_tree_gather
 
     def one_tree(carry, t):
-        nid = jnp.zeros(rows, jnp.int32)
-        for _ in range(max_depth):
-            f = feat[t][nid]
-            s = is_split[t][nid]
-            th = thr[t][nid]
-            nl = na_left[t][nid]
-            x = jnp.take_along_axis(X, jnp.maximum(f, 0)[:, None], axis=1)[:, 0]
-            go_right = jnp.where(jnp.isnan(x), ~nl, x >= th)
-            nid = jnp.where(s, 2 * nid + 1 + go_right.astype(jnp.int32), nid)
-        return carry, value[t][nid]
+        return carry, score_tree(Xs, feat[t], thr[t], na_left[t], is_split[t],
+                                 value[t], max_depth)
 
-    _, contribs = jax.lax.scan(one_tree, None, jnp.arange(feat.shape[0]))
+    _, contribs = lax.scan(one_tree, None, jnp.arange(feat.shape[0]))
     return contribs.T  # [rows, T]
 
 

@@ -277,6 +277,13 @@ class TreeScoringOptionsMixin:
     def _contrib_f0(self) -> float:
         return 0.0
 
+    def _score_attrs(self, X) -> dict:
+        # which side of tree.scorer_node_form's rule a predict runs
+        from h2o3_tpu.models.tree import scorer_node_form
+        n_nodes = int(self._feat.shape[1])
+        return {"node_form": scorer_node_form(n_nodes, X.shape[0]),
+                "n_nodes": n_nodes}
+
     def predict_contributions(self, frame, output_format: str = "original",
                               top_n: int = 0, bottom_n: int = 0,
                               compare_abs: bool = False):

@@ -199,6 +199,22 @@ def test_a_predict_leaves_its_four_children(frame, warm_train):
     assert 0 < in_jit <= named["score.dispatch"][0].duration_s
 
 
+@pytest.mark.parametrize("form", ["predicate", "gather"])
+def test_the_dispatch_span_says_which_form_the_scorer_ran(
+        frame, warm_train, monkeypatch, form):
+    """Depth 3: 15 nodes, which the scorer descends by predicates; with
+    the rule's bound at 0 the same predict gathers, and says so."""
+    from h2o3_tpu.models import tree
+    est, _ = warm_train
+    if form == "gather":
+        monkeypatch.setattr(tree, "SCORER_PREDICATE_MAX", 0)
+    telemetry.clear_spans()
+    est.model.predict(frame)
+    _, named = _tree(telemetry.finished_spans())
+    dispatch, = named["score.dispatch"]
+    assert dispatch.attrs == {"node_form": form, "n_nodes": 15}
+
+
 def _jit_spans(stage, parent=None):
     return [s for s in telemetry.finished_spans()
             if s.name == f"jit.{stage}"

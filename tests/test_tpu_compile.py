@@ -7,6 +7,8 @@ The ONLY file that describes the chip. The topology is described inside
 a module-scoped fixture, never at import: one process at a time may load
 libtpu, and under xdist every worker imports every test file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +21,7 @@ from h2o3_tpu.ops.binning import stripe_pair_codes
 
 F = 28                    # HIGGS width, the bench and chip_smoke shape
 BENCH_ROWS = 10_002_432   # the benchmark's 10M rows, padded to the tile
+SCORE_ROWS = 500_000      # the score cell's held-out frame
 W = 16                    # nbins=14 -> 16 lanes per feature
 ROWS = 8 * ha.TILE
 ROOT = (0, 1, 0)          # (n_prev, n_nodes, level_base)
@@ -113,17 +116,20 @@ def _hist_pallas3():
                 ((3, rows), jnp.float32)]
 
 
-def _serve_scorer():
-    """The deployed GBM's scorer at the 64-row serving bucket."""
-    trees, depth = 5, 6
+def _scorer(rows, trees, depth):
     nodes = 2 ** (depth + 1) - 1
 
     def fn(X, feat, thr, na_left, is_split, value):
         return predict_raw_stacked(X, feat, thr, na_left, is_split, value,
                                    depth)
     tm = (trees, nodes)
-    return fn, [((64, F), jnp.float32), (tm, jnp.int32), (tm, jnp.float32),
+    return fn, [((rows, F), jnp.float32), (tm, jnp.int32), (tm, jnp.float32),
                 (tm, jnp.bool_), (tm, jnp.bool_), (tm, jnp.float32)]
+
+
+def _serve_scorer():
+    """The deployed GBM's scorer at the 64-row serving bucket."""
+    return _scorer(64, trees=5, depth=6)
 
 
 CASES = {
@@ -170,3 +176,24 @@ def test_margin_update_selects_the_leaf_value_on_v5e(nodes, one_chip,
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "gather(" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * BENCH_ROWS * 4
+
+
+@pytest.mark.parametrize("depth", [5, 6])
+def test_the_scorer_gathers_nothing_over_the_rows_on_v5e(depth, one_chip,
+                                                         no_persistent_cache):
+    """The score cell's program ([500000, 28], 50 trees, depth 5) and a
+    default XGBoost model's (depth 6): the descent reads whole columns
+    and selects, so the optimised HLO holds no gather, and no array of
+    rows x nodes: the only [rows, n] are the matrix, one column of it and
+    the result."""
+    rows, trees, nodes = SCORE_ROWS, 50, 2 ** (depth + 1) - 1
+    fn, operands = _scorer(rows, trees, depth)
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+              for shape, dtype in operands]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert "gather(" not in text
+    beside_rows = {int(a if b == str(rows) else b) for a, b in re.findall(
+        r"\[(\d+),(\d+)\]", text) if str(rows) in (a, b)}
+    assert beside_rows <= {1, F, trees}, beside_rows
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * nodes
