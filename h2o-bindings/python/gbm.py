@@ -4,7 +4,6 @@ PARAM_DEFAULTS = {
     'r2_stopping': None,
     'build_tree_one_node': False,
     'pred_noise_bandwidth': 0.0,
-    'categorical_encoding': 'auto',
     'custom_distribution_func': None,
     'check_constant_response': True,
     'auto_rebalance': True,
@@ -47,6 +46,7 @@ PARAM_DEFAULTS = {
     'hist_kernel': 'auto',
     'histogram_precision': 'auto',
     'packed_codes': 'auto',
+    'categorical_encoding': 'auto',
 }
 
 
@@ -115,15 +115,6 @@ class GeneratedH2OGradientBoostingEstimator(_Base):
     @pred_noise_bandwidth.setter
     def pred_noise_bandwidth(self, value):
         self.params['pred_noise_bandwidth'] = value
-
-    @property
-    def categorical_encoding(self):
-        """Type: str; default: 'auto'."""
-        return self.params.get('categorical_encoding')
-
-    @categorical_encoding.setter
-    def categorical_encoding(self, value):
-        self.params['categorical_encoding'] = value
 
     @property
     def custom_distribution_func(self):
@@ -502,3 +493,12 @@ class GeneratedH2OGradientBoostingEstimator(_Base):
     @packed_codes.setter
     def packed_codes(self, value):
         self.params['packed_codes'] = value
+
+    @property
+    def categorical_encoding(self):
+        """Type: str; default: 'auto'."""
+        return self.params.get('categorical_encoding')
+
+    @categorical_encoding.setter
+    def categorical_encoding(self, value):
+        self.params['categorical_encoding'] = value

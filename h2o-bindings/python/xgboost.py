@@ -1,5 +1,4 @@
 PARAM_DEFAULTS = {
-    'categorical_encoding': 'auto',
     'quiet_mode': True,
     'eta': 0.3,
     'subsample': 1.0,
@@ -63,6 +62,7 @@ PARAM_DEFAULTS = {
     'hist_kernel': 'auto',
     'histogram_precision': 'auto',
     'packed_codes': 'auto',
+    'categorical_encoding': 'auto',
     'min_child_weight': 1.0,
     'reg_alpha': 0.0,
 }
@@ -88,15 +88,6 @@ class GeneratedH2OXGBoostEstimator(_Base):
         merged = dict(PARAM_DEFAULTS)
         merged.update(kwargs)
         super().__init__(**merged)
-
-    @property
-    def categorical_encoding(self):
-        """Type: str; default: 'auto'."""
-        return self.params.get('categorical_encoding')
-
-    @categorical_encoding.setter
-    def categorical_encoding(self, value):
-        self.params['categorical_encoding'] = value
 
     @property
     def quiet_mode(self):
@@ -664,6 +655,15 @@ class GeneratedH2OXGBoostEstimator(_Base):
     @packed_codes.setter
     def packed_codes(self, value):
         self.params['packed_codes'] = value
+
+    @property
+    def categorical_encoding(self):
+        """Type: str; default: 'auto'."""
+        return self.params.get('categorical_encoding')
+
+    @categorical_encoding.setter
+    def categorical_encoding(self, value):
+        self.params['categorical_encoding'] = value
 
     @property
     def min_child_weight(self):

@@ -2005,6 +2005,12 @@ def _tree_route(params, body):
     nal = np.asarray(m._na_left[t])
     spl = np.asarray(m._is_split[t])
     val = np.asarray(m._value[t])
+    # a split on a set of an enum's levels (GBM): the levels on the edge
+    # into each child, as TreeV3's ``levels`` has them
+    sets = getattr(m, "_cat_set", None)
+    is_set = (np.asarray(m._is_set[t]) if sets is not None
+              else np.zeros(len(feat), bool))
+    levels_of = {}
     # BFS over reachable nodes of the complete array → compressed arrays
     idx_of = {}
     order = []
@@ -2022,10 +2028,20 @@ def _tree_route(params, body):
             right.append(idx_of[2 * n + 2])
             fname = m.feature_names[int(feat[n])]
             feats.append(fname)
-            thrs.append(float(thr[n]))
+            thrs.append("NaN" if is_set[n] else float(thr[n]))
             nas.append("LEFT" if nal[n] else "RIGHT")
-            descs.append(f"{fname} < {thr[n]:.6g} goes left"
-                         f" (NA {'left' if nal[n] else 'right'})")
+            if is_set[n]:
+                from h2o3_tpu.models.tree import set_levels
+                dom = m.cat_domains.get(fname) or ()
+                goes = set_levels(np.asarray(sets[t, n]), len(dom))
+                levels_of[2 * n + 1] = np.flatnonzero(goes).tolist()
+                levels_of[2 * n + 2] = np.flatnonzero(~goes).tolist()
+                descs.append(f"{fname} in {int(goes.sum())} of {len(dom)} "
+                             f"levels goes left"
+                             f" (NA {'left' if nal[n] else 'right'})")
+            else:
+                descs.append(f"{fname} < {thr[n]:.6g} goes left"
+                             f" (NA {'left' if nal[n] else 'right'})")
         else:
             left.append(-1)
             right.append(-1)
@@ -2041,7 +2057,7 @@ def _tree_route(params, body):
             "left_children": left, "right_children": right,
             "root_node_id": 0, "descriptions": descs,
             "thresholds": thrs, "features": feats,
-            "levels": [None] * len(order), "nas": nas,
+            "levels": [levels_of.get(n) for n in order], "nas": nas,
             "predictions": preds,
             "tree_decision_path": None, "decision_paths": None}
 

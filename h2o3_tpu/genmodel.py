@@ -385,6 +385,8 @@ def pojo_source(model, class_name: Optional[str] = None) -> str:
     h2o-genmodel's GenModel when a JDK is present; golden-file checked
     otherwise."""
     from h2o3_tpu import telemetry
+    from h2o3_tpu.models.tree import refuse_set_splits
+    refuse_set_splits(model, "the POJO writer")
     algo = model.algo
     cls = class_name or f"{algo}_pojo_{abs(hash(model.key)) % 10 ** 8}"
     # one counted pytree fetch for the codegen arrays (export-time D2H
@@ -711,6 +713,9 @@ class EasyPredictModelWrapper:
         behaviors of the reference wrapper)."""
         extras: Dict[str, Any] = {}
         m = self.model
+        if self.enable_contributions or self.enable_leaf_assignment:
+            from h2o3_tpu.models.tree import refuse_set_splits
+            refuse_set_splits(m, "contributions and leaf assignments")
         if self.enable_contributions:
             from h2o3_tpu.models.treeshap import tree_shap_contributions
             phi, bias = tree_shap_contributions(

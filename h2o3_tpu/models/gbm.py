@@ -83,6 +83,13 @@ GBM_DEFAULTS: Dict = dict(
     # always uses the adaptive kernel (its per-tree grid phase needs
     # per-level rebinning, which packing removes by design)
     packed_codes="auto",
+    # how enum columns enter the trees (hex/Model.Parameters
+    # CategoricalEncodingScheme): 'auto' and 'enum' are one bin a level
+    # and a split that sends a SET of levels left (on the packed path;
+    # tree.prepare_tree_inputs); 'label_encoder' is a threshold on the
+    # level index; the other schemes are not built and are reported in
+    # model.output["categorical_encoding"]
+    categorical_encoding="auto",
 )
 
 
@@ -149,6 +156,17 @@ class GBMModel(TreeScoringOptionsMixin, Model):
         self._value = jnp.asarray(trees_host["value"])
         nw = trees_host.get("node_w")
         self._node_w = jnp.asarray(nw) if nw is not None else None
+        # category-set splits (tree.grow_tree_binned on enum features):
+        # [T*K, M, words] uint32 and which nodes hold a set; None where
+        # every split is a threshold
+        cs = trees_host.get("cat_set")
+        self._cat_set = jnp.asarray(cs) if cs is not None else None
+        self._is_set = (jnp.asarray(trees_host["is_set"])
+                        if cs is not None else None)
+        # how many nodes split on a set (``score.dispatch``'s attr),
+        # counted once from the host arrays: a predict fetches nothing
+        self._set_nodes = (int(np.asarray(trees_host["is_set"]).sum())
+                           if cs is not None else None)
 
     def _contrib_f0(self) -> float:
         return float(np.asarray(self.f0).reshape(-1)[0])
@@ -156,7 +174,9 @@ class GBMModel(TreeScoringOptionsMixin, Model):
     def _margin_matrix(self, X, offset=None):
         contribs = predict_raw_stacked(X, self._feat, self._thr, self._na_left,
                                        self._is_split, self._value,
-                                       self.max_depth)
+                                       self.max_depth,
+                                       cat_set=self._cat_set,
+                                       is_set=self._is_set)
         K = self._K
         if K == 1:
             margin = jnp.asarray(self.f0) + contribs.sum(axis=1)
@@ -196,6 +216,10 @@ class GBMModel(TreeScoringOptionsMixin, Model):
         d["f0"] = np.asarray(self.f0)
         if self._node_w is not None:
             d["node_w"] = np.asarray(telemetry.device_get(self._node_w))
+        if self._cat_set is not None:
+            sets = telemetry.device_get({"cat_set": self._cat_set,
+                                         "is_set": self._is_set})
+            d.update({k: np.asarray(v) for k, v in sets.items()})
         rm = getattr(self, "_resume_margin", None)
         if rm is not None:
             # in-training checkpoint state: the exact f32 training
@@ -234,11 +258,44 @@ class GBMModel(TreeScoringOptionsMixin, Model):
         m._value = jnp.asarray(arrays["value"])
         m._node_w = (jnp.asarray(arrays["node_w"])
                      if "node_w" in arrays else None)
+        m._cat_set = (jnp.asarray(arrays["cat_set"])
+                      if "cat_set" in arrays else None)
+        m._is_set = (jnp.asarray(arrays["is_set"])
+                     if "cat_set" in arrays else None)
+        m._set_nodes = (int(np.asarray(arrays["is_set"]).sum())
+                        if "cat_set" in arrays else None)
         m._resume_margin = (np.asarray(arrays["resume_margin"])
                             if "resume_margin" in arrays else None)
         m._resume_sig = (np.asarray(arrays["resume_sig"])
                          if "resume_sig" in arrays else None)
         return m
+
+
+def _stack_sets(prior, th: dict) -> dict:
+    """``cat_set`` / ``is_set`` of a finished train: the new trees' sets
+    (``th``, tree.collect_chunk_trees) after a checkpoint's, either side
+    zeros where it split by thresholds alone, words padded to the wider;
+    {} where neither has a set."""
+    old = getattr(prior, "_cat_set", None) if prior is not None else None
+    if "cat_set" not in th and old is None:
+        return {}
+    parts = []
+    for cs, iss, like in (
+            (old, getattr(prior, "_is_set", None),
+             getattr(prior, "_feat", None)),
+            (th.get("cat_set"), th.get("is_set"), th["feat"])):
+        if like is None:
+            continue
+        shape = np.asarray(like).shape
+        parts.append((np.zeros(shape + (1,), np.uint32) if cs is None
+                      else np.asarray(cs),
+                      np.zeros(shape, bool) if cs is None
+                      else np.asarray(iss)))
+    words = max(c.shape[-1] for c, _ in parts)
+    return {"cat_set": np.concatenate(
+                [np.pad(c, ((0, 0), (0, 0), (0, words - c.shape[-1])))
+                 for c, _ in parts]),
+            "is_set": np.concatenate([i for _, i in parts])}
 
 
 @jax.named_scope("gbm.chunk")     # a stable name in the ops' metadata
@@ -488,7 +545,8 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         # the bin stage every dense tree trainer shares (models/tree.py):
         # which grower, then the sketch, digitise and pack it calls for
         inputs = prepare_tree_inputs(spec, p, int(p["max_depth"]), prof=prof,
-                                     random_is_adaptive=True)
+                                     random_is_adaptive=True,
+                                     set_splits=self._set_splits_wanted())
         adaptive, packed = inputs.adaptive, inputs.packed
         cfg, bm, pc = inputs.cfg, inputs.bm, inputs.pc
         root_lo, root_hi, nb_f = inputs.root_lo, inputs.root_hi, inputs.nb_f
@@ -590,7 +648,7 @@ class H2OGradientBoostingEstimator(ModelBuilder):
             elif packed:
                 # validation codes share the training sketch AND the
                 # packed NA = W-1 convention (predict_binned walk)
-                vtrain = pack_codes_for(valid_spec.X, bm, pc.W)
+                vtrain = pack_codes_for(valid_spec.X, bm, pc.W, pc.widths)
             else:
                 vtrain = make_codes_view(digitize_with_edges(
                     valid_spec.X, bm.edges, bm.n_bins)).rm
@@ -961,6 +1019,8 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         # kernel actually streamed — bench.py and profile_train.py read
         # this for the bytes/row attribution
         model.output["packed_codes"] = inputs.record()
+        model.output["categorical_encoding"] = self._encoding_record(
+            spec, inputs.set_features)
         # the dense chunk body traces its whole level loop into ONE
         # executable — every level rides a single dispatch (the fused
         # shape the streamed driver's L-level windows approximate)
@@ -1154,6 +1214,9 @@ class H2OGradientBoostingEstimator(ModelBuilder):
             th = {k: np.stack([tr[k] for tr in trees_list]) for k in
                   ("feat", "thr", "na_left", "is_split", "value",
                    "node_w")}
+            # a checkpoint trained on the packed path may hold sets; the
+            # streamed growers split by threshold
+            sets = _stack_sets(prior, th)
             if prior is not None:
                 th = {
                     "feat": np.concatenate(
@@ -1171,6 +1234,7 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                         if getattr(prior, "_node_w", None) is not None
                         else None),
                 }
+            th.update(sets)
             m = GBMModel(self._model_key(), p, spec,
                          dist_name, np.float32(f0), th, [],
                          cfg.n_bins, cfg.max_depth, start_trees + T,
@@ -1342,6 +1406,7 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         model.output["packed_codes"] = packed_codes_record(
             packed, dtype=x_stream.dtype, W=W,
             bytes_per_value=x_itemsize, n_bins=cfg.n_bins)
+        model.output["categorical_encoding"] = self._encoding_record(spec, 0)
         # multi-level fusion record (ISSUE 17): the resolved
         # H2O3_LEVELS_PER_PASS window, and how many levels each device
         # dispatch actually covered — fused only on the packed
@@ -1379,6 +1444,47 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         model.training_metrics = self._metrics_from_margin(
             jnp.asarray(mpad), spec, dist_name, K, dist=dist)
         return model
+
+    # categorical_encoding values (hex/Model.Parameters.CategoricalEncoding
+    # Scheme) and what this trainer does with each: auto and enum are one
+    # bin a level and a split on a SET of levels; label_encoder is the
+    # level index as a number, a threshold; the rest are not built
+    _SET_ENCODINGS = ("auto", "enum")
+    _ORDINAL_ENCODINGS = ("label_encoder", "labelencoder")
+
+    def _encoding(self) -> str:
+        return str(self.params.get("categorical_encoding")
+                   or "auto").lower()
+
+    def _set_splits_wanted(self) -> bool:
+        return self._encoding() not in self._ORDINAL_ENCODINGS
+
+    def _encoding_record(self, spec, set_features: int) -> dict:
+        """``model.output["categorical_encoding"]``: what was asked, what
+        the trees hold, and once in the log where the two differ."""
+        asked, enums = self._encoding(), int(sum(map(bool, spec.is_cat)))
+        applied = ("none" if not enums else "enum" if set_features
+                   else "ordinal")
+        rec = {"requested": asked, "applied": applied,
+               "enum_features": enums, "set_features": int(set_features)}
+        if asked not in self._SET_ENCODINGS + self._ORDINAL_ENCODINGS:
+            rec["honoured"] = False
+            rec["note"] = (f"categorical_encoding={asked!r} is not "
+                           f"implemented; enum columns were trained as "
+                           f"{applied!r}")
+        elif enums and asked in self._SET_ENCODINGS and not set_features:
+            rec["honoured"] = False
+            rec["note"] = ("category-set splits need the packed level "
+                           "kernel (packed_codes; dense, not histogram_type"
+                           "='random', levels within nbins_cats and VMEM): "
+                           "enum columns were split by threshold on the "
+                           "level index")
+        else:
+            rec["honoured"] = True
+        if not rec["honoured"] and enums:
+            from h2o3_tpu.log import warn
+            warn("%s: %s", self.algo, rec["note"])
+        return rec
 
     def _dist(self, dist_name: str, huber_delta: float = 1.0):
         if str(dist_name).lower().startswith("custom"):
@@ -1549,6 +1655,7 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         val_scaled = val * lrs[:, None]
         trees_host = {"feat": feat, "thr": thr, "na_left": nal,
                       "is_split": spl, "value": val_scaled, "node_w": node_w}
+        sets = _stack_sets(prior, th)
         if prior is not None:
             # checkpoint continuation: prepend the prior model's trees
             # (already lr-scaled) in (tree, class) order
@@ -1562,6 +1669,7 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                            if getattr(prior, "_node_w", None) is not None
                            else None),
             }
+        trees_host.update(sets)
         f0_host = np.asarray(telemetry.device_get(f0, pipeline="train"))
         model = GBMModel(self._model_key(), self.params,
                          spec, dist_name, f0_host, trees_host,
@@ -1591,6 +1699,15 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         }
         model.scoring_history = keeper.history
         if with_metrics:
+            # how many splits the trees hold and how many are sets: host
+            # counts of arrays already fetched
+            for name, n, what in (
+                    ("h2o3_tree_splits_total", int(spl.sum()),
+                     "splits in the trees of finished tree trains"),
+                    ("h2o3_tree_set_splits_total",
+                     int(th["is_set"].sum()) if "is_set" in th else 0,
+                     "of them splits on a set of an enum's levels")):
+                telemetry.counter(name, {"algo": self.algo}, help=what).inc(n)
             # final metrics from the training margin (exact, no
             # re-predict); in-training checkpoints skip this — they are
             # resume state, not reporting artifacts

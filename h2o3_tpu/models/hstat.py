@@ -105,6 +105,8 @@ def friedman_popescu_h(model, frame, variables: Sequence[str]) -> float:
     n = float(V.shape[0])
     k = len(fids)
 
+    from h2o3_tpu.models.tree import refuse_set_splits
+    refuse_set_splits(model, "the H statistic")
     feat = np.asarray(model._feat)
     thr = np.asarray(model._thr)
     na_left = np.asarray(model._na_left)

@@ -128,24 +128,61 @@ _AUC_BINS = 1 << _AUC_BIN_BITS
 _EXACT_SWEEP_ROWS = 200_000
 
 
+def _bucket_sums_by_product(b, vp, vn):
+    """Per-bucket sums of ``vp`` and ``vn`` ([_AUC_BINS] each) with no
+    scatter: the bucket id split into a high and a low part (512 x 256),
+    the sums one [rows, 512]^T x [rows, 2 * 256] product of two one-hots
+    that fuse into the product's operands. The values go in as their
+    three exact bfloat16 terms against a 0/1 one-hot, so every product is
+    exact and the sums are f32 accumulations, as the scatter's are. On
+    the TPU three scatters over the rows were all of a train's
+    ``finalize_s`` (0.25 s at 10M rows, 0.84-1.03 s at 40M) and the part
+    of it that moved from run to run (PERF.md section 6, PR 33)."""
+    n_lo = 1 << (_AUC_BIN_BITS // 2)
+    n_hi = _AUC_BINS // n_lo
+    hi = (b // n_lo)[:, None] == jnp.arange(n_hi)[None, :]
+    col = jnp.arange(2 * n_lo)[None, :]
+    right = jnp.where((b % n_lo)[:, None] == col % n_lo,
+                      jnp.where(col < n_lo, vp[:, None], vn[:, None]), 0.0)
+    tot = jnp.zeros((n_hi, 2 * n_lo), jnp.float32)
+    for _ in range(3):
+        term = jax.lax.reduce_precision(right, 8, 7)   # not a cast: XLA
+        tot = tot + jax.lax.dot_general(               # may elide one
+            hi.astype(jnp.bfloat16), term.astype(jnp.bfloat16),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        right = right - term
+    return tot[:, :n_lo].reshape(-1), tot[:, n_lo:].reshape(-1)
+
+
 @jax.jit
 def _binned_curve_kernel(score, y, w):
     """Large-n curve summary: order-preserving float32-bit bucketisation
-    into 2^17 bins + scatter-add histograms (the AUC2 sketch idea,
-    hex/AUC2.java's 400 bins, at 300× finer resolution). Only scatter,
-    elementwise bit math, and a 2^17 cumsum — everything compiles fast
-    and nothing O(n) ever reaches the host."""
+    into 2^17 bins (the AUC2 sketch idea, hex/AUC2.java's 400 bins, at
+    300x finer resolution) and per-bucket weighted positives and
+    negatives: scatter-adds off the TPU, a factored one-hot product on it
+    (:func:`_bucket_sums_by_product`). The third result is each bucket's
+    LOWEST score, the threshold at which all of its rows count (computed
+    from the bucket id, no pass over the rows). Nothing O(n) ever
+    reaches the host."""
     s32 = score.astype(jnp.float32)
     bits = jax.lax.bitcast_convert_type(s32, jnp.uint32)
     # standard float radix trick: flip all bits for negatives, set the
     # sign bit for positives → unsigned keys in score order
     key = jnp.where((bits >> 31) == 1, ~bits,
                     bits | jnp.uint32(0x80000000))
-    b = (key >> (32 - _AUC_BIN_BITS)).astype(jnp.int32)
-    hp = jnp.zeros(_AUC_BINS, jnp.float32).at[b].add(w * y)
-    hn = jnp.zeros(_AUC_BINS, jnp.float32).at[b].add(w * (1.0 - y))
-    smax = jnp.full(_AUC_BINS, -jnp.inf, jnp.float32).at[b].max(s32)
-    return hp, hn, smax
+    shift = 32 - _AUC_BIN_BITS
+    b = (key >> shift).astype(jnp.int32)
+    vp, vn = w * y, w * (1.0 - y)
+    if jax.default_backend() == "tpu":
+        hp, hn = _bucket_sums_by_product(b, vp, vn)
+    else:
+        hp = jnp.zeros(_AUC_BINS, jnp.float32).at[b].add(vp)
+        hn = jnp.zeros(_AUC_BINS, jnp.float32).at[b].add(vn)
+    edge_key = jnp.arange(_AUC_BINS, dtype=jnp.uint32) << shift
+    edge = jax.lax.bitcast_convert_type(
+        jnp.where((edge_key >> 31) == 1, edge_key & jnp.uint32(0x7FFFFFFF),
+                  ~edge_key), jnp.float32)
+    return hp, hn, edge
 
 
 @jax.jit
@@ -177,11 +214,11 @@ def _binary_curve(prob, y, w):
         is_b = np.concatenate([s[1:] != s[:-1], [True]])
         sb, tpb, fpb = s[is_b], tp[is_b], fp[is_b]
     else:
-        hp, hn, smax = (np.asarray(v) for v in
+        hp, hn, edge = (np.asarray(v) for v in
                         _binned_curve_kernel(prob, y, w))
-        occ = np.isfinite(smax) & ((hp > 0) | (hn > 0))
+        occ = np.isfinite(edge) & ((hp > 0) | (hn > 0))
         # descending score order
-        sb = smax[occ][::-1]
+        sb = edge[occ][::-1]
         tpb = np.cumsum(hp[occ][::-1])
         fpb = np.cumsum(hn[occ][::-1])
     P = float(tpb[-1]) if len(tpb) else 0.0

@@ -189,10 +189,15 @@ class H2ORuleFitEstimator(ModelBuilder):
                     ntrees=per_depth, max_depth=d, seed=seed,
                     learn_rate=0.1, distribution=p.get("distribution",
                                                        "auto"),
+                    # a rule is a conjunction of thresholds: enum columns
+                    # enter as their level index
+                    categorical_encoding="label_encoder",
                     weights_column="__w" if "__w" in frame else None)
                 gbm.train(y="__response", x=list(spec.names),
                           training_frame=frame)
                 gm = gbm.model
+                from h2o3_tpu.models.tree import refuse_set_splits
+                refuse_set_splits(gm, "rule extraction")
                 feat = np.asarray(jax.device_get(gm._feat))
                 thr = np.asarray(jax.device_get(gm._thr))
                 nal = np.asarray(jax.device_get(gm._na_left))

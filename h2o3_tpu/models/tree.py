@@ -168,6 +168,21 @@ class TreeConfig:
     # default — deviation bound quantified in ops/hist_adaptive.py) or
     # 'float32' (6-pass HIGHEST, exact); 'auto' = bfloat16
     histogram_precision: str = "auto"
+    # CATEGORY-SET SPLITS (the packed path of a frame with enum features,
+    # prepare_tree_inputs): per feature whether it splits by a set of its
+    # levels, its real bin count, and its lane count on the level
+    # kernel's global lane axis (ops/binning.lane_widths). All empty: no
+    # set feature, every feature pick_W(n_bins) lanes and a threshold.
+    set_feats: tuple = ()
+    bin_counts: tuple = ()
+    lane_widths: tuple = ()
+
+    @property
+    def set_words(self) -> int:
+        """uint32 words of a node's exported set: a bit a level of the
+        widest set feature."""
+        cards = [n for n, s in zip(self.bin_counts, self.set_feats) if s]
+        return -(-max(cards, default=0) // 32)
 
     @property
     def n_nodes(self) -> int:
@@ -222,9 +237,27 @@ def _find_splits(trip, cfg: TreeConfig, col_mask, mono=None,
     h = trip[1][:, :F, :]
     w = trip[2][:, :F, :]
     g_na, h_na, w_na = g[..., B], h[..., B], w[..., B]
-    cg = jnp.cumsum(g[..., :B], axis=-1)
-    ch = jnp.cumsum(h[..., :B], axis=-1)
-    cw = jnp.cumsum(w[..., :B], axis=-1)
+    gb, hb, wb = g[..., :B], h[..., :B], w[..., :B]
+    if cfg.set_feats:
+        # a set feature's bins in the order of G/(H + lambda): by the
+        # convexity of the score the best two-way partition of its levels
+        # is a prefix of that order (Fisher 1958), so the scan below
+        # serves it unchanged. Levels no row of the node has sort last and
+        # are never a candidate; a numeric feature's key is its bin index,
+        # which the stable sort leaves in place. The sums ride the sort:
+        # no gather
+        isset = jnp.asarray(cfg.set_feats)[None, :, None]
+        lane = jax.lax.broadcasted_iota(jnp.int32, gb.shape, 2)
+        present = wb > 0
+        key = jnp.where(isset, jnp.where(
+            present, gb / (hb + cfg.reg_lambda + 1e-12), jnp.inf),
+            lane.astype(jnp.float32))
+        _, gb, hb, wb, order = jax.lax.sort(
+            (key, gb, hb, wb, lane), dimension=2, num_keys=1, is_stable=True)
+        n_present = present.sum(axis=-1)                          # [N, F]
+    cg = jnp.cumsum(gb, axis=-1)
+    ch = jnp.cumsum(hb, axis=-1)
+    cw = jnp.cumsum(wb, axis=-1)
     g_tot = cg[..., -1] + g_na
     h_tot = ch[..., -1] + h_na
     w_tot = cw[..., -1] + w_na
@@ -255,7 +288,14 @@ def _find_splits(trip, cfg: TreeConfig, col_mask, mono=None,
     all_gains = jnp.stack([gains_nr, gains_nl], axis=-1)             # [N,F,B-1,2]
     cm = col_mask if col_mask.ndim == 2 else col_mask[None, :]
     all_gains = jnp.where(cm[:, :, None, None], all_gains, NEG_INF)
-    if max_bin is not None and max_bin - 1 < B - 1:
+    if cfg.set_feats:
+        # candidates t = 1 .. (a numeric feature's bins, a set feature's
+        # levels present in the node) - 1
+        last = jnp.where(isset[..., 0], n_present,
+                         jnp.asarray(cfg.bin_counts)[None, :])
+        tmask = jnp.arange(B - 1)[None, None, :] < (last[..., None] - 1)
+        all_gains = jnp.where(tmask[..., None], all_gains, NEG_INF)
+    elif max_bin is not None and max_bin - 1 < B - 1:
         tmask = jnp.arange(B - 1) < (max_bin - 1)
         all_gains = jnp.where(tmask[None, None, :, None], all_gains,
                               NEG_INF)
@@ -284,9 +324,23 @@ def _find_splits(trip, cfg: TreeConfig, col_mask, mono=None,
     vr_sel = _leaf_value(gt_s - gl_s, ht_s - hl_s, cfg)
     wr_sel = w_tot[nidx, 0] - wl_s
     # f=0 slice of per-feature totals == node totals
-    return (best_gain, feat.astype(jnp.int32), bin_idx.astype(jnp.int32),
-            na_left, g_tot[:, 0], h_tot[:, 0], w_tot[:, 0], vl_sel, vr_sel,
-            wl_s, wr_sel)
+    out = (best_gain, feat.astype(jnp.int32), bin_idx.astype(jnp.int32),
+           na_left, g_tot[:, 0], h_tot[:, 0], w_tot[:, 0], vl_sel, vr_sel,
+           wl_s, wr_sel)
+    if not cfg.set_feats:
+        return out
+    # the chosen split as a SET over the feature's B real bins: the first
+    # ``bin_idx`` of its order (a numeric feature's: the bins below the
+    # threshold); a level no row of the node has goes where NA goes
+    chosen = (jnp.arange(F)[None, :] == feat[:, None])[..., None]  # [N,F,1]
+    order_sel = jnp.sum(jnp.where(chosen, order, 0), axis=1)       # [N, B]
+    here = jnp.any(chosen & present, axis=1)
+    pos = jax.lax.broadcasted_iota(jnp.int32, order_sel.shape, 1)
+    _, rank = jax.lax.sort((order_sel, pos), dimension=1, num_keys=1)
+    set_split = jnp.asarray(cfg.set_feats)[feat]                   # [N]
+    left = jnp.where(set_split[:, None] & ~here, na_left[:, None],
+                     rank < bin_idx[:, None])
+    return out + (left, set_split)
 
 
 def _find_splits_sharded(trip, cfg: TreeConfig, col_mask, mono=None,
@@ -303,7 +357,9 @@ def _find_splits_sharded(trip, cfg: TreeConfig, col_mask, mono=None,
     flattened candidate order is feature-major and shard blocks are
     contiguous feature ranges, so "first max wins" picks the same split
     — sharded and unsharded trees stay bit-identical."""
-    if model_axis is None:
+    if model_axis is None or cfg.set_feats:
+        # a set split's left set does not ride the winners' all_gather:
+        # every model shard scans all features of a frame that has them
         return _find_splits(trip, cfg, col_mask, mono=mono,
                             max_bin=max_bin)
     n_model = jax.lax.axis_size(model_axis)
@@ -578,17 +634,22 @@ def packed_bins_upper_bound(spec, params) -> int:
     return max(nbins, min(mc, nc + 1), 2)
 
 
-def binned_feasible(n_bins: int, n_features: int, max_depth: int) -> bool:
+def binned_feasible(n_bins: int, n_features: int, max_depth: int,
+                    lanes: Optional[int] = None) -> bool:
     """Whether the packed binned kernel's deepest level fits VMEM —
     the adaptive_feasible bound applied to W = pick_W(n_bins) (scratch
     + output block both hold [3·2^(D-1), F·W] f32). Past the 254-bin
     lane cap or the VMEM bound, the matmul/scatter global-sketch path
-    takes over."""
+    takes over. ``lanes``: the level's lane count under per-feature lane
+    widths (a frame with set features), which has no 254-bin cap: a
+    feature there is as wide as its own bins."""
     from h2o3_tpu.ops.hist_adaptive import pick_W
+    n_deep = 2 ** max(max_depth - 1, 0)
+    if lanes is not None:
+        return 2 * 3 * n_deep * lanes * 4 <= 96 * 2 ** 20
     if n_bins > 254:
         return False
     W = pick_W(n_bins)
-    n_deep = 2 ** max(max_depth - 1, 0)
     return 2 * 3 * n_deep * n_features * W * 4 <= 96 * 2 ** 20
 
 
@@ -641,7 +702,8 @@ def tree_config(params, max_depth: int, n_bins: int, n_features: int,
 
 def tree_path(hist_type: str, packed_requested: bool, n_bins: Optional[int],
               n_features: int, max_depth: int, *, adaptive_fits: bool,
-              random_is_adaptive: bool = True) -> str:
+              random_is_adaptive: bool = True,
+              lanes: Optional[int] = None) -> str:
     """Which grower a tree train runs, as a pure function of what the
     trainers know: ``"packed"`` (grow_tree_binned on int8/int16 codes),
     ``"adaptive"`` (grow_tree_adaptive on raw features) or ``"sketch"``
@@ -658,7 +720,9 @@ def tree_path(hist_type: str, packed_requested: bool, n_bins: Optional[int],
 
     - packed: requested, not ``random`` (its per-tree grid phase needs
       the per-level rebinning that packing removes), and the deepest
-      level's accumulators fit (:func:`binned_feasible`);
+      level's accumulators fit (:func:`binned_feasible`; ``lanes`` is
+      the level's lane count where the frame's features have their own
+      lane widths, or a bound on it);
     - else adaptive: a uniform histogram type (``random`` among them
       where ``random_is_adaptive``: GBM; DRF bins ``random`` by the
       global sketch) whose kernel fits;
@@ -666,7 +730,7 @@ def tree_path(hist_type: str, packed_requested: bool, n_bins: Optional[int],
       or a depth whose level fits neither kernel."""
     if (packed_requested and hist_type != "random"
             and (n_bins is None
-                 or binned_feasible(n_bins, n_features, max_depth))):
+                 or binned_feasible(n_bins, n_features, max_depth, lanes))):
         return "packed"
     uniform = ADAPTIVE_HIST_TYPES + (("random",) if random_is_adaptive
                                      else ())
@@ -738,12 +802,17 @@ class TreeInputs(NamedTuple):
         return (codes.rm, codes.t if has_t else codes.rm, has_t,
                 self.pc.na_bin if self.packed else self.bm.na_bin)
 
+    @property
+    def set_features(self) -> int:
+        """Features that split by a set of their levels."""
+        return sum(self.cfg.set_feats)
+
     def loop_attrs(self) -> dict:
         """The loop span's attributes: the record's keys."""
         if not self.packed:
             return {}
         return {"W": self.pc.W, "code_bytes": self.pc.itemsize,
-                **self.level_plan}
+                "set_features": self.set_features, **self.level_plan}
 
     def record(self) -> dict:
         """``model.output["packed_codes"]``: what the level kernel
@@ -753,19 +822,34 @@ class TreeInputs(NamedTuple):
         return packed_codes_record(
             True, dtype=self.pc.rm.dtype, W=self.pc.W,
             bytes_per_value=self.pc.itemsize, n_bins=self.bm.n_bins,
-            plan=self.level_plan)
+            plan=self.level_plan, set_features=self.set_features)
+
+
+def set_split_features(spec, params) -> tuple:
+    """Per feature whether it can split by a SET of its levels: an enum
+    of at most ``nbins_cats`` levels (wider ones keep the grouping of
+    adjacent levels); () where there is none."""
+    nc = int(params.get("nbins_cats", 1024))
+    sf = tuple(bool(c) and 0 < len(spec.cat_domains.get(n, ())) <= nc
+               for n, c in zip(spec.names, spec.is_cat))
+    return sf if any(sf) else ()
 
 
 def prepare_tree_inputs(spec, params, max_depth: int, *, prof,
                         mtries: int = 0,
-                        random_is_adaptive: bool) -> TreeInputs:
+                        random_is_adaptive: bool,
+                        set_splits: bool = False) -> TreeInputs:
     """The bin stage of every dense tree trainer (GBM, XGBoost, DRF): the
     path decision (:func:`tree_path`), the sketch, digitise and pack it
     calls for, the TreeConfig, and the packed levels' plan.
 
     ``mtries`` is DRF's per-node feature subset; ``random_is_adaptive``
     is the trainer's reading of ``histogram_type="random"`` (see
-    tree_path). The trainer's ``prof`` (its ``log.Profile``) times the
+    tree_path). ``set_splits`` (GBM under ``categorical_encoding`` auto
+    or enum): on the packed path an enum feature (:func:`set_split_features`)
+    splits by a set of its levels, every feature gets the lanes of its
+    own bin count (ops/binning.lane_widths) and the levels route by set;
+    off that path enums keep ordinal thresholds. The trainer's ``prof`` (its ``log.Profile``) times the
     stage as phase ``bin`` with ``bin.sketch``, ``bin.digitize``
     (ops/binning.bin_matrix_device) and ``bin.pack`` inside it, each
     ended by a fence on what it dispatched: the digitise's temporaries
@@ -778,12 +862,22 @@ def prepare_tree_inputs(spec, params, max_depth: int, *, prof,
                    n_features=F, max_depth=max_depth,
                    adaptive_fits=adaptive_feasible(spec, p, max_depth),
                    random_is_adaptive=random_is_adaptive)
+    from h2o3_tpu.ops.binning import lane_widths
+    set_feats = set_split_features(spec, p) if set_splits else ()
     with prof.phase("bin"):
         # from the categorical domains alone: where packing cannot come in
         # under its lane and VMEM caps, take the adaptive kernel without
         # paying the sketch and digitise
-        mode = path(packed_bins_upper_bound(spec, p))
+        lanes = None
+        if set_feats:
+            nb, nc = max(int(p["nbins"]), 2), int(p["nbins_cats"])
+            lanes = sum(lane_widths(
+                [len(spec.cat_domains[n]) if s else
+                 min(len(spec.cat_domains.get(n, ())), nc + 1) if c else nb
+                 for n, c, s in zip(spec.names, spec.is_cat, set_feats)]))
+        mode = path(packed_bins_upper_bound(spec, p), lanes=lanes)
         bm = pc = level_plan = None
+        widths = bin_counts = ()
         if mode != "adaptive":
             # device-side sketch: X never leaves HBM. While packing is
             # still on offer the int32 transposed operand (with_t) is
@@ -798,7 +892,12 @@ def prepare_tree_inputs(spec, params, max_depth: int, *, prof,
             # the sketch's own bin count: past the 254-lane cap or VMEM,
             # packing falls back to the fused adaptive kernel, not to the
             # slow matmul path the sketch would otherwise route to
-            mode = path(bm.n_bins)
+            if set_feats:
+                bin_counts = tuple(len(e) + 1 for e in bm.edges)
+                widths = lane_widths(bin_counts)
+            mode = path(bm.n_bins, lanes=sum(widths) if widths else None)
+        if mode != "packed":
+            set_feats = widths = bin_counts = ()    # enums stay ordinal
         if mode == "adaptive":
             bm = None
             cfg, root_lo, root_hi, nb_f = adaptive_setup(
@@ -806,7 +905,7 @@ def prepare_tree_inputs(spec, params, max_depth: int, *, prof,
         else:
             if mode == "packed":
                 with prof.phase("bin.pack"):
-                    pc = pack_codes(bm)
+                    pc = pack_codes(bm, widths=widths)
                     # free the int32 code view: the packed layouts replace
                     # it (1-2 bytes/value x2 <= half the f32 X footprint);
                     # only bm.edges / n_bins are read from here on
@@ -816,6 +915,10 @@ def prepare_tree_inputs(spec, params, max_depth: int, *, prof,
                     jax.block_until_ready(pc)  # h2o3-lint: allow[transfer-seam] bin-stage timing fence: replaces time the loop-entry fence already waited, unattributed
             cfg = tree_config(p, max_depth, bm.n_bins, bm.n_features,
                               mtries=min(mtries, bm.n_features))
+            if set_feats:
+                from dataclasses import replace as dc_replace
+                cfg = dc_replace(cfg, set_feats=set_feats,
+                                 bin_counts=bin_counts, lane_widths=widths)
             root_lo = jnp.zeros(cfg.n_features, jnp.float32)
             root_hi = jnp.zeros(cfg.n_features, jnp.float32)
             nb_f = jnp.zeros(cfg.n_features, jnp.float32)
@@ -825,7 +928,8 @@ def prepare_tree_inputs(spec, params, max_depth: int, *, prof,
             # value (by the tree's size): for the loop span and the
             # model's record
             level_plan = {
-                **binned_level_plan(pc.W, cfg.n_features, binned_method(cfg)),
+                **binned_level_plan(pc.W, cfg.n_features, binned_method(cfg),
+                                    widths),
                 "leaf_lookup": node_lookup_form(cfg.n_nodes),
                 "n_nodes": cfg.n_nodes}
         else:
@@ -1102,7 +1206,50 @@ def _binned_split_level(trip, find_cfg: TreeConfig, level_mask,
     tables = (jnp.maximum(bf, 0).astype(jnp.float32),
               bb.astype(jnp.float32),
               bnl.astype(jnp.float32), can.astype(jnp.float32))
+    if cfg.set_feats:
+        # routing by set: the chosen feature's lane offset rides where the
+        # bin did, and a fifth table holds the left set over the feature's
+        # LOCAL codes, its NA code's entry the NA direction
+        from h2o3_tpu.ops.hist_adaptive import lane_offsets
+        f = jnp.maximum(bf, 0)
+        na_code = jnp.asarray(cfg.lane_widths)[f] - 1
+        code = jnp.arange(max(cfg.lane_widths))[None, :]
+        left = jnp.pad(sel[11], ((0, 0), (0, 1)))       # [N, W], W = B + 1
+        left = jnp.where(code == na_code[:, None], bnl[:, None], left)
+        tables = (tables[0],
+                  jnp.asarray(lane_offsets(cfg.lane_widths),
+                              jnp.float32)[f],
+                  tables[2], tables[3], left.astype(jnp.float32))
     return sel, can, tables
+
+
+def padded_level_hist(hist, cfg: TreeConfig):
+    """A level's flat histogram [3, N, lanes] (features at their lane
+    offsets, ``cfg.lane_widths``) as the split search's [3, N, F, W]:
+    each feature's real bins from lane 0, its NA lane last, W the widest
+    feature's lane count."""
+    from h2o3_tpu.ops.hist_adaptive import lane_offsets
+    W = max(cfg.lane_widths)
+    cols = []
+    for off, w, nb in zip(lane_offsets(cfg.lane_widths), cfg.lane_widths,
+                          cfg.bin_counts):
+        cols.append(jnp.concatenate(
+            [hist[..., off:off + nb],
+             jnp.zeros(hist.shape[:2] + (W - 1 - nb,), hist.dtype),
+             hist[..., off + w - 1:off + w]], axis=-1))
+    return jnp.stack(cols, axis=2)
+
+
+def pack_set_bits(left, set_split, can, n_words: int):
+    """[N, n_words] uint32: bit ``b`` of a node's words says level ``b``
+    of its split feature goes left; zeros where the node splits on no
+    set."""
+    bits = jnp.pad(left, ((0, 0), (0, max(n_words * 32 - left.shape[1], 0)))
+                   )[:, :n_words * 32]
+    bits = bits & (set_split & can)[:, None]
+    words = bits.reshape(-1, n_words, 32).astype(jnp.uint32) << jnp.arange(
+        32, dtype=jnp.uint32)
+    return words.sum(axis=-1, dtype=jnp.uint32)
 
 
 def _level_record(sel, can, cfg: TreeConfig):
@@ -1190,10 +1337,13 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
     D = cfg.max_depth
     M = cfg.n_nodes
     rows, F = codes_rm.shape
-    W = pick_W(cfg.n_bins)
+    widths = cfg.lane_widths
+    W = max(widths) if widths else pick_W(cfg.n_bins)
     method = binned_method(cfg)
     mxu_dtype = _hist_mxu_dtype(cfg, rows)
     find_cfg = dc_replace(cfg, n_bins=W - 1)   # NA lane at W-1
+    cat_set = jnp.zeros((M, cfg.set_words), jnp.uint32)
+    set_split = jnp.zeros(M, bool)
 
     feat = jnp.full(M, -1, jnp.int32)
     split_bin = jnp.zeros(M, jnp.int32)
@@ -1207,6 +1357,8 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
     nid = jnp.zeros(rows, jnp.int32)
     zeros1 = jnp.zeros(1, jnp.float32)
     tables = (zeros1, zeros1, zeros1, zeros1)
+    if widths:
+        tables += (jnp.zeros((1, W), jnp.float32),)    # the root has no set
     lo_b = jnp.full(1, -BIGV)
     hi_b = jnp.full(1, BIGV)
     allowed = (jnp.ones((1, F), bool) if sets is not None else None)
@@ -1224,6 +1376,8 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
         tree = {"feat": feat, "split_bin": split_bin, "na_left": na_left,
                 "is_split": is_split, "value": value, "gain": gain_arr,
                 "node_w": node_w}
+        if cfg.set_feats:
+            tree.update(cat_set=cat_set, set_split=set_split)
         return tree, nid
 
     vl_s = vr_s = wl_s = wr_s = None
@@ -1232,9 +1386,11 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
         base = N - 1
         nid, hist = binned_level(codes_rm, nid, ghw, tables,
                                  N // 2 if d else 0, N, base, W, method,
-                                 mxu_dtype=mxu_dtype, ct=ct)
+                                 mxu_dtype=mxu_dtype, ct=ct, widths=widths)
         if axis_name is not None:
             hist = jax.lax.psum(hist, axis_name)
+        if widths:
+            hist = padded_level_hist(hist, cfg)
         trip = (hist[0], hist[1], hist[2])
         level_mask = col_mask
         mt_d = _level_mtries(cfg, d, F)
@@ -1249,9 +1405,13 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
         sel, can, tables = _binned_split_level(trip, find_cfg, level_mask,
                                                cfg, mono=mono,
                                                model_axis=model_axis)
-        bg, bf, bb, bnl, gt, ht, wt, vl_s, vr_s, wl_s, wr_s = sel
+        bg, bf, bb, bnl, gt, ht, wt, vl_s, vr_s, wl_s, wr_s = sel[:11]
         nidx = jnp.arange(N)
         idx = base + nidx
+        if cfg.set_feats:
+            cat_set = cat_set.at[idx].set(
+                pack_set_bits(sel[11], sel[12], can, cfg.set_words))
+            set_split = set_split.at[idx].set(sel[12] & can)
         feat = feat.at[idx].set(jnp.where(can, bf, -1))
         split_bin = split_bin.at[idx].set(bb)
         na_left = na_left.at[idx].set(bnl)
@@ -1293,6 +1453,8 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
     tree = {"feat": feat, "split_bin": split_bin, "na_left": na_left,
             "is_split": is_split, "value": value, "gain": gain_arr,
             "node_w": node_w}
+    if cfg.set_feats:
+        tree.update(cat_set=cat_set, set_split=set_split)
     return tree, nid
 
 
@@ -1410,9 +1572,19 @@ def grow_tree_spmd(codes, g, h, w, cfg: TreeConfig, col_mask,
     return tree, nid
 
 
+# segments up to which _segment_totals factors the node one-hot (64 x 64)
+_SEGMENT_FACTOR_MAX = 4096
+
+
 def _segment_totals(lid, valid, g, h, w, n_seg: int):
     """Per-node (g,h,w) sums. One-hot matmul for small node counts (TPU
-    scatter-add costs ~20ms/1M rows; the matmul is <1ms), scatter beyond."""
+    scatter-add costs ~20ms/1M rows; the matmul is <1ms). Up to
+    _SEGMENT_FACTOR_MAX segments (the leaves of depths 9 to 12) the node
+    id is split into a high and a low part, 2^k each: the sums are one
+    [rows, hi]^T x [rows, 3 lo] product of two small one-hots at HIGHEST
+    precision, exact as the scatter's adds are, where the scatter took
+    three passes of 20 ms a million rows (1.2 s a depth-10 tree at 40M
+    rows). Scatter beyond."""
     if n_seg <= 256:
         oh = (lid[:, None] == jnp.arange(n_seg)[None, :]).astype(jnp.float32)
         ghw = jnp.stack([jnp.where(valid, g, 0.0), jnp.where(valid, h, 0.0),
@@ -1420,17 +1592,73 @@ def _segment_totals(lid, valid, g, h, w, n_seg: int):
         tot = jax.lax.dot_general(oh, ghw, (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         return tot[:, 0], tot[:, 1], tot[:, 2]
+    if n_seg <= _SEGMENT_FACTOR_MAX:
+        n_lo = 1 << ((n_seg - 1).bit_length() // 2)
+        n_hi = -(-n_seg // n_lo)
+        hi = (lid // n_lo)[:, None] == jnp.arange(n_hi)[None, :]
+        # column c of the right side: (g, h, w)[c // n_lo] where the row's
+        # low part is c % n_lo; elementwise in (row, c), so it fuses into
+        # the product's operand and no [rows, 3 lo] array exists
+        col = jnp.arange(3 * n_lo)[None, :]
+        val = jnp.where(col < n_lo, g[:, None],
+                        jnp.where(col < 2 * n_lo, h[:, None], w[:, None]))
+        right = jnp.where(((lid % n_lo)[:, None] == col % n_lo)
+                          & valid[:, None], val, 0.0)
+        tot = jax.lax.dot_general(
+            hi.astype(jnp.float32), right, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)            # [hi, 3 * lo]
+        tot = tot.reshape(n_hi, 3, n_lo).transpose(1, 0, 2).reshape(
+            3, n_hi * n_lo)[:, :n_seg]
+        return tot[0], tot[1], tot[2]
     gD = jnp.zeros(n_seg, jnp.float32).at[lid].add(jnp.where(valid, g, 0.0))
     hD = jnp.zeros(n_seg, jnp.float32).at[lid].add(jnp.where(valid, h, 0.0))
     wD = jnp.zeros(n_seg, jnp.float32).at[lid].add(jnp.where(valid, w, 0.0))
     return gD, hD, wD
 
 
-def predict_binned(codes, tree, max_depth: int, na_bin: int):
+def refuse_set_splits(model, what: str) -> None:
+    """For a reader of ``thr`` that cannot read a set: a clear error on a
+    model with category-set splits, where it would misread NaN
+    thresholds."""
+    if getattr(model, "_cat_set", None) is not None:
+        raise NotImplementedError(
+            f"{what} reads every split as a threshold, and model "
+            f"{getattr(model, 'key', '?')} splits enum columns on sets of "
+            f"levels (categorical_encoding 'auto'/'enum'); train with "
+            f"categorical_encoding='label_encoder' for thresholds on the "
+            f"level index")
+
+
+def set_levels(words: np.ndarray, n_levels: int) -> np.ndarray:
+    """A node's exported set words [n] uint32 as a bool per level."""
+    bits = (np.asarray(words, np.uint32)[:, None]
+            >> np.arange(32, dtype=np.uint32)) & 1
+    out = np.zeros(n_levels, bool)
+    k = min(n_levels, bits.size)
+    out[:k] = bits.reshape(-1)[:k].astype(bool)
+    return out
+
+
+def set_bit(words, code):
+    """Whether bit ``code`` of a set is on: ``words`` [..., n] uint32 a
+    row, ``code`` [...] int32; False outside the words."""
+    n = words.shape[-1]
+    inside = (code >= 0) & (code < 32 * n)
+    c = jnp.clip(code, 0, 32 * n - 1)
+    word = jnp.take_along_axis(words, (c >> 5)[..., None], axis=-1)[..., 0]
+    return inside & (((word >> (c & 31).astype(jnp.uint32)) & 1) == 1)
+
+
+def predict_binned(codes, tree, max_depth: int, na_bin):
     """Prediction on a binned matrix (leaf lookup); one packed-word gather
-    per level (see grow_tree routing)."""
+    per level (see grow_tree routing). ``na_bin`` is the NA code, one a
+    feature where the codes are packed under per-feature lane widths; a
+    tree with ``cat_set`` sends a row at a set split left iff its code's
+    bit is on (validation margins inside the boost chunk)."""
     rm = codes.rm if isinstance(codes, CodesView) else codes
     rows = rm.shape[0]
+    na_of = jnp.asarray(na_bin, jnp.int32)
     word = (jnp.maximum(tree["feat"], 0)
             | (tree["split_bin"] << BIN_SHIFT)
             | (tree["na_left"].astype(jnp.int32) << NA_SHIFT)
@@ -1446,17 +1674,29 @@ def predict_binned(codes, tree, max_depth: int, na_bin: int):
         s = ((rw >> SPLIT_SHIFT) & 1).astype(bool)
         c = jnp.take_along_axis(rm, f[:, None], axis=1)[:, 0]
         c = c.astype(jnp.int32)
-        is_na = c == na_bin
+        is_na = c == (na_of[f] if na_of.ndim else na_of)
         go_right = jnp.where(is_na, ~nl, c >= b)
+        if "cat_set" in tree:
+            go_right = jnp.where(
+                tree["set_split"][nid] & ~is_na,
+                ~set_bit(tree["cat_set"][nid], c), go_right)
         nid = jnp.where(s, 2 * nid + 1 + go_right.astype(jnp.int32), nid)
     return node_lookup(tree["value"], nid), nid
 
 
+def _level_of(x):
+    """A column's values as enum levels: the level index, -1 where the
+    value is NA or no level (negative, fractional parts dropped)."""
+    return jnp.where((x >= 0) & (x < 2.0 ** 30), x, -1.0).astype(jnp.int32)
+
+
 def _score_tree_gather(X, feat, thr, na_left, is_split, value,
-                       max_depth: int):
+                       max_depth: int, cat_set=None, is_set=None):
     """A row's leaf value in one tree by per-row lookups: four node tables
     and one element of ``X`` a level, then the value; flat in the tree's
-    size."""
+    size. With ``cat_set`` [M, n] / ``is_set`` [M] two more: a row at a
+    set split goes left iff its level's bit is on, and where NA goes if
+    the level lies outside the words."""
     nid = jnp.zeros(X.shape[0], jnp.int32)
     for _ in range(max_depth):
         f = feat[nid]
@@ -1465,27 +1705,45 @@ def _score_tree_gather(X, feat, thr, na_left, is_split, value,
         nl = na_left[nid]
         x = jnp.take_along_axis(X, jnp.maximum(f, 0)[:, None], axis=1)[:, 0]
         go_right = jnp.where(jnp.isnan(x), ~nl, x >= th)
+        if cat_set is not None:
+            lvl = _level_of(x)
+            known = (lvl >= 0) & (lvl < 32 * cat_set.shape[-1])
+            go_right = jnp.where(
+                is_set[nid], jnp.where(known, ~set_bit(cat_set[nid], lvl),
+                                       ~nl), go_right)
         nid = jnp.where(s, 2 * nid + 1 + go_right.astype(jnp.int32), nid)
     return value[nid]
 
 
-def _block_step(XT, feat, thr, right_if_na, is_split, low):
+def _block_step(XT, feat, thr, right_if_na, is_split, low, cat_set=None,
+                is_set=None):
     """(go_right, is_split) of every row at node ``low`` of a block of
     nodes (tables [B], ``feat`` clipped at 0): each node's predicate over
     ALL rows from scalars of its tables and one contiguous column of
     ``XT``, then the row's own selected on ``low``'s bits. Plain ``lax``
-    calls: the body is traced again on every eager call."""
+    calls: the body is traced again on every eager call. With ``cat_set``
+    [B, n] / ``is_set`` [B] a node's predicate is the set's where the
+    node splits on one: the word selected on the level's high bits, its
+    bit tested, no per-row index."""
     go = []
     for j in range(feat.shape[0]):
         x = lax.dynamic_index_in_dim(XT, feat[j], 0, keepdims=False)
-        go.append(lax.select(lax.ne(x, x),
-                             lax.broadcast(right_if_na[j], x.shape),
-                             lax.ge(x, lax.broadcast(thr[j], x.shape))))
+        na = lax.broadcast(right_if_na[j], x.shape)
+        right = lax.select(lax.ne(x, x), na,
+                           lax.ge(x, lax.broadcast(thr[j], x.shape)))
+        if cat_set is not None:
+            lvl = _level_of(x)
+            n = cat_set.shape[-1]
+            word = _select_tree(cat_set[j], jnp.clip(lvl >> 5, 0, n - 1))
+            out = (((word >> (lvl & 31).astype(jnp.uint32)) & 1) == 0)
+            known = (lvl >= 0) & (lvl < 32 * n)
+            right = jnp.where(is_set[j], jnp.where(known, out, na), right)
+        go.append(right)
     return _select_tree(go, low), _select_tree(is_split, low)
 
 
 def _score_tree_predicates(XT, feat, thr, na_left, is_split, value,
-                           max_depth: int):
+                           max_depth: int, cat_set=None, is_set=None):
     """``_score_tree_gather``'s values with no per-row index: level d's
     2^d predicates are evaluated on whole columns and a row's own is
     selected on the bits of its place in the level (unrolled up to
@@ -1495,20 +1753,23 @@ def _score_tree_predicates(XT, feat, thr, na_left, is_split, value,
     elementwise work a row."""
     B = _PREDICATE_BLOCK
     feat, na_left = jnp.maximum(feat, 0), ~na_left
+    tabs = (feat, thr, na_left, is_split)
+    if cat_set is not None:
+        tabs += (cat_set, is_set)
     nid = jnp.zeros(XT.shape[1], jnp.int32)
     for d in range(max_depth):
         base, n = 2 ** d - 1, 2 ** d
         at = nid - base      # negative where the row stopped above
         if n <= B:
-            go, s = _block_step(XT, *(t[base:base + n] for t in
-                                      (feat, thr, na_left, is_split)), at)
+            go, s = _block_step(XT, *(t[base:base + n] for t in tabs[:4]), at,
+                                *(t[base:base + n] for t in tabs[4:]))
         else:
             low, high = at & (B - 1), at >> (B.bit_length() - 1)
 
             def block(k, acc):
-                got = _block_step(XT, *(
-                    lax.dynamic_slice_in_dim(t, base + k * B, B)
-                    for t in (feat, thr, na_left, is_split)), low)
+                cut = [lax.dynamic_slice_in_dim(t, base + k * B, B)
+                       for t in tabs]
+                got = _block_step(XT, *cut[:4], low, *cut[4:])
                 return tuple(jnp.where(high == k, g, a)
                              for g, a in zip(got, acc))
             none = jnp.zeros(nid.shape, bool)
@@ -1518,7 +1779,8 @@ def _score_tree_predicates(XT, feat, thr, na_left, is_split, value,
     return node_lookup(value, nid)
 
 
-def predict_raw_stacked(X, feat, thr, na_left, is_split, value, max_depth: int):
+def predict_raw_stacked(X, feat, thr, na_left, is_split, value, max_depth: int,
+                        cat_set=None, is_set=None):
     """Scoring-time prediction on raw features for a stack of T trees.
 
     feat/thr/... are [T, M]; X is [rows, F] float32 with NaN=NA.
@@ -1535,15 +1797,23 @@ def predict_raw_stacked(X, feat, thr, na_left, is_split, value, max_depth: int):
     batches (the serving buckets) keep the gathers, 5 a level and one for
     the value, whose cost has no per-node part. The rule
     (``scorer_node_form``) reads static shapes; both forms give the same
-    bits."""
+    bits.
+
+    ``cat_set`` [T, M, n] uint32 and ``is_set`` [T, M] (a model with
+    category-set splits, GBM on enum columns): a row at a node with
+    ``is_set`` goes left iff the bit of its level (the enum column's
+    value) is on in the node's words, and where NA goes if the value is
+    NA or past the words. Without them the program is the numeric one."""
     if scorer_node_form(feat.shape[1], X.shape[0]) == "predicate":
         Xs, score_tree = X.T, _score_tree_predicates
     else:
         Xs, score_tree = X, _score_tree_gather
 
     def one_tree(carry, t):
+        sets = {} if cat_set is None else {"cat_set": cat_set[t],
+                                           "is_set": is_set[t]}
         return carry, score_tree(Xs, feat[t], thr[t], na_left[t], is_split[t],
-                                 value[t], max_depth)
+                                 value[t], max_depth, **sets)
 
     _, contribs = lax.scan(one_tree, None, jnp.arange(feat.shape[0]))
     return contribs.T  # [rows, T]
@@ -1641,6 +1911,15 @@ def collect_chunk_trees(all_trees, M: int, edges) -> dict:
     else:
         out["thr"] = bins_to_thresholds_stacked(cat("split_bin"),
                                                 out["feat"], edges)
+    if "cat_set" in host[0]:
+        # category-set splits: the sets' words and which nodes hold one;
+        # such a node has no threshold
+        out["cat_set"] = np.concatenate(
+            [np.asarray(t["cat_set"])[:n].reshape(
+                (-1, M) + np.asarray(t["cat_set"]).shape[-1:])
+             for t, n in zip(host, acts)])
+        out["is_set"] = cat("set_split")
+        out["thr"] = np.where(out["is_set"], np.float32(np.nan), out["thr"])
     return out
 
 

@@ -281,8 +281,12 @@ class TreeScoringOptionsMixin:
         # which side of tree.scorer_node_form's rule a predict runs
         from h2o3_tpu.models.tree import scorer_node_form
         n_nodes = int(self._feat.shape[1])
-        return {"node_form": scorer_node_form(n_nodes, X.shape[0]),
-                "n_nodes": n_nodes}
+        out = {"node_form": scorer_node_form(n_nodes, X.shape[0]),
+               "n_nodes": n_nodes}
+        if getattr(self, "_set_nodes", None) is not None:
+            # nodes that test membership in a set of levels
+            out["set_nodes"] = self._set_nodes
+        return out
 
     def predict_contributions(self, frame, output_format: str = "original",
                               top_n: int = 0, bottom_n: int = 0,
@@ -297,6 +301,8 @@ class TreeScoringOptionsMixin:
         split on original columns directly (enum codes as floats), so
         there is no one-hot expansion to compact — unlike the reference's
         XGBoost path where 'original' re-expands 1-hot contributions."""
+        from h2o3_tpu.models.tree import refuse_set_splits
+        refuse_set_splits(self, "predict_contributions (TreeSHAP)")
         if str(output_format).lower() not in ("original", "compact"):
             raise ValueError(f"unknown output_format '{output_format}'")
         from h2o3_tpu.frame.frame import Frame
@@ -341,6 +347,8 @@ class TreeScoringOptionsMixin:
         from h2o3_tpu.frame.frame import Frame
         from h2o3_tpu.frame.vec import Vec
         from h2o3_tpu.models.model_base import adapt_test_matrix
+        from h2o3_tpu.models.tree import refuse_set_splits
+        refuse_set_splits(self, "predict_leaf_node_assignment")
         X = adapt_test_matrix(self, frame)
         out = leaf_node_assignment(
             np.asarray(jax.device_get(X)), self._feat, self._thr,
@@ -372,7 +380,9 @@ class TreeScoringOptionsMixin:
         margins = staged_margins(np.asarray(jax.device_get(X)), self._feat,
                                  self._thr, self._na_left, self._is_split,
                                  self._value, self.max_depth,
-                                 getattr(self, "f0", 0.0))
+                                 getattr(self, "f0", 0.0),
+                                 cat_set=getattr(self, "_cat_set", None),
+                                 is_set=getattr(self, "_is_set", None))
         p1 = np.asarray(jax.device_get(
             1.0 / (1.0 + jnp.exp(-margins))))[:frame.nrow]
         T = p1.shape[1]
@@ -416,15 +426,17 @@ def _ranked_contrib_frame(names, phi, bias, top_n, bottom_n, compare_abs):
 
 
 def staged_margins(X, feat, thr, na_left, is_split, value, max_depth: int,
-                   f0, K: int = 1):
+                   f0, K: int = 1, cat_set=None, is_set=None):
     """Cumulative margin after each boosting iteration
     (hex/Model.java staged_predict_proba): returns [rows, n_stages] (K=1)
-    or [rows, n_stages, K]."""
+    or [rows, n_stages, K]. ``cat_set`` / ``is_set``: the model's
+    category-set splits (tree.predict_raw_stacked)."""
     from h2o3_tpu.models.tree import predict_raw_stacked
     contribs = predict_raw_stacked(jnp.asarray(X), jnp.asarray(feat),
                                    jnp.asarray(thr), jnp.asarray(na_left),
                                    jnp.asarray(is_split), jnp.asarray(value),
-                                   max_depth)                 # [rows, T]
+                                   max_depth, cat_set=cat_set,
+                                   is_set=is_set)             # [rows, T]
     if K == 1:
         return jnp.asarray(f0) + jnp.cumsum(contribs, axis=1)
     rows = contribs.shape[0]

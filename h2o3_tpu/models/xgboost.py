@@ -65,6 +65,11 @@ _ALIAS = {
 
 class H2OXGBoostEstimator(H2OGradientBoostingEstimator):
     algo = "xgboost"
+    # XGBoost has no category-set split: an enum column is its level index
+    # and a threshold on it, which is what this estimator's ``auto`` means
+    # (H2O-3's resolves to one-hot, which is not built)
+    _SET_ENCODINGS = ()
+    _ORDINAL_ENCODINGS = ("auto", "label_encoder", "labelencoder")
 
     def __init__(self, **params):
         booster = (params.get("booster",

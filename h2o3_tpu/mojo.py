@@ -43,9 +43,15 @@ NA_RIGHT = 3   # NaSplitDir.NARight
 
 # ------------------------------------------------------------------ writer
 
-def _compress_tree(feat, thr, na_left, is_split, value) -> Tuple[bytes,
-                                                                 bytes]:
-    """Complete-binary-array tree → (tree_bytes, aux_bytes)."""
+def _compress_tree(feat, thr, na_left, is_split, value, right_bits=None
+                   ) -> Tuple[bytes, bytes]:
+    """Complete-binary-array tree → (tree_bytes, aux_bytes).
+    ``right_bits`` maps a node that splits on a set of an enum's levels
+    to a bool per level, True where the level goes RIGHT: such a node is
+    written as a bitset node (``equal`` bits 12: i32 bit offset, i32 bit
+    count, the bits), which SharedTreeMojoModel.scoreTree tests with
+    ``contains(level)`` = go right."""
+    right_bits = right_bits or {}
     ids = {}
     counter = [0]
 
@@ -80,10 +86,18 @@ def _compress_tree(feat, thr, na_left, is_split, value) -> Tuple[bytes,
             node_type |= slen
         if right_leaf:
             node_type |= 0xC0
+        bits = right_bits.get(m)
+        if bits is not None:
+            node_type |= 12
         out = io.BytesIO()
         out.write(struct.pack("<BHB", node_type, int(feat[m]),
                               NA_LEFT if na_left[m] else NA_RIGHT))
-        out.write(struct.pack("<f", float(thr[m])))
+        if bits is not None:
+            out.write(struct.pack("<ii", 0, len(bits)))
+            out.write(np.packbits(np.asarray(bits, bool),
+                                  bitorder="little").tobytes())
+        else:
+            out.write(struct.pack("<f", float(thr[m])))
         if not left_leaf:
             lsz = len(left)
             if lsz < 256:
@@ -191,6 +205,12 @@ def export_mojo(model, path: str) -> str:
     nal = np.asarray(nal)
     spl = np.asarray(spl)
     val = np.array(val)
+    # category-set splits (GBM on enum columns): per set node the levels
+    # that go right, over the column's domain
+    cat_set = is_set = None
+    if getattr(model, "_cat_set", None) is not None:
+        cat_set, is_set = (np.asarray(a) for a in telemetry.device_get(
+            (model._cat_set, model._is_set)))
     K = model.nclasses if model.nclasses > 2 else 1
     T = model.ntrees_built
     f0 = np.asarray(model.f0, dtype=np.float64).reshape(-1) \
@@ -267,8 +287,16 @@ def export_mojo(model, path: str) -> str:
         for t in range(T):
             for k in range(K):
                 row = t * K + k
+                right_bits = None
+                if is_set is not None:
+                    from h2o3_tpu.models.tree import set_levels
+                    right_bits = {
+                        int(n): ~set_levels(cat_set[row, n], len(
+                            model.cat_domains[model.feature_names[
+                                int(feat[row, n])]]))
+                        for n in np.flatnonzero(is_set[row])}
                 tree, aux = _compress_tree(feat[row], thr[row], nal[row],
-                                           spl[row], val[row])
+                                           spl[row], val[row], right_bits)
                 zf.writestr(f"trees/t{k:02d}_{t:03d}.bin", tree)
                 zf.writestr(f"trees/t{k:02d}_{t:03d}_aux.bin", aux)
     return path

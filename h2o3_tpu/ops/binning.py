@@ -38,9 +38,17 @@ class PackedCodes(NamedTuple):
     rm: jax.Array
     t: Optional[jax.Array]
     W: int
+    # a frame with enum features (:func:`lane_widths`): each feature's
+    # lane count, its NA code its own last lane; ``rm`` keeps the local
+    # codes, ``t`` holds GLOBAL lanes (code + the feature's lane offset),
+    # ``W`` is the widest feature's count
+    widths: tuple = ()
 
     @property
-    def na_bin(self) -> int:
+    def na_bin(self):
+        """The NA code: one number, or one a feature under ``widths``."""
+        if self.widths:
+            return tuple(w - 1 for w in self.widths)
         return self.W - 1
 
     @property
@@ -195,7 +203,9 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
             sp.attrs.update(
                 edges=("uniform" if histogram_type in (
                     "uniform_adaptive", "uniform") else "quantile"),
-                n_edges=max((len(e) for e in edges), default=0))
+                n_edges=max((len(e) for e in edges), default=0),
+                enum_features=int(sum(bool(c) for c in is_cat)),
+                numeric_features=int(sum(not c for c in is_cat)))
     with phase("bin.digitize"):
         codes = make_codes_view(digitize_with_edges(X, edges, n_bins_eff),
                                 with_t=with_t)
@@ -375,7 +385,8 @@ def digitize_codes_host(X_host, edges: List[np.ndarray], n_bins_eff: int):
 
 def packed_codes_record(enabled: bool, dtype=None, W: int = None,
                         bytes_per_value: int = None,
-                        n_bins: int = None, plan: dict = None) -> dict:
+                        n_bins: int = None, plan: dict = None,
+                        set_features: int = 0) -> dict:
     """The ONE spelling of ``model.output['packed_codes']`` — GBM dense,
     GBM streamed and DRF all emit it through here so bench.py /
     profile_train.py key parsing can never meet a drifted copy. ``plan``
@@ -385,7 +396,8 @@ def packed_codes_record(enabled: bool, dtype=None, W: int = None,
         return {"enabled": False}
     return {"enabled": True, "dtype": str(np.dtype(dtype)), "W": int(W),
             "bytes_per_value": int(bytes_per_value), "n_bins": int(n_bins),
-            "kernel": "binned_level", **(plan or {})}
+            "kernel": "binned_level", "set_features": int(set_features),
+            **(plan or {})}
 
 
 def make_codes_view(codes_rm, tile: int = 2048, mesh=None,
@@ -439,6 +451,49 @@ def _repack_codes(c, *, na: int, W: int, dt):
     return jnp.where(ci == na, W - 1, ci).astype(dt)
 
 
+def lane_widths(bin_counts: Sequence[int]) -> tuple:
+    """Lanes a feature on the level kernel's global lane axis, from its
+    real bin count: its bins, one NA lane (the last), rounded up to a
+    whole sublane group of 8."""
+    return tuple(-(-(int(n) + 1) // 8) * 8 for n in bin_counts)
+
+
+def lanes_code_dtype(widths: tuple):
+    """Smallest kernel-legal integer dtype for global lanes."""
+    return jnp.int8 if sum(widths) <= 128 else jnp.int16
+
+
+@partial(jax.jit, static_argnames=("na", "widths", "dt"))
+def _repack_codes_ragged(c, *, na: int, widths: tuple, dt):
+    """NA code n_bins -> the feature's own last lane, kernel dtype."""
+    ci = c.astype(jnp.int32)
+    last = jnp.asarray([w - 1 for w in widths], jnp.int32)
+    return jnp.where(ci == na, last[None, :], ci).astype(dt)
+
+
+@lru_cache(maxsize=32)
+def _pack_t_ragged(mesh, widths: tuple, tile: int):
+    """Cached builder of the [F, rows_p@data] operand on GLOBAL lanes,
+    padded per shard like :func:`_pack_t_sharded`."""
+    from jax.sharding import PartitionSpec as P
+    return jax.jit(jax.shard_map(
+        partial(_global_lanes_t, widths=widths, tile=tile), mesh=mesh,
+        in_specs=P("data"), out_specs=P(None, "data")))
+
+
+def _global_lanes_t(rm, *, widths: tuple, tile: int):
+    """[F, rows_p] GLOBAL lanes: local code + lane offset; pad rows NA."""
+    from h2o3_tpu.ops.hist_adaptive import lane_offsets
+    off = jnp.asarray(lane_offsets(widths), rm.dtype)
+    t = rm.T + off[:, None]
+    pad_r = (-t.shape[1]) % tile
+    if pad_r:
+        na = off + jnp.asarray([w - 1 for w in widths], rm.dtype)
+        t = jnp.concatenate(
+            [t, jnp.broadcast_to(na[:, None], (t.shape[0], pad_r))], axis=1)
+    return t
+
+
 @partial(jax.jit, static_argnames=("W", "tile"))
 def _pack_t_single(rm, *, W: int, tile: int):
     rows_l = rm.shape[0]
@@ -462,20 +517,31 @@ def _pack_t_sharded(mesh, W: int, tile: int):
                                  out_specs=P(None, "data")))
 
 
-def pack_codes(bm: "BinnedMatrix", mesh=None) -> PackedCodes:
+def pack_codes(bm: "BinnedMatrix", mesh=None,
+               widths: tuple = ()) -> PackedCodes:
     """Pack a BinnedMatrix's codes for the binned pallas level kernel:
     remap NA (code == n_bins) to the reserved lane W-1, narrow to the
     smallest kernel dtype (int8 for W <= 128, else int16), and build
     the transposed tile-padded hot-loop operand on TPU (or under the
     interpret escape). Sharding mirrors make_codes_view: rm stays
     [rows@data, F]; t is [F, rows_p@data] padded PER SHARD so shard
-    i's t columns are shard i's rm rows."""
+    i's t columns are shard i's rm rows. ``widths``: the per-feature
+    lane layout of a frame with enum features (:func:`lane_widths`);
+    ``t`` then holds global lanes."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from h2o3_tpu.ops.hist_adaptive import (TILE, code_dtype,
                                             pallas_interpret, pick_W)
     from h2o3_tpu.parallel.mesh import current_mesh, n_data_shards
 
+    if widths:
+        dt = lanes_code_dtype(widths)
+        rm = _repack_codes_ragged(bm.codes.rm, na=bm.n_bins, widths=widths,
+                                  dt=dt)
+        t = None
+        if jax.default_backend() == "tpu" or pallas_interpret():
+            t = _pack_t_ragged(mesh or current_mesh(), widths, TILE)(rm)
+        return PackedCodes(rm=rm, t=t, W=max(widths), widths=widths)
     W = pick_W(bm.n_bins)
     dt = code_dtype(W)
     rm = _repack_codes(bm.codes.rm, na=bm.n_bins, W=W, dt=dt)
@@ -508,14 +574,19 @@ def stripe_pair_codes(ct, W: int):
     return jnp.pad(ct, ((0, 1), (0, 0)), constant_values=W - 1)
 
 
-def pack_codes_for(X, bm: "BinnedMatrix", W: Optional[int] = None):
+def pack_codes_for(X, bm: "BinnedMatrix", W: Optional[int] = None,
+                   widths: tuple = ()):
     """Digitise a NEW matrix (validation / scoring frame) with the
     training sketch's edges and pack it to the kernel convention
-    (NA = reserved bin W-1, kernel dtype). Row-major only —
-    predict_binned walks it with na_bin = W-1."""
+    (NA = reserved bin W-1, or each feature's last lane under
+    ``widths``; kernel dtype). Row-major only — predict_binned walks it
+    with the packed codes' ``na_bin``."""
     from h2o3_tpu.ops.hist_adaptive import code_dtype, pick_W
-    W = W or pick_W(bm.n_bins)
     c = digitize_with_edges(X, bm.edges, bm.n_bins)
+    if widths:
+        return _repack_codes_ragged(c, na=bm.n_bins, widths=widths,
+                                    dt=lanes_code_dtype(widths))
+    W = W or pick_W(bm.n_bins)
     return _repack_codes(c, na=bm.n_bins, W=W, dt=code_dtype(W))
 
 

@@ -630,10 +630,14 @@ def code_dtype(W: int):
     return jnp.int8 if W <= 128 else jnp.int16
 
 
-def _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W):
+def _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W,
+              sets_ref=None):
     """Transposed binned routing: cf [F, tile] f32-valued CODES (NA =
     W-1). The split-bin compare ``code >= bin`` happens on exact
-    integer-valued floats — no lo/inv rebinning anywhere."""
+    integer-valued floats — no lo/inv rebinning anywhere. With
+    ``sets_ref`` ([W, n_prev] bf16 0/1, the previous level's left sets
+    over the local codes) a row goes left iff its code is in its node's
+    set."""
     prev_base = level_base - n_prev
     lid_p = nid - prev_base
     onp = (jax.lax.broadcasted_iota(jnp.int32, (n_prev, tile), 0)
@@ -646,16 +650,60 @@ def _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W):
     fi = jax.lax.broadcasted_iota(jnp.int32, (F, tile), 0)
     csel = jnp.sum(jnp.where(fi == f_r.astype(jnp.int32)[None, :], cf, 0.0),
                    axis=0)
-    gr_f = jnp.where(csel == float(W - 1), 1.0 - nl_r,
-                     (csel >= b_r).astype(jnp.float32))
+    if sets_ref is None:
+        gr_f = jnp.where(csel == float(W - 1), 1.0 - nl_r,
+                         (csel >= b_r).astype(jnp.float32))
+    else:
+        # ROUTING BY SET (a frame with enum features; every feature of it,
+        # a threshold being the set "bins below t"): the node's left set
+        # as a column over the W local codes, by the node one-hot the
+        # table lookup already built, then the row's own entry selected
+        # on its code. ``cf`` holds GLOBAL lanes here, ``b_r`` the split
+        # feature's lane offset; the NA code's entry is ``na_left``.
+        col = jax.lax.dot_general(sets_ref[:, :n_prev], onp,
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        ci = (csel - b_r).astype(jnp.int32)                  # local code
+        wi = jax.lax.broadcasted_iota(jnp.int32, col.shape, 0)
+        gr_f = 1.0 - jnp.sum(jnp.where(wi == ci[None, :], col, 0.0), axis=0)
     in_prev = (lid_p >= 0) & (lid_p < n_prev)
     child = 2 * nid + 1 + gr_f.astype(jnp.int32)
     return jnp.where(in_prev & (cn_r > 0.5), child, nid)
 
 
-def _kernel_bt(c_ref, nid_ref, ghw_ref, tabs_ref, nid_out, hist_out,
-               acc_ref, *, n_prev: int, n_nodes: int, F: int, W: int,
-               tile: int, n_row_tiles: int, level_base: int, mxu_dtype):
+def lane_offsets(widths) -> tuple:
+    """Where each feature's lanes start on the global lane axis."""
+    off, out = 0, []
+    for w in widths:
+        out.append(off)
+        off += int(w)
+    return tuple(out)
+
+
+def _lane_onehot(cf, widths, W: int, tile: int, dtype):
+    """[lanes, tile] one-hot of every feature's bin. A feature's lanes lie
+    at its offset on one global lane axis. With equal widths (``widths``
+    empty: no enum feature, every feature ``W`` lanes) the codes are local
+    and a sublane repeat against ``lane % W`` builds it; else the codes
+    are global lanes and each feature's row is broadcast over its own
+    lanes."""
+    F = cf.shape[0]
+    if not widths:
+        b_all = jnp.repeat(cf, W, axis=0)                    # [F*W, tile]
+        brow = jax.lax.broadcasted_iota(jnp.int32, (F * W, tile), 0)
+        return ((brow % W).astype(jnp.float32) == b_all).astype(dtype)
+    b_all = jnp.concatenate(
+        [jnp.broadcast_to(cf[f:f + 1, :], (w, tile))
+         for f, w in enumerate(widths)], axis=0)             # [L, tile]
+    brow = jax.lax.broadcasted_iota(jnp.int32, b_all.shape, 0)
+    return (brow.astype(jnp.float32) == b_all).astype(dtype)
+
+
+def _kernel_bt(c_ref, nid_ref, ghw_ref, tabs_ref, *rest, n_prev: int,
+               n_nodes: int, F: int, W: int, tile: int, n_row_tiles: int,
+               level_base: int, mxu_dtype, widths: tuple = ()):
+    sets_ref = rest[0] if widths else None
+    nid_out, hist_out, acc_ref = rest[-3:]
     r = pl.program_id(0)
 
     @pl.when(r == 0)
@@ -667,7 +715,8 @@ def _kernel_bt(c_ref, nid_ref, ghw_ref, tabs_ref, nid_out, hist_out,
     cf = c_ref[...].astype(jnp.int32).astype(jnp.float32)    # [F, tile]
     nid = nid_ref[0, :]
     if n_prev > 0:
-        nid = _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W)
+        nid = _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W,
+                        sets_ref)
     nid_out[0, :] = nid
 
     lid = nid - level_base
@@ -675,11 +724,9 @@ def _kernel_bt(c_ref, nid_ref, ghw_ref, tabs_ref, nid_out, hist_out,
     lidm = jnp.where(in_lvl, lid, -1)
     onh_m = (jax.lax.broadcasted_iota(jnp.int32, (n_nodes, tile), 0)
              == lidm[None, :]).astype(mxu_dtype)
-    # the code IS the bin: the one-hot builds straight off the sublane
-    # repeat — no range lookup, no floor/clip stage
-    b_all = jnp.repeat(cf, W, axis=0)                        # [F*W, tile]
-    brow = jax.lax.broadcasted_iota(jnp.int32, (F * W, tile), 0)
-    oh_t = ((brow % W).astype(jnp.float32) == b_all).astype(mxu_dtype)
+    # the code IS the bin: the one-hot builds straight off the codes —
+    # no range lookup, no floor/clip stage
+    oh_t = _lane_onehot(cf, widths, W, tile, mxu_dtype)
     ghw_m = ghw_ref[...].astype(mxu_dtype)
     left = jnp.concatenate(
         [onh_m * ghw_m[k, :][None, :] for k in range(3)], axis=0)  # [3N, tile]
@@ -696,45 +743,61 @@ def _kernel_bt(c_ref, nid_ref, ghw_ref, tabs_ref, nid_out, hist_out,
 
 def binned_level_tpu_t(ct, nid, ghw, tables, n_prev: int, n_nodes: int,
                        level_base: int, W: int, tile: int = TILE,
-                       interpret: bool = False, mxu_dtype=jnp.bfloat16):
+                       interpret: bool = False, mxu_dtype=jnp.bfloat16,
+                       widths: tuple = ()):
     """Packed binned level: ct is [F, rows] int8/int16 codes (rows %
     tile == 0; NA/pad = W-1). Returns (nid' [rows] i32, hist
-    [3, n_nodes, F, W] f32 — caller psums across shards)."""
+    [3, n_nodes, F, W] f32 — caller psums across shards).
+
+    ``widths`` (per-feature lane counts; a frame with enum features):
+    ct holds GLOBAL lanes (a feature's code plus its lane offset),
+    ``tables`` carries the lane offset of a node's feature where the
+    split bin rides and a fifth entry, the nodes' left sets [n, W] over
+    the local codes; the hist comes back flat, [3, n_nodes, lanes]."""
     F, rows = ct.shape
     assert rows % tile == 0, (rows, tile)
     n_row_tiles = rows // tile
-    tabs = _pack_tables(tables)
+    tabs = _pack_tables(tables[:4])
     np1 = tabs.shape[1]
+    lanes = sum(widths) if widths else F * W
     kern = functools.partial(_kernel_bt, n_prev=n_prev, n_nodes=n_nodes,
                              F=F, W=W, tile=tile, n_row_tiles=n_row_tiles,
-                             level_base=level_base, mxu_dtype=mxu_dtype)
+                             level_base=level_base, mxu_dtype=mxu_dtype,
+                             widths=widths)
     itemsize = jnp.dtype(ct.dtype).itemsize
+    operands = [ct, nid[None, :], ghw, tabs]
+    in_specs = [
+        pl.BlockSpec((F, tile), lambda r: (0, r)),
+        pl.BlockSpec((1, tile), lambda r: (0, r)),
+        pl.BlockSpec((3, tile), lambda r: (0, r)),
+        pl.BlockSpec((12, np1), lambda r: (0, 0)),
+    ]
+    if widths:
+        operands.append(tables[4].T.astype(jnp.bfloat16))     # [W, np1]
+        in_specs.append(pl.BlockSpec((W, np1), lambda r: (0, 0)))
     nid2, hist = pl.pallas_call(
         kern,
         grid=(n_row_tiles,),
-        in_specs=[
-            pl.BlockSpec((F, tile), lambda r: (0, r)),
-            pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3, tile), lambda r: (0, r)),
-            pl.BlockSpec((12, np1), lambda r: (0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3 * n_nodes, F * W), lambda r: (0, 0)),
+            pl.BlockSpec((3 * n_nodes, lanes), lambda r: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, rows), jnp.int32),
-            jax.ShapeDtypeStruct((3 * n_nodes, F * W), jnp.float32),
+            jax.ShapeDtypeStruct((3 * n_nodes, lanes), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((3 * n_nodes, F * W), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((3 * n_nodes, lanes), jnp.float32)],
         cost_estimate=pl.CostEstimate(
-            flops=2 * 3 * n_nodes * F * W * rows,
+            flops=2 * 3 * n_nodes * lanes * rows,
             bytes_accessed=rows * F * itemsize + rows * 16,
             transcendentals=0),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="binned_level_tpu_t",
-    )(ct, nid[None, :], ghw, tabs)
+    )(*operands)
+    if widths:
+        return nid2[0], hist.reshape(3, n_nodes, lanes)
     return nid2[0], hist.reshape(3, n_nodes, F, W)
 
 
@@ -886,85 +949,103 @@ def stripe_supported() -> bool:
     return _stripe_probe()
 
 
-def binned_level_xla(codes, nid, ghw, tables, n_prev: int, n_nodes: int,
-                     level_base: int, W: int):
-    """Pure-XLA reference/CPU path for the binned level (scatter-add
-    histogram, [rows, F] int codes, NA = W-1). Accumulation order
-    matches ops/histogram._hist_scatter3 row order, so the packed and
-    unpacked global-sketch paths are BIT-identical on CPU."""
-    rows, F = codes.shape
-    feat, sbin, nal, can = tables
-    ci = codes.astype(jnp.int32)
-    if n_prev > 0:
-        prev_base = level_base - n_prev
-        lid_p = jnp.clip(nid - prev_base, 0, n_prev - 1)
-        in_prev = (nid >= prev_base) & (nid < prev_base + n_prev)
-        f_r = feat[lid_p].astype(jnp.int32)
-        csel = jnp.take_along_axis(ci, f_r[:, None], axis=1)[:, 0]
-        is_na = csel == W - 1
-        go_right = jnp.where(is_na, nal[lid_p] < 0.5,
-                             csel.astype(jnp.float32) >= sbin[lid_p])
-        child = 2 * nid + 1 + go_right.astype(jnp.int32)
-        nid = jnp.where(in_prev & (can[lid_p] > 0.5), child, nid)
-    lid = nid - level_base
-    in_lvl = (lid >= 0) & (lid < n_nodes)
-    lidc = jnp.where(in_lvl, lid, 0)
-    flat = (lidc[:, None] * F + jnp.arange(F)[None, :]) * W + ci
-    vw = jnp.where(in_lvl, 1.0, 0.0)
-    out = jnp.zeros((n_nodes * F * W, 3), jnp.float32)
-    out = out.at[flat.reshape(-1), :].add(
-        (ghw.T * vw[:, None])[:, None, :].repeat(F, axis=1).reshape(-1, 3))
-    hist = out.reshape(n_nodes, F, W, 3)
-    return nid, jnp.moveaxis(hist, -1, 0)
-
-
-def _route_kernel_bt(c_ref, nid_ref, tabs_ref, nid_out, *, n_prev: int,
-                     level_base: int, F: int, W: int, tile: int):
-    cf = c_ref[...].astype(jnp.int32).astype(jnp.float32)
-    nid = nid_ref[0, :]
-    nid = _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W)
-    nid_out[0, :] = nid
-
-
-def binned_route_only_tpu_t(ct, nid, tables, n_prev: int, level_base: int,
-                            W: int, tile: int = TILE,
-                            interpret: bool = False):
-    F, rows = ct.shape
-    assert rows % tile == 0
-    tabs = _pack_tables(tables)
-    np1 = tabs.shape[1]
-    kern = functools.partial(_route_kernel_bt, n_prev=n_prev,
-                             level_base=level_base, F=F, W=W, tile=tile)
-    nid2 = pl.pallas_call(
-        kern,
-        grid=(rows // tile,),
-        in_specs=[
-            pl.BlockSpec((F, tile), lambda r: (0, r)),
-            pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((12, np1), lambda r: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda r: (0, r)),
-        out_shape=jax.ShapeDtypeStruct((1, rows), jnp.int32),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-        name="binned_route_only_tpu_t",
-    )(ct, nid[None, :], tabs)
-    return nid2[0]
-
-
-def binned_route_only_xla(codes, nid, tables, n_prev: int, level_base: int,
-                          W: int):
-    feat, sbin, nal, can = tables
-    ci = codes.astype(jnp.int32)
+def _go_right_xla(ci, nid, tables, n_prev: int, level_base: int, W: int):
+    """(rows that step, their child) through the previous level's tables,
+    by per-row lookups: the scatter references' routing. Five tables:
+    routing by set on the local codes (``tables[4]`` [n, W])."""
+    feat, sbin, nal, can = tables[:4]
     prev_base = level_base - n_prev
     lid_p = jnp.clip(nid - prev_base, 0, n_prev - 1)
     in_prev = (nid >= prev_base) & (nid < prev_base + n_prev)
     f_r = feat[lid_p].astype(jnp.int32)
     csel = jnp.take_along_axis(ci, f_r[:, None], axis=1)[:, 0]
-    go_right = jnp.where(csel == W - 1, nal[lid_p] < 0.5,
-                         csel.astype(jnp.float32) >= sbin[lid_p])
+    if len(tables) > 4:
+        go_right = tables[4][lid_p, csel] < 0.5
+    else:
+        go_right = jnp.where(csel == W - 1, nal[lid_p] < 0.5,
+                             csel.astype(jnp.float32) >= sbin[lid_p])
     child = 2 * nid + 1 + go_right.astype(jnp.int32)
     return jnp.where(in_prev & (can[lid_p] > 0.5), child, nid)
+
+
+def binned_level_xla(codes, nid, ghw, tables, n_prev: int, n_nodes: int,
+                     level_base: int, W: int, widths: tuple = ()):
+    """Pure-XLA reference/CPU path for the binned level (scatter-add
+    histogram, [rows, F] int codes, NA = W-1). Accumulation order
+    matches ops/histogram._hist_scatter3 row order, so the packed and
+    unpacked global-sketch paths are BIT-identical on CPU. With
+    ``widths`` (see :func:`binned_level_tpu_t`; the codes stay LOCAL
+    here) the hist is flat, [3, n_nodes, lanes]."""
+    rows, F = codes.shape
+    ci = codes.astype(jnp.int32)
+    if n_prev > 0:
+        nid = _go_right_xla(ci, nid, tables, n_prev, level_base, W)
+    lid = nid - level_base
+    in_lvl = (lid >= 0) & (lid < n_nodes)
+    lidc = jnp.where(in_lvl, lid, 0)
+    if widths:
+        lanes = sum(widths)
+        flat = lidc[:, None] * lanes + jnp.asarray(lane_offsets(widths)
+                                                   )[None, :] + ci
+    else:
+        lanes = F * W
+        flat = (lidc[:, None] * F + jnp.arange(F)[None, :]) * W + ci
+    vw = jnp.where(in_lvl, 1.0, 0.0)
+    out = jnp.zeros((n_nodes * lanes, 3), jnp.float32)
+    out = out.at[flat.reshape(-1), :].add(
+        (ghw.T * vw[:, None])[:, None, :].repeat(F, axis=1).reshape(-1, 3))
+    hist = out.reshape((n_nodes, lanes, 3) if widths
+                       else (n_nodes, F, W, 3))
+    return nid, jnp.moveaxis(hist, -1, 0)
+
+
+def _route_kernel_bt(c_ref, nid_ref, tabs_ref, *rest, n_prev: int,
+                     level_base: int, F: int, W: int, tile: int):
+    sets_ref = rest[0] if len(rest) > 1 else None
+    cf = c_ref[...].astype(jnp.int32).astype(jnp.float32)
+    nid = nid_ref[0, :]
+    nid = _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W,
+                    sets_ref)
+    rest[-1][0, :] = nid
+
+
+def binned_route_only_tpu_t(ct, nid, tables, n_prev: int, level_base: int,
+                            W: int, tile: int = TILE,
+                            interpret: bool = False):
+    """The leaves' routing. A fifth table (the left sets, see
+    :func:`binned_level_tpu_t`) selects routing by set on global lanes."""
+    F, rows = ct.shape
+    assert rows % tile == 0
+    tabs = _pack_tables(tables[:4])
+    np1 = tabs.shape[1]
+    kern = functools.partial(_route_kernel_bt, n_prev=n_prev,
+                             level_base=level_base, F=F, W=W, tile=tile)
+    operands = [ct, nid[None, :], tabs]
+    in_specs = [
+        pl.BlockSpec((F, tile), lambda r: (0, r)),
+        pl.BlockSpec((1, tile), lambda r: (0, r)),
+        pl.BlockSpec((12, np1), lambda r: (0, 0)),
+    ]
+    if len(tables) > 4:
+        operands.append(tables[4].T.astype(jnp.bfloat16))     # [W, np1]
+        in_specs.append(pl.BlockSpec((W, np1), lambda r: (0, 0)))
+    nid2 = pl.pallas_call(
+        kern,
+        grid=(rows // tile,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, tile), lambda r: (0, r)),
+        out_shape=jax.ShapeDtypeStruct((1, rows), jnp.int32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="binned_route_only_tpu_t",
+    )(*operands)
+    return nid2[0]
+
+
+def binned_route_only_xla(codes, nid, tables, n_prev: int, level_base: int,
+                          W: int):
+    return _go_right_xla(codes.astype(jnp.int32), nid, tables, n_prev,
+                         level_base, W)
 
 
 def _binned_pad(ct, nid, ghw, W):
@@ -983,41 +1064,51 @@ def _binned_pad(ct, nid, ghw, W):
     return ct, nid, ghw
 
 
-def binned_level_kernel(W: int, F: int, method: str = "auto") -> str:
+def binned_level_kernel(W: int, F: int, method: str = "auto",
+                        widths: tuple = ()) -> str:
     """Name of the body :func:`binned_level` dispatches a bf16/f32 level
     to — the one rule both the dispatch and its reporters
     (chip_smoke.py) read, so what is printed is what ran."""
     if _resolve_method(method) != "pallas":
         return "binned_level_xla"
-    if W == 16 and F >= 2 and stripe_supported():
+    if W == 16 and F >= 2 and not widths and stripe_supported():
         return "binned_level_tpu_stripe"
     return "binned_level_tpu_t"
 
 
-def binned_level_plan(W: int, F: int, method: str = "auto") -> dict:
+def binned_level_plan(W: int, F: int, method: str = "auto",
+                      widths: tuple = ()) -> dict:
     """What the levels of a dense packed train run, for its records
     (``model.output["packed_codes"]``, the ``train.loop`` span): the
-    kernel as the device trace names it, and its blocking: every body
-    takes all F features in one block, ``TILE`` rows a grid step."""
-    body = binned_level_kernel(W, F, method)
+    kernel as the device trace names it, its blocking (every body
+    takes all F features in one block, ``TILE`` rows a grid step) and
+    its lanes: ``lanes`` histogram lanes a level, laid out
+    ``lane_layout`` "uniform" (F x W) or "ragged" (a feature's own
+    width at its offset)."""
+    body = binned_level_kernel(W, F, method, widths)
     return {"kernel": body, "feature_block": F,
-            "row_tile": 0 if body == "binned_level_xla" else TILE}
+            "row_tile": 0 if body == "binned_level_xla" else TILE,
+            "lanes": sum(widths) if widths else F * W,
+            "lane_layout": "ragged" if widths else "uniform"}
 
 
 def binned_level(codes_rm, nid, ghw, tables, n_prev: int, n_nodes: int,
                  level_base: int, W: int, method: str = "auto",
-                 mxu_dtype=jnp.bfloat16, ct=None):
+                 mxu_dtype=jnp.bfloat16, ct=None, widths: tuple = ()):
     """Dispatch the packed binned level: the scatter reference off the
     TPU (or where ``method`` says so), the stripe kernel at W == 16 where
     its probe passed, else ``binned_level_tpu_t``. ``ct`` is the
     pre-transposed [F, rows_p] code matrix (built once per train by
     ops/binning.pack_codes); without it the pallas path transposes on
-    the fly (streamed chunks)."""
+    the fly (streamed chunks). ``widths``: the lane layout of a frame
+    with enum features (:func:`binned_level_tpu_t`); ``ct`` is then
+    given, on global lanes."""
     body = binned_level_kernel(
-        W, codes_rm.shape[1] if ct is None else ct.shape[0], method)
+        W, codes_rm.shape[1] if ct is None else ct.shape[0], method,
+        widths)
     if body == "binned_level_xla":
         return binned_level_xla(codes_rm, nid, ghw, tables, n_prev, n_nodes,
-                                level_base, W)
+                                level_base, W, widths)
     if ct is None:
         ct = codes_rm.T
     rows = nid.shape[0]
@@ -1031,7 +1122,8 @@ def binned_level(codes_rm, nid, ghw, tables, n_prev: int, n_nodes: int,
     else:
         nid2, hist = binned_level_tpu_t(
             ct, nid, ghw, tables, n_prev, n_nodes, level_base, W,
-            mxu_dtype=mxu_dtype, interpret=pallas_interpret())
+            mxu_dtype=mxu_dtype, interpret=pallas_interpret(),
+            widths=widths)
     return nid2[:rows], hist
 
 

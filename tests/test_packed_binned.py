@@ -188,7 +188,14 @@ def test_level_plan_names_what_the_dispatch_runs(monkeypatch, W, F, method,
     from h2o3_tpu.ops import hist_adaptive as ha
     monkeypatch.setattr(ha, "TILE", 8192)        # the chip's, whatever the env
     assert binned_level_plan(W, F, method) == {
-        "kernel": kernel, "feature_block": F, "row_tile": tile}
+        "kernel": kernel, "feature_block": F, "row_tile": tile,
+        "lanes": F * W, "lane_layout": "uniform"}
+    # per-feature lane widths (a frame with set features) run the one
+    # transposed body, whatever W is, on the lanes the widths sum to
+    ragged = binned_level_plan(W, 2, method, widths=(W, 8))
+    assert (ragged["lanes"], ragged["lane_layout"]) == (W + 8, "ragged")
+    assert ragged["kernel"] == ("binned_level_xla" if method == "scatter"
+                                else "binned_level_tpu_t")
     assert kernel == ha.binned_level_kernel(W, F, method)
 
 

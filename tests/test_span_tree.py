@@ -92,14 +92,19 @@ def test_a_train_leaves_the_span_tree_with_the_right_parents(warm_train):
     # the packed path says what its levels ran, as the model's record does
     pc = est.model.output["packed_codes"]
     for key in ("W", "kernel", "feature_block", "row_tile", "leaf_lookup",
-                "n_nodes"):
+                "n_nodes", "lanes", "lane_layout", "set_features"):
         assert loop.attrs[key] == pc[key], key
+    # a numeric frame: every feature W lanes, no set feature
+    assert (pc["lanes"], pc["lane_layout"], pc["set_features"]) == (
+        32 * FEATURES, "uniform", 0)
     # depth 3: 15 nodes, whose values the margin update selects
     assert (pc["leaf_lookup"], pc["n_nodes"]) == ("select", 15)
     assert loop.attrs["code_bytes"] == pc["bytes_per_value"] == 1
     assert (pc["W"], pc["feature_block"]) == (32, FEATURES)
     sketch = named["train.bin.sketch"][0]
     assert (sketch.attrs["edges"], sketch.attrs["n_edges"]) == ("uniform", 19)
+    assert (sketch.attrs["enum_features"],
+            sketch.attrs["numeric_features"]) == (0, FEATURES)
     # the stages follow one another inside train.train
     order = [named[n][0] for n in ("train.bin.sketch", "train.bin.digitize",
                                    "train.bin.pack", "train.loop",
@@ -213,6 +218,69 @@ def test_the_dispatch_span_says_which_form_the_scorer_ran(
     _, named = _tree(telemetry.finished_spans())
     dispatch, = named["score.dispatch"]
     assert dispatch.attrs == {"node_form": form, "n_nodes": 15}
+
+
+def _counter(name, algo="gbm"):
+    return sum(s["value"] for s in telemetry.registry().samples()
+               if s["name"] == name and s.get("labels", {}).get("algo") == algo)
+
+
+def test_a_set_split_train_says_so_in_spans_counters_and_routes():
+    """An enum column on the packed path (ISSUE 33): the sketch span
+    counts the enum features, the loop span and the model's record carry
+    the lane layout and the set features, a predict's dispatch span the
+    set nodes, and the two split counters move by the model's own
+    arrays; /3/Timeline and /metrics show them."""
+    from h2o3_tpu.api import server
+    from h2o3_tpu.frame.vec import T_ENUM, Vec
+    rng = np.random.default_rng(33)
+    n = 4096
+    c = rng.integers(0, 40, n)
+    effect = rng.normal(size=40)
+    x = rng.normal(size=n).astype(np.float32)
+    y = (effect[c] + 0.5 * x + 0.3 * rng.normal(size=n) > 0).astype(np.int32)
+    frame = h2o.Frame(["c", "x", "y"], [
+        Vec.from_numpy(c, T_ENUM, [f"k{i}" for i in range(40)]),
+        Vec.from_numpy(x), Vec.from_numpy(y, T_ENUM, ["n", "y"])])
+    before = {k: _counter(k) for k in ("h2o3_tree_splits_total",
+                                       "h2o3_tree_set_splits_total")}
+    est = H2OGradientBoostingEstimator(ntrees=3, max_depth=3, seed=1,
+                                       packed_codes=True)
+    telemetry.clear_spans()
+    est.train(y="y", training_frame=frame)
+    _, named = _tree(telemetry.finished_spans())
+    sketch, loop = named["train.bin.sketch"][0], named["train.loop"][0]
+    assert (sketch.attrs["enum_features"],
+            sketch.attrs["numeric_features"]) == (1, 1)
+    pc = est.model.output["packed_codes"]
+    # 40 levels + NA -> 48 lanes, 20 bins + NA -> 24
+    assert (pc["lane_layout"], pc["lanes"], pc["set_features"], pc["W"]) == (
+        "ragged", 72, 1, 48)
+    for key in ("lanes", "lane_layout", "set_features"):
+        assert loop.attrs[key] == pc[key], key
+    m = est.model
+    splits = int(np.asarray(m._is_split).sum())
+    sets = int(np.asarray(m._is_set).sum())
+    assert 0 < sets <= splits
+    assert _counter("h2o3_tree_splits_total") - before[
+        "h2o3_tree_splits_total"] == splits
+    assert _counter("h2o3_tree_set_splits_total") - before[
+        "h2o3_tree_set_splits_total"] == sets
+    telemetry.clear_spans()
+    m.predict(frame)
+    _, named = _tree(telemetry.finished_spans())
+    dispatch, = named["score.dispatch"]
+    assert dispatch.attrs["set_nodes"] == sets
+    assert dispatch.attrs["n_nodes"] == 15
+    # the routes: the trace of the span ring, and the exposition
+    trace = server._timeline({"format": "trace"}, None)["__raw"]
+    text = trace.decode() if isinstance(trace, bytes) else str(trace)
+    assert "set_nodes" in text
+    exposition = server._metrics({}, None)["__raw"]
+    exposition = (exposition.decode() if isinstance(exposition, bytes)
+                  else str(exposition))
+    assert 'h2o3_tree_set_splits_total{algo="gbm"}' in exposition
+    assert 'h2o3_tree_splits_total{algo="gbm"}' in exposition
 
 
 def _jit_spans(stage, parent=None):

@@ -17,7 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from h2o3_tpu.models.tree import node_lookup, predict_raw_stacked
 from h2o3_tpu.ops import hist_adaptive as ha
 from h2o3_tpu.ops import hist_pallas
-from h2o3_tpu.ops.binning import stripe_pair_codes
+from h2o3_tpu.ops.binning import lane_widths, stripe_pair_codes
 
 F = 28                    # HIGGS width, the bench and chip_smoke shape
 BENCH_ROWS = 10_002_432   # the benchmark's 10M rows, padded to the tile
@@ -95,6 +95,43 @@ def _binned_route(width=W, code_dtype=jnp.int8):
     return fn, [((F, ROWS), code_dtype), ((ROWS,), jnp.int32), *_tables(32)]
 
 
+# the airline table's lane layout (GBM-perf, benchmark cell
+# airline_gbm.train): 8 features of 12, 31, 7, 100, 22, 300, 300, 100 bins,
+# each at its own width on one global lane axis, int16 global-lane codes,
+# routing by set (a fifth table [n_prev, 304])
+AIRLINE_BINS = (12, 31, 7, 100, 22, 300, 300, 100)
+LEVEL9 = (256, 512, 511)      # the last split level of depth 10
+
+
+def _airline_operands(n_prev, ghw=True):
+    n = max(n_prev, 1)
+    widths = lane_widths(AIRLINE_BINS)
+    ops = [((len(widths), ROWS), jnp.int16), ((ROWS,), jnp.int32)]
+    if ghw:
+        ops.append(((3, ROWS), jnp.float32))
+    return widths, ops + [*_tables(n_prev), ((n, max(widths)), jnp.float32)]
+
+
+def _binned_t_ragged(level):
+    n_prev, n_nodes, base = level
+    widths, operands = _airline_operands(n_prev)
+
+    def fn(ct, nid, ghw, *tables):
+        return ha.binned_level_tpu_t(ct, nid, ghw, tables, n_prev, n_nodes,
+                                     base, max(widths), tile=ha.TILE,
+                                     widths=widths)
+    return fn, operands
+
+
+def _binned_route_sets():
+    widths, operands = _airline_operands(512, ghw=False)
+
+    def fn(ct, nid, *tables):
+        return ha.binned_route_only_tpu_t(ct, nid, tables, 512, 1023,
+                                          max(widths), tile=ha.TILE)
+    return fn, operands
+
+
 def _adaptive_t(level):
     n_prev, n_nodes, base = level
 
@@ -143,6 +180,9 @@ CASES = {
     "binned_route_only_tpu_t": _binned_route,
     "binned_level_tpu_t-w256-level5": lambda: _binned_t(LEVEL5, 256, jnp.int16),
     "binned_route_only_tpu_t-w256": lambda: _binned_route(256, jnp.int16),
+    "binned_level_tpu_t-ragged896-root": lambda: _binned_t_ragged(ROOT),
+    "binned_level_tpu_t-ragged896-level9": lambda: _binned_t_ragged(LEVEL9),
+    "binned_route_only_tpu_t-sets": _binned_route_sets,
     "adaptive_level_tpu_t-level5": lambda: _adaptive_t(LEVEL5),
     "hist_pallas3": _hist_pallas3,
     "predict_raw_stacked-bucket64": _serve_scorer,

@@ -118,6 +118,19 @@ def test_tree_path_by_hand(hist, requested, n_bins, fits, ria, want):
                      random_is_adaptive=ria) == want
 
 
+@pytest.mark.parametrize("depth,lanes,want", [
+    # per-feature lane widths (a frame with set features): 300 bins pack,
+    # the 254-bin cap is the uniform layout's
+    (10, 896, "packed"),
+    # 2 x [3 * 2^9, 4096] f32 = 50 MB: a uniform W=512 would fit too
+    (10, 4096, "packed"),
+    # 2 x [3 * 2^12, 896] f32 = 88 MB fits, depth 14 does not
+    (13, 896, "packed"), (14, 896, "adaptive")])
+def test_tree_path_with_lane_widths(depth, lanes, want):
+    assert tree_path("uniform_adaptive", True, 300, 8, depth,
+                     adaptive_fits=True, lanes=lanes) == want
+
+
 def test_tree_config_reads_every_objective_field_once():
     """The one builder: XGBoost's objective fields reach DRF's and the
     streamed driver's configs as they reach GBM's."""
@@ -153,7 +166,25 @@ _TINY = dict(ntrees=2, max_depth=2, seed=3, min_rows=1.0,
              score_tree_interval=0, stopping_rounds=0)
 _PACKED_KEYS = {"enabled", "dtype", "W", "bytes_per_value", "n_bins",
                 "kernel", "feature_block", "row_tile", "leaf_lookup",
-                "n_nodes"}
+                "n_nodes", "lanes", "lane_layout", "set_features"}
+# the trees of this numeric frame as the commit before category-set splits
+# (fe4f801) grew them: feat, na_left, is_split, and thr and value to four
+# decimals
+_BEFORE_SETS = {"gbm-packed": "36eefd2adffb9421",
+                "drf-packed": "27ea00fd5716a892",
+                "gbm-auto": "36eefd2adffb9421", "drf-auto": "970f9e7430b7c41e",
+                "gbm-random": "ea8e6ebe3a4dde6a",
+                "drf-random": "6e33ada796d665a4"}
+
+
+def _digest(m):
+    import hashlib
+    h = hashlib.sha256()
+    for a in (m._feat, m._na_left, m._is_split):
+        h.update(np.ascontiguousarray(np.asarray(a)).tobytes())
+    for a in (m._thr, m._value):
+        h.update(np.round(np.asarray(a, np.float64), 4).tobytes())
+    return h.hexdigest()[:16]
 
 
 def _mode(model):
@@ -175,7 +206,8 @@ def _mode(model):
      {"packed_codes": True, "histogram_type": "random"}, "sketch"),
 ], ids=["gbm-packed", "drf-packed", "gbm-auto", "drf-auto", "gbm-random",
         "drf-random"])
-def test_dense_trainers_take_the_tables_path(monkeypatch, Est, params, want):
+def test_dense_trainers_take_the_tables_path(monkeypatch, request, Est,
+                                            params, want):
     monkeypatch.delenv("H2O3_PALLAS_INTERPRET", raising=False)
     est = Est(**{**_TINY, **params})
     est.train(y="resp", training_frame=h2o.Frame.from_numpy(_frame()))
@@ -186,6 +218,14 @@ def test_dense_trainers_take_the_tables_path(monkeypatch, Est, params, want):
     if want == "packed":
         assert (pc["kernel"], pc["feature_block"], pc["n_nodes"]) == (
             "binned_level_xla", 4, 7)
+        # a numeric frame keeps the uniform layout and thresholds
+        assert (pc["lanes"], pc["lane_layout"], pc["set_features"]) == (
+            4 * pc["W"], "uniform", 0)
+    # ... and grows the trees it grew before set splits came in
+    assert est.model._cat_set is None if Est is H2OGradientBoostingEstimator \
+        else not hasattr(est.model, "_cat_set")
+    assert _digest(est.model) == _BEFORE_SETS[
+        request.node.callspec.id], "a numeric frame's trees changed"
 
 
 @pytest.mark.parametrize("params,want", [
