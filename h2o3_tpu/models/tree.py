@@ -35,7 +35,10 @@ import numpy as np
 
 from h2o3_tpu.ops.binning import (CodesView, bin_matrix_device, pack_codes,
                                   packed_codes_record)
-from h2o3_tpu.ops.hist_adaptive import binned_level_plan
+from h2o3_tpu.ops.hist_adaptive import (binned_level, binned_level_plan,
+                                        can_builds_right, can_entry,
+                                        can_other_child, can_splits,
+                                        level_acc_rows)
 
 NEG_INF = -1e30
 
@@ -637,8 +640,11 @@ def packed_bins_upper_bound(spec, params) -> int:
 def binned_feasible(n_bins: int, n_features: int, max_depth: int,
                     lanes: Optional[int] = None) -> bool:
     """Whether the packed binned kernel's deepest level fits VMEM —
-    the adaptive_feasible bound applied to W = pick_W(n_bins) (scratch
-    + output block both hold [3·2^(D-1), F·W] f32). Past the 254-bin
+    the adaptive_feasible bound applied to W = pick_W(n_bins): scratch
+    + output block counted at [3·2^(D-1), F·W] f32 each, TWICE what the
+    packed level holds since it accumulates one child a parent
+    ([3·2^(D-2), F·W]); the bound is kept as it was (which depths pack
+    is its own change). Past the 254-bin
     lane cap or the VMEM bound, the matmul/scatter global-sketch path
     takes over. ``lanes``: the level's lane count under per-feature lane
     widths (a frame with set features), which has no 254-bin cap: a
@@ -666,9 +672,11 @@ def _adaptive_n_bins_eff(spec, params) -> int:
 
 def adaptive_feasible(spec, params, max_depth: int) -> bool:
     """Whether the fused adaptive kernel's deepest level fits VMEM
-    (scratch + output block both hold [3·2^(D-1), F·W] f32; ~128MB/core
-    on v5e, gated conservatively at 96MB). Beyond this the global-sketch
-    path takes over (it tiles features and uses sibling subtraction)."""
+    (scratch + output block both hold [3·2^(D-1), F·W] f32: the f32
+    adaptive level builds every node of a level, unlike the packed one;
+    ~128MB/core on v5e, gated conservatively at 96MB). Beyond this the
+    global-sketch path takes over (it tiles features and uses sibling
+    subtraction)."""
     from h2o3_tpu.ops.hist_adaptive import pick_W
     if int(params["nbins"]) > 254:
         return False
@@ -931,7 +939,16 @@ def prepare_tree_inputs(spec, params, max_depth: int, *, prof,
                 **binned_level_plan(pc.W, cfg.n_features, binned_method(cfg),
                                     widths),
                 "leaf_lookup": node_lookup_form(cfg.n_nodes),
-                "n_nodes": cfg.n_nodes}
+                "n_nodes": cfg.n_nodes,
+                # what a level accumulates (level_child_sums, by the
+                # precision a shard's rows choose in grow_tree_binned) and
+                # the deepest level's accumulator rows
+                "level_hist": (
+                    "smaller_child" if level_derives(_hist_mxu_dtype(
+                        cfg, pc.rm.sharding.shard_shape(pc.rm.shape)[0]))
+                    else "both_children"),
+                "acc_rows": level_acc_rows(
+                    2 ** (max_depth - 2) if max_depth > 1 else 0)}
         else:
             # the work above is dispatched, not done: wait for it here so
             # bin_s carries it. The loop-entry fence absorbed it otherwise,
@@ -1163,8 +1180,9 @@ def levels_per_pass(max_depth: int, n_features: int, W: int) -> int:
     - integer: clamped to [1, max_depth]; 1 is the exact old per-level
       path (one dispatch + one host sync per level);
     - unset / 'auto': VMEM-budgeted — the largest L <= 4 whose DEEPEST
-      possible window keeps the sum of its live level histograms
-      (3 · 2^d · F · W · 4 bytes over the window) inside half the
+      possible window keeps the sum of its levels' histograms in node
+      order (3 · 2^d · F · W · 4 bytes over the window; the kernels'
+      accumulators hold half of each) inside half the
       kernel VMEM limit, the same ceiling the per-level accumulator
       scratch is provisioned against. L=4 everywhere practical; the
       bound only bites at extreme depth × features × W products where
@@ -1202,10 +1220,12 @@ def _binned_split_level(trip, find_cfg: TreeConfig, level_mask,
     can = (bg > jnp.maximum(cfg.min_split_improvement, 0.0)) & (wt_ > 0)
     # next level's routing tables: the split BIN rides where the
     # adaptive path carries a raw threshold — an exact integer-valued
-    # float through the kernel's bf16-split LUT
+    # float; ``can`` also says which child the next level BUILDS: the one
+    # with the smaller w (ops/hist_adaptive.py's conventions say why),
+    # chosen after the data psum so every shard chooses alike
     tables = (jnp.maximum(bf, 0).astype(jnp.float32),
-              bb.astype(jnp.float32),
-              bnl.astype(jnp.float32), can.astype(jnp.float32))
+              bb.astype(jnp.float32), bnl.astype(jnp.float32),
+              can_entry(can, sel[10] < sel[9]))
     if cfg.set_feats:
         # routing by set: the chosen feature's lane offset rides where the
         # bin did, and a fifth table holds the left set over the feature's
@@ -1221,6 +1241,57 @@ def _binned_split_level(trip, find_cfg: TreeConfig, level_mask,
                               jnp.float32)[f],
                   tables[2], tables[3], left.astype(jnp.float32))
     return sel, can, tables
+
+
+def level_derives(mxu_dtype) -> bool:
+    """Whether a packed level takes each sibling as parent - built (bf16
+    sums) or builds it too (float32 histograms, which promise a node's sums
+    to the rounding of its own rows: ops/hist_adaptive.py, PRECISION)."""
+    return mxu_dtype != jnp.float32
+
+
+def level_child_sums(codes_rm, nid, ghw, tables, n_prev: int, level_base: int,
+                     W: int, method: str = "auto", mxu_dtype=jnp.bfloat16,
+                     ct=None, widths: tuple = ()):
+    """What a packed level accumulates over one holder of rows (a shard, a
+    chunk): (nid', sums [k, 3, max(n_prev, 1), ...]), LINEAR in the rows, so
+    shards psum it and chunks add it before :func:`sibling_level_hist`.
+    k = 1: ``binned_level``'s built child of every previous-level node (at
+    the root, the root). k = 2 below the root where the level does not
+    derive (:func:`level_derives`): the sibling as well, by the same kernel
+    called again on the same ``nid`` with the other child chosen."""
+    def level(tabs):
+        return binned_level(codes_rm, nid, ghw, tabs, n_prev, level_base, W,
+                            method, mxu_dtype=mxu_dtype, ct=ct, widths=widths)
+    nid2, built = level(tables)
+    if n_prev == 0 or level_derives(mxu_dtype):
+        return nid2, built[None]
+    other = level(tables[:3] + (can_other_child(tables[3]),) + tables[4:])[1]
+    return nid2, jnp.stack([built, other])
+
+
+def sibling_level_hist(sums, parent, tables):
+    """A level's histogram [3, N, ...] in node order from what its kernels
+    accumulated: ``sums`` [k, 3, N/2, ...] (:func:`level_child_sums`,
+    already summed over shards and chunks: the subtraction is linear),
+    ``parent`` the previous level's own [3, N/2, ...] (None at the root,
+    whose histogram ``sums[0]`` is) and ``tables``, the routing tables that
+    level handed down, whose ``can`` entry says which child was built
+    first. With k = 1 the sibling is parent - built. A node that did not
+    split gives BOTH children an empty histogram: routing reaches neither,
+    and the parent's mass left on one of them would be searched, and
+    recorded, as a split. Every packed driver reassembles its levels
+    here."""
+    built = sums[0]
+    if parent is None:
+        return built
+    can = tables[3].reshape((1, -1) + (1,) * (built.ndim - 2))
+    other = (sums[1] if sums.shape[0] == 2
+             else jnp.where(can_splits(can), parent - built, 0.0))
+    right_built = can_builds_right(can)
+    pair = jnp.stack([jnp.where(right_built, other, built),
+                      jnp.where(right_built, built, other)], axis=2)
+    return pair.reshape(3, 2 * built.shape[1], *built.shape[2:])
 
 
 def padded_level_hist(hist, cfg: TreeConfig):
@@ -1280,23 +1351,23 @@ def _fused_binned_window(cfg: TreeConfig, d0: int, Lw: int, W: int,
     guard)."""
     from dataclasses import replace as dc_replace
 
-    from h2o3_tpu.ops.hist_adaptive import binned_level
     find_cfg = dc_replace(cfg, n_bins=W - 1)
     mxu_dtype = jnp.float32 if mxu_name == "float32" else jnp.bfloat16
 
-    def window(x, nid, ghw, tables, col_mask):
+    def window(x, nid, ghw, tables, col_mask, hist):
         recs = []
         for j in range(Lw):
             d = d0 + j
             N = 1 << d
-            nid, hist = binned_level(
+            nid, sums = level_child_sums(
                 None if trans else x, nid, ghw, tables,
-                N // 2 if d else 0, N, N - 1, W,
+                N // 2 if d else 0, N - 1, W,
                 mxu_dtype=mxu_dtype, ct=x if trans else None)
+            hist = sibling_level_hist(sums, hist if d else None, tables)
             sel, can, tables = _binned_split_level(
                 (hist[0], hist[1], hist[2]), find_cfg, col_mask, cfg)
             recs.append(_level_record(sel, can, cfg))
-        return nid, recs, tables
+        return nid, recs, tables, hist
 
     return jax.jit(window)
 
@@ -1329,9 +1400,14 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
     Per level the fused binned kernel routes rows by integer
     code-vs-bin compare and builds the histogram one-hot straight off
     the codes — no lo/inv rebinning anywhere, so the hot loop moves
-    1-2 bytes/value instead of 4."""
-    from h2o3_tpu.ops.hist_adaptive import (binned_level,
-                                            binned_route_only, pick_W)
+    1-2 bytes/value instead of 4. Below the root a call accumulates ONE
+    child of every previous-level node, the one with the smaller w
+    ([3, N/2, ...] at a level of N nodes). With bf16 sums the previous
+    level's histogram is kept and the sibling is parent - built, under
+    ``axis_name`` after a psum of the built half alone; float32
+    histograms build the sibling by a second call
+    (:func:`level_child_sums`, :func:`sibling_level_hist`)."""
+    from h2o3_tpu.ops.hist_adaptive import binned_route_only, pick_W
     from dataclasses import replace as dc_replace
 
     D = cfg.max_depth
@@ -1381,14 +1457,17 @@ def grow_tree_binned(codes_rm, g, h, w, cfg: TreeConfig, col_mask,
         return tree, nid
 
     vl_s = vr_s = wl_s = wr_s = None
+    level_hist = None
     for d in range(D):
         N = 2 ** d
         base = N - 1
-        nid, hist = binned_level(codes_rm, nid, ghw, tables,
-                                 N // 2 if d else 0, N, base, W, method,
-                                 mxu_dtype=mxu_dtype, ct=ct, widths=widths)
+        nid, sums = level_child_sums(codes_rm, nid, ghw, tables,
+                                     N // 2 if d else 0, base, W, method,
+                                     mxu_dtype=mxu_dtype, ct=ct,
+                                     widths=widths)
         if axis_name is not None:
-            hist = jax.lax.psum(hist, axis_name)
+            sums = jax.lax.psum(sums, axis_name)
+        hist = level_hist = sibling_level_hist(sums, level_hist, tables)
         if widths:
             hist = padded_level_hist(hist, cfg)
         trip = (hist[0], hist[1], hist[2])
@@ -2139,8 +2218,7 @@ def grow_tree_binned_streamed(chunks, dist, lr, cfg: TreeConfig, edges,
     (unbinned from ``edges`` here, once, at tree end) so the streamed
     caller's finalize shape matches the adaptive streamed grower's."""
     from h2o3_tpu import telemetry
-    from h2o3_tpu.ops.hist_adaptive import (binned_level,
-                                            binned_route_only, pick_W)
+    from h2o3_tpu.ops.hist_adaptive import binned_route_only, pick_W
     from dataclasses import replace as dc_replace
 
     rows, F = chunks.rows, chunks.F
@@ -2180,6 +2258,7 @@ def grow_tree_binned_streamed(chunks, dist, lr, cfg: TreeConfig, edges,
     # still batches every level's split-record fetch into one sync at
     # the window boundary. L=1 is the exact old path.
     L = levels_per_pass(D, F, W)
+    level_hist = None          # the previous level's, for its children's
     d = 0
     while d < D:
         Lw = min(L, D - d)
@@ -2206,11 +2285,12 @@ def grow_tree_binned_streamed(chunks, dist, lr, cfg: TreeConfig, edges,
                         ("gbm.stream_window_binned", ch.X.shape,
                          int(d), int(Lw), int(W),
                          str(mxu_dtype.__name__)),
-                        win, ch.X, ch.nid, ghw, tables, col_mask))
+                        win, ch.X, ch.nid, ghw, tables, col_mask,
+                        level_hist))
                     perf_acc.note_capture_seconds(
                         _time.perf_counter() - t_cap0)
-                nid2, recs, tables = win(ch.X, ch.nid, ghw, tables,
-                                         col_mask)
+                nid2, recs, tables, level_hist = win(
+                    ch.X, ch.nid, ghw, tables, col_mask, level_hist)
                 ch.put_nid(nid2)
         else:
             recs = []
@@ -2218,15 +2298,14 @@ def grow_tree_binned_streamed(chunks, dist, lr, cfg: TreeConfig, edges,
                 dd = d + j
                 N = 2 ** dd
                 base = N - 1
-                hist = None
+                sums = None
                 for ch in chunks.level_pass():
                     ghw = ch.ghw(dist)
                     rm_arg = None if trans else ch.X
                     ct_arg = ch.X if trans else None
-                    nid2, h_c = binned_level(rm_arg, ch.nid, ghw, tables,
-                                             N // 2 if dd else 0, N, base,
-                                             W, mxu_dtype=mxu_dtype,
-                                             ct=ct_arg)
+                    nid2, h_c = level_child_sums(
+                        rm_arg, ch.nid, ghw, tables, N // 2 if dd else 0,
+                        base, W, mxu_dtype=mxu_dtype, ct=ct_arg)
                     if perf_acc is not None:
                         # streamed-level jit seam, binned flavour: one
                         # trace+lower per (chunk shape, level) key — the
@@ -2240,15 +2319,17 @@ def grow_tree_binned_streamed(chunks, dist, lr, cfg: TreeConfig, edges,
                         perf_acc.add(costmodel.traced_cost(
                             ("gbm.stream_level_binned", ch.X.shape,
                              int(N), int(W), str(mxu_dtype.__name__)),
-                            _partial(binned_level,
+                            _partial(level_child_sums,
                                      n_prev=N // 2 if dd else 0,
-                                     n_nodes=N, level_base=base, W=W,
+                                     level_base=base, W=W,
                                      mxu_dtype=mxu_dtype),
                             rm_arg, ch.nid, ghw, tables, ct=ct_arg))
                         perf_acc.note_capture_seconds(
                             _time.perf_counter() - t_cap0)
                     ch.put_nid(nid2)
-                    hist = h_c if hist is None else hist + h_c
+                    sums = h_c if sums is None else sums + h_c
+                hist = level_hist = sibling_level_hist(sums, level_hist,
+                                                       tables)
                 sel, can, tables = _binned_split_level(
                     (hist[0], hist[1], hist[2]), find_cfg, col_mask, cfg)
                 recs.append(_level_record(sel, can, cfg))

@@ -9,10 +9,13 @@ picks the grower; the dispatchers here pick the body):
   The benchmark's cells run exactly two bodies, named as the device
   trace prints them: ``binned_level_tpu_t`` (body ``_kernel_bt``) for
   levels 0..D-1 and ``binned_route_only_tpu_t`` (``_route_kernel_bt``)
-  for the leaves' routing. Off their path: ``binned_level_tpu_stripe``
-  (``_kernel_bt_stripe``) at W == 16 (nbins <= 14) where its probe
-  passes or ``H2O3_STRIPE`` says so, and the scatter references
-  ``binned_level_xla`` / ``binned_route_only_xla`` off the TPU.
+  for the leaves' routing. Below the root a packed level accumulates ONE
+  child of every previous-level node and the grower takes the sibling
+  by subtraction (the conventions above ``code_dtype``). Off their path:
+  ``binned_level_tpu_stripe`` (``_kernel_bt_stripe``) at W == 16
+  (nbins <= 14) where its probe passes or ``H2O3_STRIPE`` says so, and
+  the scatter references ``binned_level_xla`` /
+  ``binned_route_only_xla`` off the TPU.
 - f32 ADAPTIVE levels, ``adaptive_level`` / ``route_only``
   (grow_tree_adaptive on raw features): ``packed_codes=False``,
   ``histogram_type="random"``, categorical domains too wide to pack.
@@ -618,10 +621,79 @@ def route_only(x, nid, tables, n_prev: int, level_base: int,
 #     split as the raw-threshold tables (_pack_tables): integers
 #     reconstruct exactly, so in-kernel routing is bit-identical to
 #     the scatter reference and to predict_binned's host walk;
-#   - the histogram contraction is byte-for-byte the f32 kernel's
-#     (same [3N, tile] x [FW, tile]^T lane contraction), so the
-#     bf16 / f32-HIGHEST choice (histogram_precision) composes
-#     unchanged.
+#   - the histogram contraction is the f32 kernel's lane contraction
+#     ([rows, tile] x [lanes, tile]^T), so the bf16 / f32-HIGHEST choice
+#     (histogram_precision) composes unchanged; its ROWS are not the
+#     level's nodes, see below.
+#
+# A level below the root accumulates ONE child of each previous-level
+# node, the BUILT child: 3*n_prev accumulator rows where the level has
+# 2*n_prev nodes. The left operand is the routing's own parent one-hot
+# masked by "this row stepped into its parent's built child", times
+# (g, h, w). Which child is built rides in the ``can`` table entry
+# (NO_SPLIT, BUILD_LEFT, BUILD_RIGHT below; :func:`can_entry` writes it and
+# ``can_splits`` / ``can_builds_right`` / ``can_other_child`` read it).
+# The caller takes the sibling as parent - built where the histograms are
+# bf16 sums, and builds it by a second call where they are float32
+# (models/tree.py:level_child_sums, sibling_level_hist).
+#
+# PRECISION of a level's histogram. A cell is the f32 sum of its rows'
+# addends, each rounded to ``mxu_dtype`` first, so a built and a derived
+# cell are sums of the same rounded addends a direct build would add.
+# Summing n addends a_i in f32, in any order, errs by at most
+# n * 2^-24 * sum|a_i| and, its roundings being independent, by about
+# sqrt(n) * 2^-24 * sum|a_i|: E(cell), a BUILT cell's error over its own
+# rows. A DERIVED cell is its last built ancestor A less the built
+# siblings b_1..b_k on the way down (k derived levels running), so its
+# ABSOLUTE error is
+#     E(A) + E(b_1) + ... + E(b_k)  <=  3 * sqrt(n_A) * 2^-24 * sum_A|a|
+# whatever its own size (the grower builds the child with the smaller w,
+# so where mass follows w each b_i holds at most half its parent's and
+# the sum is a geometric series under 2 E(A); each subtraction adds half
+# an ulp of a result no larger than A's). Relative to its OWN sums that
+# is up to 2^k times a direct build's: every derived level keeps at least
+# half its parent's w, no more. The other choice has no bound at all: a
+# 10-row child derived from a 10M-row parent would be noise
+# (tests/test_packed_binned.py holds one and three derived levels to the
+# bound above, and shows a 10-row child DERIVED from a 200k-row parent
+# fail it).
+# - bf16 addends (``histogram_precision`` bfloat16, 'auto' from 2^18 rows)
+#   carry 2^-9 each, which is what the compared numbers of such a train
+#   read (PERF.md section 6, PR 34: equal to the last digit with and
+#   without derivation at 10M-40M rows): the sibling is DERIVED.
+# - float32 histograms (``histogram_precision`` float32, 'auto' under 2^18
+#   rows) promise a node's sums to the f32 rounding of its OWN rows, and a
+#   one-row cell exactly its row (gain ties then break as a float64 search
+#   breaks them). No difference from an ancestor's sums keeps that: a
+#   20-row node at depth 9 of a 4,096-row frame read 10-100x a direct
+#   build's error derived. There the sibling is BUILT, by the same kernel
+#   called again with ``can_other_child``: the trees are the direct
+#   formulation's bit for bit, at twice the calls of frames whose levels
+#   cost microseconds.
+
+NO_SPLIT, BUILD_LEFT, BUILD_RIGHT = 0.0, 1.0, 2.0
+
+
+def can_entry(can, build_right):
+    """The ``can`` table entry of a packed level: NO_SPLIT where ``can``
+    is false (the node's rows stay), else which child the next level's
+    kernel accumulates. Small integers, exact through the bf16 LUT."""
+    return jnp.where(can, jnp.where(build_right, BUILD_RIGHT, BUILD_LEFT),
+                     NO_SPLIT)
+
+
+def can_splits(entry):
+    return entry > NO_SPLIT + 0.5
+
+
+def can_builds_right(entry):
+    return entry > BUILD_LEFT + 0.5
+
+
+def can_other_child(entry):
+    """The entry that builds the sibling of the child ``entry`` builds."""
+    return jnp.where(can_splits(entry), BUILD_LEFT + BUILD_RIGHT - entry,
+                     NO_SPLIT)
 
 
 def code_dtype(W: int):
@@ -630,14 +702,16 @@ def code_dtype(W: int):
     return jnp.int8 if W <= 128 else jnp.int16
 
 
-def _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W,
-              sets_ref=None):
+def _step_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W,
+             sets_ref=None):
     """Transposed binned routing: cf [F, tile] f32-valued CODES (NA =
     W-1). The split-bin compare ``code >= bin`` happens on exact
     integer-valued floats — no lo/inv rebinning anywhere. With
     ``sets_ref`` ([W, n_prev] bf16 0/1, the previous level's left sets
     over the local codes) a row goes left iff its code is in its node's
-    set."""
+    set. Returns (the rows' node ids after the step, their parent one-hot
+    [n_prev, tile] bf16, 1. where a row stepped into its parent's BUILT
+    child else 0.)."""
     prev_base = level_base - n_prev
     lid_p = nid - prev_base
     onp = (jax.lax.broadcasted_iota(jnp.int32, (n_prev, tile), 0)
@@ -666,9 +740,45 @@ def _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W,
         ci = (csel - b_r).astype(jnp.int32)                  # local code
         wi = jax.lax.broadcasted_iota(jnp.int32, col.shape, 0)
         gr_f = 1.0 - jnp.sum(jnp.where(wi == ci[None, :], col, 0.0), axis=0)
-    in_prev = (lid_p >= 0) & (lid_p < n_prev)
+    step = (lid_p >= 0) & (lid_p < n_prev) & can_splits(cn_r)
     child = 2 * nid + 1 + gr_f.astype(jnp.int32)
-    return jnp.where(in_prev & (cn_r > 0.5), child, nid)
+    # the entry less BUILD_LEFT is the built child's direction (0 left,
+    # 1 right), and -1, which no direction equals, where no row steps
+    built = (gr_f == cn_r - BUILD_LEFT).astype(jnp.float32)
+    return jnp.where(step, child, nid), onp, built
+
+
+def _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W,
+              sets_ref=None):
+    """The rows' node ids after :func:`_step_bt`."""
+    return _step_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W,
+                    sets_ref)[0]
+
+
+def _built_rows(cf, nid, ghw, tabs_ref, n_prev, level_base, tile, F, W,
+                mxu_dtype, sets_ref=None):
+    """What a packed level accumulates, from the routing: (the rows' new
+    node ids, the histogram's left operand [3 * max(n_prev, 1), tile]).
+    Below the root that operand is the parent one-hot times (g, h, w)
+    masked to the rows that stepped into their parent's built child; at
+    the root, the rows of node 0."""
+    if n_prev == 0:
+        rows = (jax.lax.broadcasted_iota(jnp.int32, (1, tile), 0)
+                == (nid - level_base)[None, :]).astype(mxu_dtype)
+    else:
+        nid, onp, built = _step_bt(cf, nid, tabs_ref, n_prev, level_base,
+                                   tile, F, W, sets_ref)
+        ghw = ghw * built[None, :]
+        rows = onp.astype(mxu_dtype)
+    ghw_m = ghw.astype(mxu_dtype)
+    return nid, jnp.concatenate(
+        [rows * ghw_m[k, :][None, :] for k in range(3)], axis=0)
+
+
+def level_acc_rows(n_prev: int) -> int:
+    """Accumulator rows of a packed level whose previous level has
+    ``n_prev`` nodes (0: the root): (g, h, w) of one child a parent."""
+    return 3 * max(n_prev, 1)
 
 
 def lane_offsets(widths) -> tuple:
@@ -700,7 +810,7 @@ def _lane_onehot(cf, widths, W: int, tile: int, dtype):
 
 
 def _kernel_bt(c_ref, nid_ref, ghw_ref, tabs_ref, *rest, n_prev: int,
-               n_nodes: int, F: int, W: int, tile: int, n_row_tiles: int,
+               F: int, W: int, tile: int, n_row_tiles: int,
                level_base: int, mxu_dtype, widths: tuple = ()):
     sets_ref = rest[0] if widths else None
     nid_out, hist_out, acc_ref = rest[-3:]
@@ -713,57 +823,51 @@ def _kernel_bt(c_ref, nid_ref, ghw_ref, tabs_ref, *rest, n_prev: int,
     # int8/int16 -> f32 once per tile in VMEM (int->float is legal in
     # Mosaic through an i32 widening)
     cf = c_ref[...].astype(jnp.int32).astype(jnp.float32)    # [F, tile]
-    nid = nid_ref[0, :]
-    if n_prev > 0:
-        nid = _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile, F, W,
-                        sets_ref)
+    nid, left = _built_rows(cf, nid_ref[0, :], ghw_ref[...], tabs_ref,
+                            n_prev, level_base, tile, F, W, mxu_dtype,
+                            sets_ref)
     nid_out[0, :] = nid
-
-    lid = nid - level_base
-    in_lvl = (lid >= 0) & (lid < n_nodes)
-    lidm = jnp.where(in_lvl, lid, -1)
-    onh_m = (jax.lax.broadcasted_iota(jnp.int32, (n_nodes, tile), 0)
-             == lidm[None, :]).astype(mxu_dtype)
     # the code IS the bin: the one-hot builds straight off the codes —
     # no range lookup, no floor/clip stage
     oh_t = _lane_onehot(cf, widths, W, tile, mxu_dtype)
-    ghw_m = ghw_ref[...].astype(mxu_dtype)
-    left = jnp.concatenate(
-        [onh_m * ghw_m[k, :][None, :] for k in range(3)], axis=0)  # [3N, tile]
     acc_ref[...] += jax.lax.dot_general(
         left, oh_t, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=(jax.lax.Precision.HIGHEST if mxu_dtype == jnp.float32
-                   else jax.lax.Precision.DEFAULT))       # [3N, FW]
+                   else jax.lax.Precision.DEFAULT))       # [acc rows, lanes]
 
     @pl.when(r == n_row_tiles - 1)
     def _flush():
         hist_out[...] = acc_ref[...]
 
 
-def binned_level_tpu_t(ct, nid, ghw, tables, n_prev: int, n_nodes: int,
-                       level_base: int, W: int, tile: int = TILE,
-                       interpret: bool = False, mxu_dtype=jnp.bfloat16,
-                       widths: tuple = ()):
+def binned_level_tpu_t(ct, nid, ghw, tables, n_prev: int, level_base: int,
+                       W: int, tile: int = TILE, interpret: bool = False,
+                       mxu_dtype=jnp.bfloat16, widths: tuple = ()):
     """Packed binned level: ct is [F, rows] int8/int16 codes (rows %
-    tile == 0; NA/pad = W-1). Returns (nid' [rows] i32, hist
-    [3, n_nodes, F, W] f32 — caller psums across shards).
+    tile == 0; NA/pad = W-1), ``tables`` the previous level's (feat, bin,
+    na_left, can), ``can`` saying which child of a node is built (the
+    conventions above). Returns (nid' [rows] i32, hist [3, max(n_prev, 1),
+    F, W] f32: row p the BUILT child of previous-level node p, zeros where
+    p does not split; at the root the root's own. The caller psums it
+    across shards and takes the siblings by subtraction).
 
     ``widths`` (per-feature lane counts; a frame with enum features):
     ct holds GLOBAL lanes (a feature's code plus its lane offset),
     ``tables`` carries the lane offset of a node's feature where the
     split bin rides and a fifth entry, the nodes' left sets [n, W] over
-    the local codes; the hist comes back flat, [3, n_nodes, lanes]."""
+    the local codes; the hist comes back flat, [3, max(n_prev, 1),
+    lanes]."""
     F, rows = ct.shape
     assert rows % tile == 0, (rows, tile)
     n_row_tiles = rows // tile
     tabs = _pack_tables(tables[:4])
     np1 = tabs.shape[1]
     lanes = sum(widths) if widths else F * W
-    kern = functools.partial(_kernel_bt, n_prev=n_prev, n_nodes=n_nodes,
-                             F=F, W=W, tile=tile, n_row_tiles=n_row_tiles,
-                             level_base=level_base, mxu_dtype=mxu_dtype,
-                             widths=widths)
+    n_acc = level_acc_rows(n_prev)
+    kern = functools.partial(_kernel_bt, n_prev=n_prev, F=F, W=W, tile=tile,
+                             n_row_tiles=n_row_tiles, level_base=level_base,
+                             mxu_dtype=mxu_dtype, widths=widths)
     itemsize = jnp.dtype(ct.dtype).itemsize
     operands = [ct, nid[None, :], ghw, tabs]
     in_specs = [
@@ -781,15 +885,15 @@ def binned_level_tpu_t(ct, nid, ghw, tables, n_prev: int, n_nodes: int,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3 * n_nodes, lanes), lambda r: (0, 0)),
+            pl.BlockSpec((n_acc, lanes), lambda r: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, rows), jnp.int32),
-            jax.ShapeDtypeStruct((3 * n_nodes, lanes), jnp.float32),
+            jax.ShapeDtypeStruct((n_acc, lanes), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((3 * n_nodes, lanes), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n_acc, lanes), jnp.float32)],
         cost_estimate=pl.CostEstimate(
-            flops=2 * 3 * n_nodes * lanes * rows,
+            flops=2 * n_acc * lanes * rows,
             bytes_accessed=rows * F * itemsize + rows * 16,
             transcendentals=0),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
@@ -797,14 +901,13 @@ def binned_level_tpu_t(ct, nid, ghw, tables, n_prev: int, n_nodes: int,
         name="binned_level_tpu_t",
     )(*operands)
     if widths:
-        return nid2[0], hist.reshape(3, n_nodes, lanes)
-    return nid2[0], hist.reshape(3, n_nodes, F, W)
+        return nid2[0], hist.reshape(3, n_acc // 3, lanes)
+    return nid2[0], hist.reshape(3, n_acc // 3, F, W)
 
 
 def _kernel_bt_stripe(c_ref, nid_ref, ghw_ref, tabs_ref, nid_out, hist_out,
-                      acc_ref, *, n_prev: int, n_nodes: int, F2: int,
-                      W: int, tile: int, n_row_tiles: int, level_base: int,
-                      mxu_dtype):
+                      acc_ref, *, n_prev: int, F2: int, W: int, tile: int,
+                      n_row_tiles: int, level_base: int, mxu_dtype):
     """STRIPE-PACKED binned level (W=16): two features share one 32-lane
     stripe of the one-hot — feature 2p's bins in sub-lanes 0..W-1,
     feature 2p+1's in W..2W-1 (codes offset by +W in-register). The
@@ -823,17 +926,9 @@ def _kernel_bt_stripe(c_ref, nid_ref, ghw_ref, tabs_ref, nid_out, hist_out,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     cf = c_ref[...].astype(jnp.int32).astype(jnp.float32)    # [2*F2, tile]
-    nid = nid_ref[0, :]
-    if n_prev > 0:
-        nid = _route_bt(cf, nid, tabs_ref, n_prev, level_base, tile,
-                        2 * F2, W)
+    nid, left = _built_rows(cf, nid_ref[0, :], ghw_ref[...], tabs_ref,
+                            n_prev, level_base, tile, 2 * F2, W, mxu_dtype)
     nid_out[0, :] = nid
-
-    lid = nid - level_base
-    in_lvl = (lid >= 0) & (lid < n_nodes)
-    lidm = jnp.where(in_lvl, lid, -1)
-    onh_m = (jax.lax.broadcasted_iota(jnp.int32, (n_nodes, tile), 0)
-             == lidm[None, :]).astype(mxu_dtype)
     # stripe offset: the pair's odd feature lives in the upper W lanes —
     # one add on the [2*F2, tile] codes, then a single repeat builds
     # both features' lanes of every stripe at once
@@ -842,14 +937,11 @@ def _kernel_bt_stripe(c_ref, nid_ref, ghw_ref, tabs_ref, nid_out, hist_out,
     b_all = jnp.repeat(cs, W, axis=0)                        # [F2*2W, tile]
     brow = jax.lax.broadcasted_iota(jnp.int32, (2 * F2 * W, tile), 0)
     oh_t = ((brow % (2 * W)).astype(jnp.float32) == b_all).astype(mxu_dtype)
-    ghw_m = ghw_ref[...].astype(mxu_dtype)
-    left = jnp.concatenate(
-        [onh_m * ghw_m[k, :][None, :] for k in range(3)], axis=0)
     acc_ref[...] += jax.lax.dot_general(
         left, oh_t, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=(jax.lax.Precision.HIGHEST if mxu_dtype == jnp.float32
-                   else jax.lax.Precision.DEFAULT))        # [3N, F2*2W]
+                   else jax.lax.Precision.DEFAULT))    # [acc rows, F2*2W]
 
     @pl.when(r == n_row_tiles - 1)
     def _flush():
@@ -857,13 +949,14 @@ def _kernel_bt_stripe(c_ref, nid_ref, ghw_ref, tabs_ref, nid_out, hist_out,
 
 
 def binned_level_tpu_stripe(ct, nid, ghw, tables, n_prev: int,
-                            n_nodes: int, level_base: int, W: int,
-                            tile: int = TILE, interpret: bool = False,
+                            level_base: int, W: int, tile: int = TILE,
+                            interpret: bool = False,
                             mxu_dtype=jnp.bfloat16, F: int = None):
-    """Stripe-packed binned level: ct is the stripe operand [2*F2, rows]
-    (ops/binning.stripe_pair_codes — an odd F pads one all-NA feature
-    row). ``F`` is the REAL feature count; the returned hist is sliced
-    back to [3, n_nodes, F, W]."""
+    """Stripe-packed binned level, :func:`binned_level_tpu_t`'s contract:
+    ct is the stripe operand [2*F2, rows] (ops/binning.stripe_pair_codes —
+    an odd F pads one all-NA feature row). ``F`` is the REAL feature
+    count; the returned hist is sliced back to [3, max(n_prev, 1), F,
+    W]."""
     F_op, rows = ct.shape
     assert F_op % 2 == 0, F_op
     F2 = F_op // 2
@@ -872,9 +965,9 @@ def binned_level_tpu_stripe(ct, nid, ghw, tables, n_prev: int,
     n_row_tiles = rows // tile
     tabs = _pack_tables(tables)
     np1 = tabs.shape[1]
-    kern = functools.partial(_kernel_bt_stripe, n_prev=n_prev,
-                             n_nodes=n_nodes, F2=F2, W=W, tile=tile,
-                             n_row_tiles=n_row_tiles,
+    n_acc = level_acc_rows(n_prev)
+    kern = functools.partial(_kernel_bt_stripe, n_prev=n_prev, F2=F2, W=W,
+                             tile=tile, n_row_tiles=n_row_tiles,
                              level_base=level_base, mxu_dtype=mxu_dtype)
     itemsize = jnp.dtype(ct.dtype).itemsize
     nid2, hist = pl.pallas_call(
@@ -888,23 +981,22 @@ def binned_level_tpu_stripe(ct, nid, ghw, tables, n_prev: int,
         ],
         out_specs=[
             pl.BlockSpec((1, tile), lambda r: (0, r)),
-            pl.BlockSpec((3 * n_nodes, 2 * F2 * W), lambda r: (0, 0)),
+            pl.BlockSpec((n_acc, 2 * F2 * W), lambda r: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, rows), jnp.int32),
-            jax.ShapeDtypeStruct((3 * n_nodes, 2 * F2 * W), jnp.float32),
+            jax.ShapeDtypeStruct((n_acc, 2 * F2 * W), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((3 * n_nodes, 2 * F2 * W),
-                                   jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n_acc, 2 * F2 * W), jnp.float32)],
         cost_estimate=pl.CostEstimate(
-            flops=2 * 3 * n_nodes * 2 * F2 * W * rows,
+            flops=2 * n_acc * 2 * F2 * W * rows,
             bytes_accessed=rows * 2 * F2 * itemsize + rows * 16,
             transcendentals=0),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="binned_level_tpu_stripe",
     )(ct, nid[None, :], ghw, tabs)
-    return nid2[0], hist.reshape(3, n_nodes, 2 * F2, W)[:, :, :F, :]
+    return nid2[0], hist.reshape(3, n_acc // 3, 2 * F2, W)[:, :, :F, :]
 
 
 @functools.lru_cache(maxsize=1)
@@ -927,7 +1019,7 @@ def _stripe_probe() -> bool:
             ghw = jnp.zeros((3, TILE), jnp.float32)
             z1 = jnp.zeros(1, jnp.float32)
             nid2, hist = binned_level_tpu_stripe(
-                ct, nid, ghw, (z1, z1, z1, z1), 0, 1, 0, 16)
+                ct, nid, ghw, (z1, z1, z1, z1), 0, 0, 16)
             jax.block_until_ready((nid2, hist))  # h2o3-lint: allow[transfer-seam] once-per-process capability probe: the block IS the probe (Mosaic lowering failures surface at execute)
         return True
     except Exception as e:  # noqa: BLE001 — any Mosaic refusal demotes
@@ -949,10 +1041,12 @@ def stripe_supported() -> bool:
     return _stripe_probe()
 
 
-def _go_right_xla(ci, nid, tables, n_prev: int, level_base: int, W: int):
-    """(rows that step, their child) through the previous level's tables,
-    by per-row lookups: the scatter references' routing. Five tables:
-    routing by set on the local codes (``tables[4]`` [n, W])."""
+def _step_xla(ci, nid, tables, n_prev: int, level_base: int, W: int):
+    """(the rows' node ids after the step, their previous-level local ids,
+    which rows stepped into their parent's BUILT child) through the
+    previous level's tables, by per-row lookups: the scatter references'
+    routing. Five tables: routing by set on the local codes (``tables[4]``
+    [n, W])."""
     feat, sbin, nal, can = tables[:4]
     prev_base = level_base - n_prev
     lid_p = jnp.clip(nid - prev_base, 0, n_prev - 1)
@@ -964,38 +1058,40 @@ def _go_right_xla(ci, nid, tables, n_prev: int, level_base: int, W: int):
     else:
         go_right = jnp.where(csel == W - 1, nal[lid_p] < 0.5,
                              csel.astype(jnp.float32) >= sbin[lid_p])
+    step = in_prev & can_splits(can[lid_p])
     child = 2 * nid + 1 + go_right.astype(jnp.int32)
-    return jnp.where(in_prev & (can[lid_p] > 0.5), child, nid)
+    return (jnp.where(step, child, nid), lid_p,
+            step & (go_right == can_builds_right(can[lid_p])))
 
 
-def binned_level_xla(codes, nid, ghw, tables, n_prev: int, n_nodes: int,
-                     level_base: int, W: int, widths: tuple = ()):
-    """Pure-XLA reference/CPU path for the binned level (scatter-add
-    histogram, [rows, F] int codes, NA = W-1). Accumulation order
-    matches ops/histogram._hist_scatter3 row order, so the packed and
-    unpacked global-sketch paths are BIT-identical on CPU. With
-    ``widths`` (see :func:`binned_level_tpu_t`; the codes stay LOCAL
-    here) the hist is flat, [3, n_nodes, lanes]."""
+def binned_level_xla(codes, nid, ghw, tables, n_prev: int, level_base: int,
+                     W: int, widths: tuple = ()):
+    """Pure-XLA reference/CPU path for the binned level
+    (:func:`binned_level_tpu_t`'s contract: the BUILT child of every
+    previous-level node; scatter-add histogram, [rows, F] int codes, NA =
+    W-1). Rows add in row order, like ops/histogram._hist_scatter3. With
+    ``widths`` (the codes stay LOCAL here) the hist is flat,
+    [3, max(n_prev, 1), lanes]."""
     rows, F = codes.shape
     ci = codes.astype(jnp.int32)
+    n_acc = max(n_prev, 1)
     if n_prev > 0:
-        nid = _go_right_xla(ci, nid, tables, n_prev, level_base, W)
-    lid = nid - level_base
-    in_lvl = (lid >= 0) & (lid < n_nodes)
-    lidc = jnp.where(in_lvl, lid, 0)
+        nid, seg, built = _step_xla(ci, nid, tables, n_prev, level_base, W)
+    else:
+        seg = jnp.zeros_like(nid)
+        built = nid == level_base
     if widths:
         lanes = sum(widths)
-        flat = lidc[:, None] * lanes + jnp.asarray(lane_offsets(widths)
-                                                   )[None, :] + ci
+        flat = seg[:, None] * lanes + jnp.asarray(lane_offsets(widths)
+                                                  )[None, :] + ci
     else:
         lanes = F * W
-        flat = (lidc[:, None] * F + jnp.arange(F)[None, :]) * W + ci
-    vw = jnp.where(in_lvl, 1.0, 0.0)
-    out = jnp.zeros((n_nodes * lanes, 3), jnp.float32)
+        flat = (seg[:, None] * F + jnp.arange(F)[None, :]) * W + ci
+    vw = jnp.where(built, 1.0, 0.0)
+    out = jnp.zeros((n_acc * lanes, 3), jnp.float32)
     out = out.at[flat.reshape(-1), :].add(
         (ghw.T * vw[:, None])[:, None, :].repeat(F, axis=1).reshape(-1, 3))
-    hist = out.reshape((n_nodes, lanes, 3) if widths
-                       else (n_nodes, F, W, 3))
+    hist = out.reshape((n_acc, lanes, 3) if widths else (n_acc, F, W, 3))
     return nid, jnp.moveaxis(hist, -1, 0)
 
 
@@ -1044,8 +1140,8 @@ def binned_route_only_tpu_t(ct, nid, tables, n_prev: int, level_base: int,
 
 def binned_route_only_xla(codes, nid, tables, n_prev: int, level_base: int,
                           W: int):
-    return _go_right_xla(codes.astype(jnp.int32), nid, tables, n_prev,
-                         level_base, W)
+    return _step_xla(codes.astype(jnp.int32), nid, tables, n_prev,
+                     level_base, W)[0]
 
 
 def _binned_pad(ct, nid, ghw, W):
@@ -1092,22 +1188,22 @@ def binned_level_plan(W: int, F: int, method: str = "auto",
             "lane_layout": "ragged" if widths else "uniform"}
 
 
-def binned_level(codes_rm, nid, ghw, tables, n_prev: int, n_nodes: int,
-                 level_base: int, W: int, method: str = "auto",
-                 mxu_dtype=jnp.bfloat16, ct=None, widths: tuple = ()):
-    """Dispatch the packed binned level: the scatter reference off the
-    TPU (or where ``method`` says so), the stripe kernel at W == 16 where
-    its probe passed, else ``binned_level_tpu_t``. ``ct`` is the
+def binned_level(codes_rm, nid, ghw, tables, n_prev: int, level_base: int,
+                 W: int, method: str = "auto", mxu_dtype=jnp.bfloat16,
+                 ct=None, widths: tuple = ()):
+    """Dispatch the packed binned level (the contract:
+    :func:`binned_level_tpu_t`): the scatter reference off the TPU (or
+    where ``method`` says so), the stripe kernel at W == 16 where its
+    probe passed, else ``binned_level_tpu_t``. ``ct`` is the
     pre-transposed [F, rows_p] code matrix (built once per train by
     ops/binning.pack_codes); without it the pallas path transposes on
     the fly (streamed chunks). ``widths``: the lane layout of a frame
-    with enum features (:func:`binned_level_tpu_t`); ``ct`` is then
-    given, on global lanes."""
+    with enum features; ``ct`` is then given, on global lanes."""
     body = binned_level_kernel(
         W, codes_rm.shape[1] if ct is None else ct.shape[0], method,
         widths)
     if body == "binned_level_xla":
-        return binned_level_xla(codes_rm, nid, ghw, tables, n_prev, n_nodes,
+        return binned_level_xla(codes_rm, nid, ghw, tables, n_prev,
                                 level_base, W, widths)
     if ct is None:
         ct = codes_rm.T
@@ -1116,12 +1212,12 @@ def binned_level(codes_rm, nid, ghw, tables, n_prev: int, n_nodes: int,
     if body == "binned_level_tpu_stripe":
         from h2o3_tpu.ops.binning import stripe_pair_codes
         nid2, hist = binned_level_tpu_stripe(
-            stripe_pair_codes(ct, W), nid, ghw, tables, n_prev, n_nodes,
-            level_base, W, mxu_dtype=mxu_dtype,
-            interpret=pallas_interpret(), F=ct.shape[0])
+            stripe_pair_codes(ct, W), nid, ghw, tables, n_prev, level_base,
+            W, mxu_dtype=mxu_dtype, interpret=pallas_interpret(),
+            F=ct.shape[0])
     else:
         nid2, hist = binned_level_tpu_t(
-            ct, nid, ghw, tables, n_prev, n_nodes, level_base, W,
+            ct, nid, ghw, tables, n_prev, level_base, W,
             mxu_dtype=mxu_dtype, interpret=pallas_interpret(),
             widths=widths)
     return nid2[:rows], hist

@@ -243,15 +243,17 @@ def test_stripe_kernel_bit_parity_interpret():
                               .astype(np.float32)),
                   jnp.asarray((rng.random(n_prev) < 0.5)
                               .astype(np.float32)),
-                  jnp.ones(n_prev, jnp.float32))
+                  # one parent builds its left child, one its right
+                  jnp.asarray([1.0, 2.0]))
         ct = jnp.asarray(codes.T.astype(np.int8))
         nid_s, hist_s = binned_level_tpu_stripe(
             stripe_pair_codes(ct, W), jnp.asarray(nid), ghw, tables,
-            n_prev, N, base, W, tile=1024, interpret=True,
+            n_prev, base, W, tile=1024, interpret=True,
             mxu_dtype=jnp.float32, F=F)
         nid_x, hist_x = binned_level_xla(
             jnp.asarray(codes), jnp.asarray(nid), ghw, tables,
-            n_prev, N, base, W)
+            n_prev, base, W)
+        assert hist_x.shape == (3, n_prev, F, W)
         np.testing.assert_array_equal(np.asarray(nid_s),
                                       np.asarray(nid_x))
         np.testing.assert_array_equal(np.asarray(hist_s),

@@ -63,7 +63,8 @@ def _kernel_inputs(rows=4096, F=7, W=16, N=4, seed=0, int_ghw=True):
               jnp.asarray(rng.integers(1, W - 1, n_prev)
                           .astype(np.float32)),
               jnp.asarray((rng.random(n_prev) < 0.5).astype(np.float32)),
-              jnp.ones(n_prev, jnp.float32))
+              # ``can``: 0 no split, 1 / 2 the left / right child is built
+              jnp.asarray(rng.integers(0, 3, n_prev).astype(np.float32)))
     ct = jnp.asarray(codes.T.astype(np.int8 if W <= 128 else np.int16))
     return (codes, ct, jnp.asarray(nid), jnp.asarray(ghw), tables,
             n_prev, N, base)
@@ -73,10 +74,10 @@ def test_binned_level_bit_parity_interpret():
     codes, ct, nid, ghw, tables, n_prev, N, base = _kernel_inputs()
     W = 16
     nid_t, hist_t = binned_level_tpu_t(
-        ct, nid, ghw, tables, n_prev, N, base, W, tile=1024,
+        ct, nid, ghw, tables, n_prev, base, W, tile=1024,
         interpret=True, mxu_dtype=jnp.float32)
     nid_x, hist_x = binned_level_xla(
-        jnp.asarray(codes), nid, ghw, tables, n_prev, N, base, W)
+        jnp.asarray(codes), nid, ghw, tables, n_prev, base, W)
     np.testing.assert_array_equal(np.asarray(nid_t), np.asarray(nid_x))
     np.testing.assert_array_equal(np.asarray(hist_t), np.asarray(hist_x))
 
@@ -86,10 +87,10 @@ def test_binned_level_float_ghw_close_interpret():
         seed=3, int_ghw=False)
     W = 16
     nid_t, hist_t = binned_level_tpu_t(
-        ct, nid, ghw, tables, n_prev, N, base, W, tile=1024,
+        ct, nid, ghw, tables, n_prev, base, W, tile=1024,
         interpret=True, mxu_dtype=jnp.float32)
     nid_x, hist_x = binned_level_xla(
-        jnp.asarray(codes), nid, ghw, tables, n_prev, N, base, W)
+        jnp.asarray(codes), nid, ghw, tables, n_prev, base, W)
     np.testing.assert_array_equal(np.asarray(nid_t), np.asarray(nid_x))
     np.testing.assert_allclose(np.asarray(hist_t), np.asarray(hist_x),
                                rtol=1e-5, atol=1e-4)
@@ -135,7 +136,7 @@ def _wide_inputs(F, N, rows=1536, pad_rows=512, seed=0):
     tables = (jnp.asarray(rng.integers(0, F, n).astype(np.float32)),
               jnp.asarray(rng.integers(1, 254, n).astype(np.float32)),
               jnp.asarray((rng.random(n) < 0.5).astype(np.float32)),
-              jnp.ones(n, jnp.float32))
+              jnp.asarray(rng.integers(0, 3, n).astype(np.float32)))
     return (jnp.asarray(codes), jnp.asarray(codes.T), jnp.asarray(nid),
             jnp.asarray(ghw), tables, n_prev, base)
 
@@ -149,13 +150,20 @@ def test_level_equals_scatter_at_w256_int16(F, N):
     codes, ct, nid, ghw, tables, n_prev, base = _wide_inputs(F, N)
     assert ct.dtype == code_dtype(256) == jnp.int16
     nid_t, hist_t = binned_level_tpu_t(
-        ct, nid, ghw, tables, n_prev, N, base, 256, tile=512,
+        ct, nid, ghw, tables, n_prev, base, 256, tile=512,
         interpret=True, mxu_dtype=jnp.float32)
-    nid_x, hist_x = binned_level_xla(codes, nid, ghw, tables, n_prev, N,
+    nid_x, hist_x = binned_level_xla(codes, nid, ghw, tables, n_prev,
                                      base, 256)
     np.testing.assert_array_equal(np.asarray(nid_t), np.asarray(nid_x))
     np.testing.assert_array_equal(np.asarray(hist_t), np.asarray(hist_x))
-    assert float(hist_x[2].sum()) == 1536.0 * F
+    # one child a splitting parent (the root's own rows at the root), and
+    # nothing of the pad rows
+    assert hist_x.shape == (3, max(n_prev, 1), F, 256)
+    can = np.asarray(tables[3])
+    built = 2 * (base - n_prev + np.arange(max(n_prev, 1))) + 1 + (can > 1.5)
+    in_built = (np.isin(np.asarray(nid_x)[:1536], built[can > 0.5])
+                if n_prev else np.ones(1536, bool))
+    assert float(hist_x[2].sum()) == float(in_built.sum()) * F
 
 
 @pytest.mark.parametrize("F", [28, 5, 1])
@@ -163,6 +171,7 @@ def test_route_only_and_leaf_totals_at_int16_w256(F):
     from h2o3_tpu.models.tree import _segment_totals
     N = 64
     codes, ct, nid, ghw, tables, n_prev, base = _wide_inputs(F, N, seed=3)
+    tables = tables[:3] + (jnp.ones(n_prev, jnp.float32),)  # all split
     r_t = binned_route_only_tpu_t(ct, nid, tables, n_prev, base, 256,
                                   tile=512, interpret=True)
     r_x = binned_route_only_xla(codes, nid, tables, n_prev, base, 256)
@@ -287,6 +296,141 @@ def test_grow_tree_binned_interpret_matches_scatter():
     np.testing.assert_array_equal(np.asarray(nid_sc), np.asarray(nid_pl))
 
 
+# ------------- one child a parent, the sibling by subtraction (ISSUE 34)
+
+
+def _int_case(rows, F, n_bins, W, seed, dtype):
+    """Codes with NAs and small-integer g, h, w: every histogram sum is
+    exact in bf16 and in f32, whatever the order of its additions."""
+    rng = np.random.default_rng(seed)
+    codes = rng.integers(0, n_bins, size=(rows, F))
+    codes[rng.random((rows, F)) < 0.06] = W - 1
+    g = rng.integers(-4, 5, rows).astype(np.float32)
+    h = rng.integers(1, 4, rows).astype(np.float32)
+    w = rng.integers(1, 3, rows).astype(np.float32)
+    return (jnp.asarray(codes.astype(dtype)), jnp.asarray(g), jnp.asarray(h),
+            jnp.asarray(w))
+
+
+@pytest.mark.parametrize("n_bins,dtype,method", [
+    (20, np.int8, "scatter"), (20, np.int8, "pallas"),
+    (254, np.int16, "scatter"), (254, np.int16, "pallas")],
+    ids=["w32-int8-scatter", "w32-int8-kernel", "w256-int16-scatter",
+         "w256-int16-kernel"])
+def test_smaller_child_levels_grow_the_direct_formulations_tree(
+        monkeypatch, n_bins, dtype, method):
+    """Exact arithmetic: the grower that accumulates each parent's smaller
+    child and derives its sibling gives, bit for bit, the tree of the
+    formulation that builds every node of a level directly (the oracle,
+    tests/_direct_levels.py); bf16 addends, NAs, depth 5, and parents that
+    do not split."""
+    from _direct_levels import assert_same_tree, grow_direct
+    monkeypatch.setenv("H2O3_PALLAS_INTERPRET", "1")
+    F, W, depth = 3, pick_W(n_bins), 5
+    codes, g, h, w = _int_case(1800, F, n_bins, W, seed=n_bins, dtype=dtype)
+    cfg = TreeConfig(max_depth=depth, n_bins=n_bins, n_features=F,
+                     min_rows=200.0, min_split_improvement=0.0,
+                     hist_method=method, histogram_precision="bfloat16")
+    col_mask = jnp.ones(F, bool)
+    tree, nid = grow_tree_binned(codes, g, h, w, cfg, col_mask)
+    want, want_nid = grow_direct(codes, g, h, w, cfg, col_mask)
+    assert_same_tree(tree, nid, want, want_nid, depth)
+
+
+def _skewed_levels(rows=200_000, small=10, seed=4):
+    """Codes whose feature 0 routes three levels down one chain: the root
+    splits at bin 1 (``small`` rows left), its right child at bin 2 (40%
+    left), that one's right child at bin 3 (45% left); bf16-valued g, h of
+    one magnitude, w = 1; a second feature of three bins for the cells."""
+    rng = np.random.default_rng(seed)
+    route = 1 + (rng.random(rows) > 0.4) * (1 + (rng.random(rows) > 0.45))
+    codes = np.stack([np.where(np.arange(rows) < small, 0, route),
+                      rng.integers(0, 3, rows)], axis=1).astype(np.int8)
+    ghw = np.stack([rng.uniform(-1, 1, rows), rng.uniform(0.2, 0.3, rows),
+                    np.ones(rows)]).astype(np.float32)
+    ghw = np.asarray(jnp.asarray(ghw).astype(jnp.bfloat16).astype(jnp.float32))
+    return codes, ghw
+
+
+def _derived_levels(codes, ghw, builds, W=16):
+    """The levels' histograms [3, N, 2, W] and the rows' node ids, level by
+    level down the chain of :func:`_skewed_levels`: level d's splitting
+    node is the last of level d - 1 and ``builds[d - 1]`` its ``can``
+    entry (which child is built); the sibling is parent - built."""
+    from h2o3_tpu.models.tree import sibling_level_hist
+    from h2o3_tpu.ops.hist_adaptive import binned_level_xla
+    rows = len(codes)
+    z = jnp.zeros(1, jnp.float32)
+    nid, hist = binned_level_xla(jnp.asarray(codes), jnp.zeros(rows, jnp.int32),
+                                 jnp.asarray(ghw), (z, z, z, z), 0, 0, W)
+    out = []
+    for d, build in enumerate(builds, start=1):
+        n_prev = 2 ** (d - 1)
+        last = jnp.zeros(n_prev, jnp.float32).at[-1]
+        tables = (jnp.zeros(n_prev), last.set(float(d)), jnp.zeros(n_prev),
+                  last.set(build))
+        nid, built = binned_level_xla(jnp.asarray(codes), nid,
+                                      jnp.asarray(ghw), tables, n_prev,
+                                      2 ** d - 1, W)
+        hist = sibling_level_hist(built[None], hist, tables)
+        assert hist.shape == (3, 2 ** d, 2, W)
+        out.append((np.asarray(hist, np.float64), np.asarray(nid)))
+    return out
+
+
+def _cells_within(hist, nid, node, codes, ghw, n_anc, k):
+    """Every cell of ``node`` (feature 1) within the bound
+    ops/hist_adaptive.py states for k derived levels running below an
+    ancestor of ``n_anc`` rows: 3 * 2^k * sqrt(n_anc) * 2^-24 *
+    sum_own|a| of the float64 sum of its addends."""
+    base = 2 ** int(np.log2(node + 1)) - 1
+    ok = True
+    for b in range(3):
+        cell = (nid == node) & (codes[:, 1] == b)
+        a = ghw[:, cell].astype(np.float64)
+        err = np.abs(hist[:, node - base, 1, b] - a.sum(axis=1))
+        ok &= bool((err <= 3 * 2 ** k * n_anc ** 0.5 * 2.0 ** -24
+                    * np.abs(a).sum(axis=1)).all())
+    return ok
+
+
+@pytest.mark.parametrize("built", [1.0, 2.0], ids=["smaller-built",
+                                                   "larger-built"])
+def test_a_derived_child_is_within_the_stated_bound_only_when_it_is_larger(
+        built):
+    """One derived level below a 200,000-row root: with the 10-row child
+    built and its 199,990-row sibling derived, both are within the stated
+    bound (k = 0 built, k = 1 derived); with the larger child built, the
+    10-row child inherits the root's absolute error and fails it: why the
+    grower builds the child with the smaller w."""
+    codes, ghw = _skewed_levels()
+    (hist, nid), = _derived_levels(codes, ghw, [built])
+    assert [(nid == n).sum() for n in (1, 2)] == [10, len(codes) - 10]
+    ok = all(_cells_within(hist, nid, 1 + child, codes, ghw, len(codes),
+                           k=int(child == (built == 1.0)))
+             for child in (0, 1))
+    assert ok is (built == 1.0)
+
+
+def test_three_derived_levels_running_stay_within_the_stated_bound():
+    """The larger child three levels running: node 14 is the root less
+    three built siblings, never built itself. Its cells, and every other
+    node's on the way, hold the bound of their own chain length k below
+    the last built ancestor (the root): an absolute error that does not
+    shrink with the node, so 2^k of a direct build's relative to its own
+    sums and no more, because each derived level keeps at least half."""
+    codes, ghw = _skewed_levels()
+    levels = _derived_levels(codes, ghw, [1.0, 1.0, 1.0])
+    sizes = []
+    for d, (hist, nid) in enumerate(levels, start=1):
+        derived, small = 2 ** (d + 1) - 2, 2 ** (d + 1) - 3
+        sizes.append(((nid == small).sum(), (nid == derived).sum()))
+        assert sizes[-1][0] < sizes[-1][1]
+        assert _cells_within(hist, nid, derived, codes, ghw, len(codes), k=d)
+        assert _cells_within(hist, nid, small, codes, ghw, len(codes), k=0)
+    assert sizes[0][0] == 10 and sizes[-1][1] > len(codes) // 4
+
+
 # -------------------------------------------------- hot-loop bytes drop
 
 
@@ -330,7 +474,7 @@ def test_binned_level_bytes_accessed_drop():
 
     ha.pl.pallas_call = spy
     try:
-        ha.binned_level_tpu_t(ct, nid, ghw, tables, N // 2, N, base, W,
+        ha.binned_level_tpu_t(ct, nid, ghw, tables, N // 2, base, W,
                               tile=1024, interpret=True,
                               mxu_dtype=jnp.float32)
         ha.adaptive_level_tpu_t(xt, nid, ghw, tables, lo, inv, N // 2, N,

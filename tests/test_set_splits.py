@@ -292,16 +292,19 @@ def test_interpreted_kernels_match_the_scatter_reference():
     left = rng.random((n_prev, W)) < 0.5
     tables = (jnp.asarray(feat, jnp.float32),
               jnp.asarray(np.asarray(off)[feat], jnp.float32),
-              jnp.zeros(n_prev), jnp.asarray([1.0, 1.0, 0.0, 1.0]),
+              # ``can``: the left child built, the right one, no split
+              jnp.zeros(n_prev), jnp.asarray([1.0, 2.0, 0.0, 1.0]),
               jnp.asarray(left, jnp.float32))
     want_nid, want = ha.binned_level_xla(jnp.asarray(rm), nid, ghw, tables,
-                                         n_prev, 8, 7, W, widths)
-    got_nid, got = ha.binned_level_tpu_t(ct, nid, ghw, tables, n_prev, 8, 7,
+                                         n_prev, 7, W, widths)
+    got_nid, got = ha.binned_level_tpu_t(ct, nid, ghw, tables, n_prev, 7,
                                          W, tile=512, interpret=True,
                                          mxu_dtype=jnp.float32, widths=widths)
     assert np.array_equal(np.asarray(got_nid), np.asarray(want_nid))
-    assert got.shape == want.shape == (3, 8, sum(widths))
+    # one child a parent, on the parents' rows; nothing where none splits
+    assert got.shape == want.shape == (3, n_prev, sum(widths))
     assert np.allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    assert not np.asarray(want[:, 2]).any() and np.asarray(want[2, 1]).any()
     routed = ha.binned_route_only_tpu_t(ct, nid, tables, n_prev, 7, W,
                                         tile=512, interpret=True)
     assert np.array_equal(np.asarray(routed), np.asarray(want_nid))
@@ -312,6 +315,41 @@ def test_interpreted_kernels_match_the_scatter_reference():
     moved = np.asarray(want_nid) != np.asarray(nid)
     assert np.array_equal(moved, node != 2)
     assert np.array_equal(went_left[moved], left[node, code][moved])
+
+
+@pytest.mark.parametrize("method", ["scatter", "pallas"],
+                         ids=["scatter", "kernel"])
+def test_smaller_child_levels_grow_the_direct_formulations_tree(monkeypatch,
+                                                                method):
+    """Exact arithmetic on a ragged layout with routing by set: integer g,
+    h, w make every sum exact, so accumulating each parent's smaller child
+    and deriving its sibling grows, bit for bit, the tree (sets included)
+    of the formulation that builds every node directly
+    (tests/_direct_levels.py); NAs, depth 5, parents that do not split."""
+    from _direct_levels import assert_same_tree, grow_direct
+    from h2o3_tpu.ops import hist_adaptive as ha
+    from h2o3_tpu.ops.binning import lane_widths
+    monkeypatch.setenv("H2O3_PALLAS_INTERPRET", "1")
+    rng = np.random.default_rng(34)
+    bins, rows, depth = (5, 12, 3, 20), 1800, 5
+    widths = lane_widths(bins)
+    rm = np.stack([rng.integers(0, b, rows) for b in bins], axis=1)
+    rm = np.where(rng.random(rm.shape) < 0.06, np.asarray(widths) - 1,
+                  rm).astype(np.int16)
+    g, h, w = (jnp.asarray(rng.integers(lo, hi, rows).astype(np.float32))
+               for lo, hi in ((-4, 5), (1, 4), (1, 3)))
+    cfg = T.TreeConfig(max_depth=depth, n_bins=max(bins), n_features=4,
+                       min_rows=200.0, min_split_improvement=0.0,
+                       hist_method=method, histogram_precision="bfloat16",
+                       set_feats=(True, True, False, True), bin_counts=bins,
+                       lane_widths=widths)
+    col_mask = jnp.ones(4, bool)
+    ct = jnp.asarray((rm + np.asarray(ha.lane_offsets(widths), np.int16)).T)
+    tree, nid = T.grow_tree_binned(jnp.asarray(rm), g, h, w, cfg, col_mask,
+                                   ct=ct)
+    want, want_nid = grow_direct(jnp.asarray(rm), g, h, w, cfg, col_mask)
+    assert_same_tree(tree, nid, want, want_nid, depth)
+    assert np.asarray(tree["set_split"])[np.asarray(tree["is_split"])].any()
 
 
 # ---------------------------------------- everything that reads the trees

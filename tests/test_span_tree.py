@@ -92,7 +92,8 @@ def test_a_train_leaves_the_span_tree_with_the_right_parents(warm_train):
     # the packed path says what its levels ran, as the model's record does
     pc = est.model.output["packed_codes"]
     for key in ("W", "kernel", "feature_block", "row_tile", "leaf_lookup",
-                "n_nodes", "lanes", "lane_layout", "set_features"):
+                "n_nodes", "lanes", "lane_layout", "set_features",
+                "level_hist", "acc_rows"):
         assert loop.attrs[key] == pc[key], key
     # a numeric frame: every feature W lanes, no set feature
     assert (pc["lanes"], pc["lane_layout"], pc["set_features"]) == (
@@ -225,6 +226,51 @@ def _counter(name, algo="gbm"):
                if s["name"] == name and s.get("labels", {}).get("algo") == algo)
 
 
+def _enum_frame():
+    from h2o3_tpu.frame.vec import T_ENUM, Vec
+    rng = np.random.default_rng(34)
+    c = rng.integers(0, 12, ROWS)
+    x = rng.normal(size=ROWS).astype(np.float32)
+    y = (rng.normal(size=12)[c] + 0.5 * x > 0).astype(np.int32)
+    return h2o.Frame(["c", "x", "y"], [
+        Vec.from_numpy(c, T_ENUM, [f"k{i}" for i in range(12)]),
+        Vec.from_numpy(x), Vec.from_numpy(y, T_ENUM, ["n", "y"])])
+
+
+@pytest.mark.parametrize("algo,depth,acc_rows,precision", [
+    ("gbm", 5, 24, "bfloat16"), ("xgboost", 6, 48, "auto"),
+    ("gbm-sets", 4, 12, "bfloat16"), ("gbm-sets", 4, 12, "auto"),
+    ("gbm", 1, 3, "auto")],
+    ids=["gbm-d5-bf16", "xgboost-d6", "set-splits-d4-bf16", "set-splits-d4",
+         "stump"])
+def test_the_loop_span_says_what_a_level_accumulates(frame, algo, depth,
+                                                     acc_rows, precision):
+    """A call of the packed level accumulates one child of every
+    previous-level node, the one with the smaller w. With bf16 sums its
+    sibling comes by subtraction (``smaller_child``); float32 histograms
+    (``auto`` on this small frame) build it by a second call
+    (``both_children``). The loop span and the model's record say which,
+    with the accumulator rows of the deepest level, 3 * 2^(depth - 2)."""
+    from h2o3_tpu.models.xgboost import H2OXGBoostEstimator
+    Est = H2OXGBoostEstimator if algo == "xgboost" else (
+        H2OGradientBoostingEstimator)
+    more = {} if precision == "auto" else {"histogram_precision": precision}
+    est = Est(ntrees=2, max_depth=depth, seed=1, packed_codes=True,
+              score_tree_interval=0, **more)
+    telemetry.install()
+    telemetry.clear_spans()
+    est.train(y="y", training_frame=_enum_frame() if algo == "gbm-sets"
+              else frame)
+    _, named = _tree(telemetry.finished_spans())
+    loop, pc = named["train.loop"][0], est.model.output["packed_codes"]
+    assert pc["set_features"] == (1 if algo == "gbm-sets" else 0)
+    assert (pc["level_hist"], pc["acc_rows"]) == (
+        "smaller_child" if precision == "bfloat16" else "both_children",
+        acc_rows)
+    assert (loop.attrs["level_hist"], loop.attrs["acc_rows"]) == (
+        pc["level_hist"], pc["acc_rows"])
+
+
 def test_a_set_split_train_says_so_in_spans_counters_and_routes():
     """An enum column on the packed path (ISSUE 33): the sketch span
     counts the enum features, the loop span and the model's record carry
@@ -256,7 +302,8 @@ def test_a_set_split_train_says_so_in_spans_counters_and_routes():
     # 40 levels + NA -> 48 lanes, 20 bins + NA -> 24
     assert (pc["lane_layout"], pc["lanes"], pc["set_features"], pc["W"]) == (
         "ragged", 72, 1, 48)
-    for key in ("lanes", "lane_layout", "set_features"):
+    for key in ("lanes", "lane_layout", "set_features", "level_hist",
+                "acc_rows"):
         assert loop.attrs[key] == pc[key], key
     m = est.model
     splits = int(np.asarray(m._is_split).sum())

@@ -25,7 +25,7 @@ SCORE_ROWS = 500_000      # the score cell's held-out frame
 W = 16                    # nbins=14 -> 16 lanes per feature
 ROWS = 8 * ha.TILE
 ROOT = (0, 1, 0)          # (n_prev, n_nodes, level_base)
-LEVEL5 = (16, 32, 31)
+LEVEL5 = (16, 32, 31)     # a packed level holds 3 * n_prev accumulator rows
 
 
 @pytest.fixture(scope="module")
@@ -69,22 +69,22 @@ def _level_operands(code_dtype, n_prev):
 def _binned_t(level, width=W, code_dtype=jnp.int8):
     """``width`` 256 with int16 codes is XGBoost-hist's shape: 254 bins
     and the NA lane."""
-    n_prev, n_nodes, base = level
+    n_prev, _, base = level
 
     def fn(ct, nid, ghw, *tables):
-        return ha.binned_level_tpu_t(ct, nid, ghw, tables, n_prev, n_nodes,
-                                     base, width, tile=ha.TILE)
+        return ha.binned_level_tpu_t(ct, nid, ghw, tables, n_prev, base,
+                                     width, tile=ha.TILE)
     return fn, _level_operands(code_dtype, n_prev)
 
 
 def _binned_stripe(level, mxu_dtype=jnp.bfloat16):
-    n_prev, n_nodes, base = level
+    n_prev, _, base = level
 
     def fn(ct, nid, ghw, *tables):
         # the operand and F exactly as binned_level hands them over
         return ha.binned_level_tpu_stripe(
-            stripe_pair_codes(ct, W), nid, ghw, tables, n_prev, n_nodes,
-            base, W, tile=ha.TILE, F=ct.shape[0], mxu_dtype=mxu_dtype)
+            stripe_pair_codes(ct, W), nid, ghw, tables, n_prev, base, W,
+            tile=ha.TILE, F=ct.shape[0], mxu_dtype=mxu_dtype)
     return fn, _level_operands(jnp.int8, n_prev)
 
 
@@ -113,12 +113,12 @@ def _airline_operands(n_prev, ghw=True):
 
 
 def _binned_t_ragged(level):
-    n_prev, n_nodes, base = level
+    n_prev, _, base = level
     widths, operands = _airline_operands(n_prev)
 
     def fn(ct, nid, ghw, *tables):
-        return ha.binned_level_tpu_t(ct, nid, ghw, tables, n_prev, n_nodes,
-                                     base, max(widths), tile=ha.TILE,
+        return ha.binned_level_tpu_t(ct, nid, ghw, tables, n_prev, base,
+                                     max(widths), tile=ha.TILE,
                                      widths=widths)
     return fn, operands
 
@@ -198,6 +198,40 @@ def test_compiles_for_v5e(case, one_chip, no_persistent_cache):
     compiled = jax.jit(fn).lower(*shapes).compile()
     n_mosaic = compiled.as_text().count('custom_call_target="tpu_custom_call"')
     assert (n_mosaic > 0) == (case in PALLAS), (case, n_mosaic)
+
+
+def _pallas_eqn(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for sub in eqn.params.values():
+            found = _pallas_eqn(sub.jaxpr) if hasattr(sub, "jaxpr") else None
+            if found is not None:
+                return found
+    return None
+
+
+@pytest.mark.parametrize("case,acc", [
+    # the airline cell's last split level: 256 parents, 896 ragged lanes
+    ("binned_level_tpu_t-ragged896-level9", (768, 896)),
+    # XGBoost-hist's: 16 parents, 28 features of 256 lanes
+    ("binned_level_tpu_t-w256-level5", (48, 7168))])
+def test_a_level_accumulates_one_child_a_parent_on_v5e(case, acc, one_chip,
+                                                       no_persistent_cache):
+    """The deepest level of the two long train cells, compiled: scratch
+    and output hold one child of every previous-level node, half the rows
+    of a level built node by node, and the declared flops are half too."""
+    fn, operands = CASES[case]()
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+              for shape, dtype in operands]
+    eqn = _pallas_eqn(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    assert eqn.outvars[1].aval.shape == acc
+    assert [a.shape for a in eqn.params["grid_mapping"].scratch_avals] == [acc]
+    n_nodes = 2 * acc[0] // 3
+    assert eqn.params["cost_estimate"].flops == (
+        2 * 3 * n_nodes * acc[1] * ROWS) // 2
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert f"f32[{acc[0]},{acc[1]}]" in text
 
 
 @pytest.mark.parametrize("nodes", [127, 63])

@@ -166,7 +166,8 @@ _TINY = dict(ntrees=2, max_depth=2, seed=3, min_rows=1.0,
              score_tree_interval=0, stopping_rounds=0)
 _PACKED_KEYS = {"enabled", "dtype", "W", "bytes_per_value", "n_bins",
                 "kernel", "feature_block", "row_tile", "leaf_lookup",
-                "n_nodes", "lanes", "lane_layout", "set_features"}
+                "n_nodes", "lanes", "lane_layout", "set_features",
+                "level_hist", "acc_rows"}
 # the trees of this numeric frame as the commit before category-set splits
 # (fe4f801) grew them: feat, na_left, is_split, and thr and value to four
 # decimals
@@ -218,6 +219,9 @@ def test_dense_trainers_take_the_tables_path(monkeypatch, request, Est,
     if want == "packed":
         assert (pc["kernel"], pc["feature_block"], pc["n_nodes"]) == (
             "binned_level_xla", 4, 7)
+        # depth 2: a call of the deeper level accumulates one child of the
+        # root; float32 histograms (auto on a small frame) build both
+        assert (pc["level_hist"], pc["acc_rows"]) == ("both_children", 3)
         # a numeric frame keeps the uniform layout and thresholds
         assert (pc["lanes"], pc["lane_layout"], pc["set_features"]) == (
             4 * pc["W"], "uniform", 0)

@@ -92,7 +92,7 @@ def main(argv=None) -> int:
             tabs = tables_for(rng, max(n_prev, 1), widths, True)
             fn = jax.jit(lambda c, n, g, *t, N=N, n_prev=n_prev, W=W,
                          widths=widths: ha.binned_level_tpu_t(
-                             c, n, g, t, n_prev, N, N - 1, W,
+                             c, n, g, t, n_prev, N - 1, W,
                              interpret=interpret, widths=widths))
             _, s = best_of(fn, (ct, nid, ghw) + tabs, args.reps)
             emit(what="level", layout=name, lanes=sum(widths), level=d,

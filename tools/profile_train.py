@@ -138,7 +138,7 @@ def _level_split(rows, F, nbins, depth):
         f32_ms = timeit(partial(adaptive_level, n_prev=n_prev, n_nodes=N,
                                 level_base=base, W=W), X, nid, ghw,
                         tables, lo, inv, xt=Xt)
-        packed_ms = timeit(partial(binned_level, n_prev=n_prev, n_nodes=N,
+        packed_ms = timeit(partial(binned_level, n_prev=n_prev,
                                    level_base=base, W=W), codes, nid,
                            ghw, tables, ct=ct)
         levels.append({"level": d, "n_nodes": N,
@@ -165,8 +165,9 @@ def _fused_pass(rows, F, nbins, depth):
     import jax.numpy as jnp
     from functools import partial
 
-    from h2o3_tpu.models.tree import levels_per_pass
-    from h2o3_tpu.ops.hist_adaptive import binned_level, pick_W
+    from h2o3_tpu.models.tree import (level_child_sums, levels_per_pass,
+                                      sibling_level_hist)
+    from h2o3_tpu.ops.hist_adaptive import pick_W
     if jax.default_backend() != "tpu":
         rows = min(rows, 1 << 18)
     W = pick_W(max(nbins, 2))
@@ -189,29 +190,30 @@ def _fused_pass(rows, F, nbins, depth):
                 (best % W).astype(jnp.float32),
                 jnp.zeros(N, jnp.float32), jnp.ones(N, jnp.float32))
 
-    def window(codes, ct, nid, ghw, tables, *, d0, Lw):
+    def window(codes, ct, nid, ghw, tables, hist, *, d0, Lw):
         recs = []
         for j in range(Lw):
             d = d0 + j
             N = 2 ** d
-            nid, hist = binned_level(codes, nid, ghw, tables,
-                                     N // 2 if d else 0, N, N - 1, W,
-                                     ct=ct)
+            nid, sums = level_child_sums(codes, nid, ghw, tables,
+                                         N // 2 if d else 0, N - 1, W, ct=ct)
+            hist = sibling_level_hist(sums, hist if d else None, tables)
             tables = select_tables(hist, N)
             recs.append(tables[0])
-        return nid, tables, recs
+        return nid, tables, hist, recs
 
     def tree(L):
         nid = jnp.zeros(rows, jnp.int32)
         tables = (jnp.zeros(1, jnp.float32), jnp.ones(1, jnp.float32),
                   jnp.zeros(1, jnp.float32), jnp.zeros(1, jnp.float32))
         loop_s = fetch_s = 0.0
+        hist = None
         d = 0
         while d < depth:
             Lw = min(L, depth - d)
             t0 = time.perf_counter()
-            nid, tables, recs = wins[(L, d, Lw)](codes, ct, nid, ghw,
-                                                 tables)
+            nid, tables, hist, recs = wins[(L, d, Lw)](codes, ct, nid, ghw,
+                                                       tables, hist)
             jax.block_until_ready(nid)
             t1 = time.perf_counter()
             jax.device_get(recs)           # boundary record fetch
