@@ -10,7 +10,14 @@ K/V store of columnar frame chunks, computed over with MRTask map/reduce
 - MRTask's binary-tree map/reduce becomes ``shard_map`` + XLA collectives
   (``psum``/``all_gather``/``reduce_scatter``) over ICI;
 - the native XGBoost ``gpu_hist`` path becomes a JAX/pallas histogram tree
-  builder whose per-node grad/hess histograms all-reduce over ICI.
+  builder whose per-node grad/hess histograms all-reduce over ICI: on a mesh
+  with more than one data shard every chip bins and builds the histograms of
+  its own rows, a level is one ``psum`` of the built half, and a table's
+  uniform and identity bin edges come from per-shard extremes reduced over
+  the ``data`` axis, so no row-sized array leaves its chip (quantile edges on
+  an accelerator mesh still go through a host copy: ops/binning.py);
+- device memory is budgeted per device: a row-sharded array is held against
+  one chip's limit by its per-shard bytes (memman.per_shard).
 
 Public surface mirrors the h2o python client (reference h2o-py/h2o/h2o.py).
 """

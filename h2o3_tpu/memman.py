@@ -15,9 +15,16 @@ blocks to host numpy until under the LOW watermark. Algorithms consult
 beyond the budget stream through training in host-chunked blocks
 instead of failing allocation (SURVEY §7.1.7's Criteo-scale config).
 
-The budget defaults to the real device memory when the backend reports
-it, and can be forced with H2O3_DEVICE_BUDGET_BYTES (the tests force a
-tiny budget on the CPU mesh to exercise eviction + streaming).
+The budget is ONE device's memory: the backend's own report, or
+H2O3_DEVICE_BUDGET_BYTES. Everything held against it is row-sharded over
+the mesh's ``data`` axis (a frame's Vec payloads, a design matrix), so
+each device holds ``bytes / data shards`` of it: ``per_shard`` is the one
+place that division is made, and the allocation gate, ``fits_device``
+and the scheduler's admission estimates all read it. A table that fits
+four chips and not one stays dense on a four-shard mesh. (The tests force
+a tiny budget with ``reset(budget=...)`` to exercise eviction +
+streaming; such a budget is held against WHOLE arrays unless the test
+says ``per_shard=True``.)
 """
 from __future__ import annotations
 
@@ -66,8 +73,13 @@ class _Block:
 
 
 class MemoryManager:
-    def __init__(self, budget: Optional[int] = None):
+    def __init__(self, budget: Optional[int] = None,
+                 shards: Optional[int] = None):
         self.budget = budget if budget is not None else _default_budget()
+        # the data shards a row-sharded array is split over when it is
+        # held against one device's budget; None: the current mesh's
+        # (read at each question, the mesh may be set after the manager)
+        self.shards = shards
         # residency is the sum over LIVE blocks: the WeakSet drops
         # garbage-collected payloads automatically, so no counter to
         # keep consistent across gc/spill/free paths
@@ -97,18 +109,31 @@ class MemoryManager:
         with _LOCK:
             self._blocks.discard(block)
 
+    def per_shard(self, nbytes: int) -> int:
+        """Bytes ONE device holds of ``nbytes`` of row-sharded arrays:
+        the whole over the data shards (nothing to divide where the
+        budget is unlimited)."""
+        if self.unlimited:
+            return int(nbytes)
+        shards = self.shards
+        if shards is None:
+            from h2o3_tpu.parallel.mesh import n_data_shards
+            shards = n_data_shards()
+        return -(-int(nbytes) // max(int(shards), 1))
+
     # -- allocation gate (MemoryManager.java malloc-with-wait analog) --
 
     def request(self, nbytes: int) -> None:
-        """Make room for an ``nbytes`` device allocation: evict LRU
-        spillable blocks while the projected residency crosses the high
-        watermark (down to the low one)."""
+        """Make room for an ``nbytes`` row-sharded device allocation:
+        evict LRU spillable blocks while a device's projected share
+        crosses the high watermark (down to the low one)."""
         with _LOCK:
-            if self._resident + nbytes <= self.budget * HIGH_WATERMARK:
+            if self.fits_device(self._resident + nbytes):
                 return
-            target = max(self.budget * LOW_WATERMARK - nbytes, 0)
+            target = max(self.budget * LOW_WATERMARK
+                         - self.per_shard(nbytes), 0)
             for b in sorted(self._blocks, key=lambda b: b.last_use):
-                if self._resident <= target:
+                if self.per_shard(self._resident) <= target:
                     break
                 try:
                     b.spill()
@@ -118,9 +143,10 @@ class MemoryManager:
                     self.released(b)
 
     def fits_device(self, nbytes: int) -> bool:
-        """Whether a dense allocation of this size is within budget —
-        algorithms switch to host-chunked streaming when it is not."""
-        return nbytes <= self.budget * HIGH_WATERMARK
+        """Whether row-sharded arrays of this size in all are within
+        budget, a device's share (:meth:`per_shard`) against its own
+        limit — algorithms switch to host-chunked streaming when not."""
+        return self.per_shard(nbytes) <= self.budget * HIGH_WATERMARK
 
     @property
     def unlimited(self) -> bool:
@@ -162,9 +188,12 @@ def manager() -> MemoryManager:
         return _MANAGER
 
 
-def reset(budget: Optional[int] = None) -> MemoryManager:
-    """Tests: reinstall with an explicit budget."""
+def reset(budget: Optional[int] = None,
+          per_shard: bool = False) -> MemoryManager:
+    """Tests: reinstall with an explicit budget, held against whole
+    arrays (one shard) unless ``per_shard`` asks for the mesh's rule."""
     global _MANAGER
     with _LOCK:
-        _MANAGER = MemoryManager(budget)
+        _MANAGER = MemoryManager(
+            budget, shards=None if budget is None or per_shard else 1)
         return _MANAGER

@@ -525,7 +525,8 @@ class H2ORandomForestEstimator(ModelBuilder):
                 from h2o3_tpu.log import warn
                 warn("drf: final in-training checkpoint failed: %s", e)
         model.output["training_loop_seconds"] = t_loop
-        model.output["packed_codes"] = inputs.record()
+        model.output["packed_codes"] = inputs.record(
+            inputs.mesh_attrs(mesh, built * K))
         # the DRF chunk body (like GBM dense) traces its whole level
         # loop into one executable — all levels per dispatch
         model.output["levels_per_dispatch"] = int(cfg.max_depth)

@@ -978,9 +978,17 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                        else ""))
 
             jax.block_until_ready(margin)  # h2o3-lint: allow[transfer-seam] train-loop timing fence: the loop span must cover device completion, not dispatch
+            # the mesh layout and what the train all-reduced over it, and
+            # the shards' collective/straggler observations
+            mesh_attrs = inputs.mesh_attrs(mesh, built * K)
+            from h2o3_tpu.parallel.shardstats import merge_observations
+            collective = merge_observations(shard_obs)
             if sp_loop is not None:
                 sp_loop.attrs.update(trees=built, chunks=chunks,
-                                     **inputs.loop_attrs())
+                                     **mesh_attrs, **inputs.loop_attrs())
+                if collective and "straggler_ratio" in collective:
+                    sp_loop.attrs["straggler_ratio"] = collective[
+                        "straggler_ratio"]
         with prof.phase("finalize"):
             model = self._finalize(spec, valid_spec, dist_name, f0,
                                    all_trees, bm, cfg, K, built, margin,
@@ -1018,7 +1026,14 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         # hot-loop representation record (ISSUE 12): what the level
         # kernel actually streamed — bench.py and profile_train.py read
         # this for the bytes/row attribution
-        model.output["packed_codes"] = inputs.record()
+        model.output["packed_codes"] = inputs.record(mesh_attrs)
+        if mesh_attrs.get("psum_bytes"):
+            telemetry.counter(
+                "h2o3_collective_bytes_total",
+                {"algo": self.algo, "op": "psum"},
+                help="bytes finished tree trains all-reduced over the "
+                     "data axis (level histograms, leaf totals), from "
+                     "shapes").inc(mesh_attrs["psum_bytes"])
         model.output["categorical_encoding"] = self._encoding_record(
             spec, inputs.set_features)
         # the dense chunk body traces its whole level loop into ONE
@@ -1034,8 +1049,6 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                 n_model_shards(mesh) > 1 and spmd_enabled())}
         # collective/straggler attribution for the scaling verdict
         # (tools/multichip_bench.py reads this per point)
-        from h2o3_tpu.parallel.shardstats import merge_observations
-        collective = merge_observations(shard_obs)
         if collective is not None:
             model.output["spmd"]["collective"] = collective
         return model

@@ -356,12 +356,15 @@ def make_gains_lift(prob, actual, weights=None, groups=16) -> Optional[dict]:
     }
 
 
-def make_binomial_metrics(prob, actual, weights=None) -> ModelMetricsBinomial:
-    """prob = P(class 1); actual ∈ {0,1}."""
+def make_binomial_metrics(prob, actual, weights=None,
+                          nobs: Optional[int] = None) -> ModelMetricsBinomial:
+    """prob = P(class 1); actual ∈ {0,1}. ``nobs``: the observations
+    among the rows, where zero-weight rows ride along (compute_metrics
+    past the exact sweep's size); every row otherwise."""
     prob = jnp.asarray(prob, dtype=jnp.float32)
     y = jnp.asarray(actual, dtype=jnp.float32)
     w = jnp.ones_like(y) if weights is None else jnp.asarray(weights, jnp.float32)
-    n = int(prob.shape[0])
+    n = int(prob.shape[0]) if nobs is None else int(nobs)
     sb, tpb, fpb, Pf, Nf, auc, aucpr = _binary_curve(prob, y, w)
     ll = float(np.asarray(_logloss_kernel(prob, y, w)))
     reg = _regression_kernel(prob, y, w)

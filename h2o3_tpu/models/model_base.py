@@ -643,15 +643,23 @@ def compute_metrics(scores, y, w, nclasses, response_domain=None,
     matrix (80MB at 10M×2) just to drop pad rows before re-uploading it
     into the metric kernels — at bench scale that fetch dominated warm
     train time. When every row is live (the common padded==nrow case)
-    the arrays pass through untouched; otherwise one device gather
-    compacts them. Only kernel outputs (scalars / 2^17-bin curve
-    summaries) ever cross to the host."""
+    the arrays pass through untouched. A binomial frame past the exact
+    sweep's size passes through too: its kernels (the 2^17-bucket curve
+    sketch, log-loss, MSE) weigh every term by ``w``, so a zero-weight
+    row adds nothing, and the compaction it replaces is a gather over the
+    rows (10 ns a row on a TPU, and on a data-sharded mesh the rows of
+    every shard brought to every chip: 123.5M rows with 7 pad rows paid
+    it in full). Otherwise one device gather compacts them. Only kernel
+    outputs (scalars / 2^17-bin curve summaries) ever cross to the
+    host."""
     w_d = jnp.asarray(w)
     live = w_d > 0
-    all_live = bool(live.all())
+    n_live = int(live.sum())
     scores_d = jnp.asarray(scores)
     y_d = jnp.asarray(y)
-    if not all_live:
+    weighed = (nclasses == 2
+               and scores_d.shape[0] > metrics_mod._EXACT_SWEEP_ROWS)
+    if n_live < live.shape[0] and not weighed:
         idx = jnp.nonzero(live)[0]
         scores_d = jnp.take(scores_d, idx, axis=0)
         y_d = jnp.take(y_d, idx, axis=0)
@@ -660,7 +668,8 @@ def compute_metrics(scores, y, w, nclasses, response_domain=None,
         return metrics_mod.make_regression_metrics(
             scores_d, y_d, w_d, deviance=deviance)
     if nclasses == 2:
-        return metrics_mod.make_binomial_metrics(scores_d[:, 1], y_d, w_d)
+        return metrics_mod.make_binomial_metrics(scores_d[:, 1], y_d, w_d,
+                                                 nobs=n_live)
     return metrics_mod.make_multinomial_metrics(scores_d, y_d, w_d)
 
 

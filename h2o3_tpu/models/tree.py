@@ -815,6 +815,21 @@ class TreeInputs(NamedTuple):
         """Features that split by a set of their levels."""
         return sum(self.cfg.set_feats)
 
+    def mesh_attrs(self, mesh, n_trees: int) -> dict:
+        """The layout a train of ``n_trees`` trees ran under: the mesh's
+        ``n_data`` and ``n_model`` and, on the packed path, ``psum_bytes``,
+        the bytes the train all-reduces over the data axis
+        (:func:`packed_tree_psum_bytes` a tree; 0 on one shard)."""
+        from h2o3_tpu.parallel.mesh import n_data_shards, n_model_shards
+        nd = n_data_shards(mesh)
+        out = {"n_data": nd, "n_model": n_model_shards(mesh)}
+        if self.packed:
+            out["psum_bytes"] = (n_trees * packed_tree_psum_bytes(
+                self.cfg, self.pc.W,
+                self.level_plan["level_hist"] == "smaller_child")
+                if nd > 1 else 0)
+        return out
+
     def loop_attrs(self) -> dict:
         """The loop span's attributes: the record's keys."""
         if not self.packed:
@@ -822,15 +837,17 @@ class TreeInputs(NamedTuple):
         return {"W": self.pc.W, "code_bytes": self.pc.itemsize,
                 "set_features": self.set_features, **self.level_plan}
 
-    def record(self) -> dict:
+    def record(self, mesh_attrs: dict) -> dict:
         """``model.output["packed_codes"]``: what the level kernel
-        streamed, and the plan of its levels."""
+        streamed, the plan of its levels, where the sketch's edges were
+        made and the layout the train ran under (:meth:`mesh_attrs`)."""
         if not self.packed:
             return packed_codes_record(False)
         return packed_codes_record(
             True, dtype=self.pc.rm.dtype, W=self.pc.W,
             bytes_per_value=self.pc.itemsize, n_bins=self.bm.n_bins,
-            plan=self.level_plan, set_features=self.set_features)
+            plan={**self.level_plan, "sketch": self.bm.sketch, **mesh_attrs},
+            set_features=self.set_features)
 
 
 def set_split_features(spec, params) -> tuple:
@@ -1248,6 +1265,18 @@ def level_derives(mxu_dtype) -> bool:
     sums) or builds it too (float32 histograms, which promise a node's sums
     to the rounding of its own rows: ops/hist_adaptive.py, PRECISION)."""
     return mxu_dtype != jnp.float32
+
+
+def packed_tree_psum_bytes(cfg: TreeConfig, W: int, derives: bool) -> int:
+    """Bytes ONE packed tree all-reduces over the data axis, from shapes:
+    a level psums what its kernels accumulated (:func:`level_child_sums`:
+    (g, h, w) of one child a previous-level node, of both where the level
+    does not derive) over every lane in float32, and the tree's end the
+    leaves' (g, h, w) totals."""
+    lanes = sum(cfg.lane_widths) if cfg.lane_widths else cfg.n_features * W
+    rows = sum((1 if d == 0 or derives else 2) * level_acc_rows(2 ** d // 2)
+               for d in range(cfg.max_depth))
+    return 4 * (rows * lanes + 3 * 2 ** cfg.max_depth)
 
 
 def level_child_sums(codes_rm, nid, ghw, tables, n_prev: int, level_base: int,

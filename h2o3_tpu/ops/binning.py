@@ -81,6 +81,10 @@ class BinnedMatrix:
     names: List[str]
     is_categorical: List[bool]
     nrow: int
+    # where the edges were made (bin_matrix_device): "mesh" (per-shard
+    # statistics reduced over the data axis), "device" (one device's
+    # sort) or "host" (a host copy of the matrix)
+    sketch: str = "host"
 
     @property
     def n_features(self) -> int:
@@ -172,101 +176,98 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
     This is the "no host round-trips" rule applied to binning itself —
     the sketch half of XGBoost's ``tree_method=hist``.
 
-    Multi-accelerator caveat: XLA lowers the cross-shard column sort to
-    an all-gather, so every chip would need to hold the FULL [padded, F]
-    matrix (plus its sorted copy) — a frame sized for the aggregate HBM
-    of a data-sharded mesh would OOM. On any multi-shard accelerator
-    mesh the EDGES fall back to the host-side sketch (device_get +
-    np.quantile, the pre-device-sketch behavior; identical edges),
-    while the digitise still runs on the sharded device matrix — a host
-    copy handed to it would land whole on the first chip; the
-    CPU test mesh's virtual shards share one host RAM, so it keeps the
-    device path. A per-shard sketch merged with a psum would scale but
-    is not bit-exact — the future lever."""
-    import jax as _jax
+    On a mesh with more than one data shard (the rule is by MESH, not by
+    backend, so the CPU test mesh runs it) edges that need only a
+    column's extremes come from per-shard statistics reduced over the
+    ``data`` axis (:func:`_mesh_sketch_edges`: ``uniform_adaptive`` /
+    ``uniform`` numeric columns, an enum's identity bins up to
+    ``nbins_cats``): O(F) numbers are fetched, no row-sized array goes to
+    the host or onto one chip, and the edges are bit-equal to the
+    one-device path's (a min and a max do not depend on the order of
+    reduction). QUANTILE edges on a multi-shard mesh still need ranks:
+    XLA lowers the cross-shard column sort to an all-gather, so every
+    chip would hold the FULL [padded, F] matrix and its sorted copy, and
+    a frame sized for the mesh's aggregate HBM would OOM. On an
+    accelerator mesh they therefore come from a host copy
+    (``device_get`` + ``np.quantile``, identical edges; the span says
+    ``where="host"`` and ``d2h_bytes`` the table's size); the CPU test
+    mesh's virtual shards share one host RAM and keep the device sort. A
+    sharded quantile sketch is the open lever (ROADMAP). The digitise
+    always runs on the sharded device matrix.
+
+    The sketch span's attrs ``where`` (``mesh`` / ``device`` / ``host``)
+    and ``d2h_bytes`` say where the edges were made and what the host
+    fetched for them; ``BinnedMatrix.sketch`` carries ``where``."""
     from h2o3_tpu import telemetry
     from h2o3_tpu.parallel.mesh import current_mesh, n_data_shards
-    if (_jax.default_backend() != "cpu"
-            and n_data_shards(current_mesh()) > 1):
-        return bin_matrix(X, names, is_cat, nrow, nbins=nbins,
-                          nbins_cats=nbins_cats,
-                          histogram_type=histogram_type, with_t=with_t,
-                          X_host=np.asarray(telemetry.device_get(
-                              X, pipeline="train"), np.float32))
     phase = (prof.phase if prof is not None
              else lambda name: contextlib.nullcontext())
+    mesh = current_mesh()
+    uniform = histogram_type in ("uniform_adaptive", "uniform")
+    sharded = n_data_shards(mesh) > 1
     with phase("bin.sketch") as sp:
-        edges, n_bins_eff = _device_sketch_edges(
-            X, is_cat, nrow, nbins, nbins_cats, histogram_type)
+        found, where = (_mesh_sketch_edges(mesh, X, is_cat, nrow, nbins,
+                                           nbins_cats, uniform)
+                        if sharded else None), "mesh"
+        if found is None and sharded and jax.default_backend() != "cpu":
+            X_host = np.asarray(telemetry.device_get(X, pipeline="train"),
+                                np.float32)
+            found, where = (*_edges_host(X_host, nrow, is_cat, nbins,
+                                         nbins_cats, histogram_type),
+                            X_host.nbytes), "host"
+            del X_host
+        elif found is None:
+            found, where = _device_sketch_edges(
+                X, is_cat, nrow, nbins, nbins_cats, uniform), "device"
+        edges, n_bins_eff, d2h_bytes = found
         if sp is not None:
-            # which edge rule the sort served, and the widest edge list
+            # which edge rule the sketch served, the widest edge list,
+            # where the edges were made and what the host fetched for them
             sp.attrs.update(
-                edges=("uniform" if histogram_type in (
-                    "uniform_adaptive", "uniform") else "quantile"),
+                edges="uniform" if uniform else "quantile",
                 n_edges=max((len(e) for e in edges), default=0),
                 enum_features=int(sum(bool(c) for c in is_cat)),
-                numeric_features=int(sum(not c for c in is_cat)))
+                numeric_features=int(sum(not c for c in is_cat)),
+                where=where, d2h_bytes=int(d2h_bytes))
     with phase("bin.digitize"):
         codes = make_codes_view(digitize_with_edges(X, edges, n_bins_eff),
                                 with_t=with_t)
         jax.block_until_ready(codes)  # h2o3-lint: allow[transfer-seam] digitise timing fence: between two device programs on one stream, so bin.digitize and bin.pack each carry their own
     return BinnedMatrix(codes=codes, n_bins=n_bins_eff, edges=edges,
                         names=list(names), is_categorical=list(is_cat),
-                        nrow=nrow)
+                        nrow=nrow, sketch=where)
 
 
-def _device_sketch_edges(X, is_cat: Sequence[bool], nrow: int, nbins: int,
-                         nbins_cats: int, histogram_type: str):
-    """(edges, effective bin count) of :func:`bin_matrix_device`: one
-    device sort, the fetch of its O(F) stats, the host's float64 lerp."""
-    from h2o3_tpu import telemetry
-    F = X.shape[1]
-    Xs, nfin_d, fmin_d, fmax_d = _sketch_stats(X, jnp.int32(nrow))
-    # ONE counted fetch of the O(F) sketch stats (transfer-seam)
-    nfin, fmin, fmax = (np.asarray(v) for v in telemetry.device_get(
-        (nfin_d, fmin_d, fmax_d), pipeline="train"))
-    uniform = histogram_type in ("uniform_adaptive", "uniform")
-    # per-feature quantile grids (numeric: nbins; over-wide cats:
-    # nbins_cats) — build one padded rank-index matrix for a single gather
-    qgrids: List[Optional[np.ndarray]] = [None] * F
-    for f in range(F):
+def _rank_grids(nfin, fmax, is_cat: Sequence[bool], nbins: int,
+                nbins_cats: int, uniform: bool) -> List[Optional[np.ndarray]]:
+    """Per feature the float64 virtual rank indexes its edges need, or
+    None where they need none: an enum of at most ``nbins_cats`` levels
+    has identity bins, a uniform grid reads min and max alone."""
+    grids: List[Optional[np.ndarray]] = [None] * len(is_cat)
+    for f, cat in enumerate(is_cat):
         n = int(nfin[f])
         if n == 0:
             continue
-        if is_cat[f]:
-            card = int(fmax[f]) + 1
-            if card <= nbins_cats:
+        if cat:
+            if int(fmax[f]) + 1 <= nbins_cats:
                 continue                     # identity bins — no quantiles
             qs = np.linspace(0.0, 1.0, nbins_cats + 1)[1:-1]
         elif uniform:
             continue                         # min/max only
         else:
             qs = np.linspace(0.0, 1.0, nbins + 1)[1:-1]
-        qgrids[f] = qs * (n - 1)             # float64 virtual indexes
-    qmax = max((len(v) for v in qgrids if v is not None), default=0)
-    quant_vals: List[Optional[np.ndarray]] = [None] * F
-    if qmax:
-        lo_idx = np.zeros((qmax, F), np.int32)
-        hi_idx = np.zeros((qmax, F), np.int32)
-        for f, virt in enumerate(qgrids):
-            if virt is None:
-                continue
-            lo_idx[: len(virt), f] = np.floor(virt).astype(np.int32)
-            hi_idx[: len(virt), f] = np.ceil(virt).astype(np.int32)
-        a, b = (np.asarray(v) for v in telemetry.device_get(
-            _gather_rank_pairs(Xs, jnp.asarray(lo_idx),
-                               jnp.asarray(hi_idx)), pipeline="train"))
-        for f, virt in enumerate(qgrids):
-            if virt is None:
-                continue
-            t = virt - np.floor(virt)
-            quant_vals[f] = _np_quantile_lerp(a[: len(virt), f],
-                                              b[: len(virt), f], t)
-    del Xs  # release the sorted full-matrix copy before digitize allocates
+        grids[f] = qs * (n - 1)
+    return grids
+
+
+def _edges_of_stats(nfin, fmin, fmax, quant_vals, is_cat: Sequence[bool],
+                    nbins: int, nbins_cats: int, uniform: bool):
+    """(edges, effective bin count) from a column's finite count, min
+    and max, and the quantile values of the columns that need them."""
     edges: List[np.ndarray] = []
-    for f in range(F):
+    for f, cat in enumerate(is_cat):
         n = int(nfin[f])
-        if is_cat[f]:
+        if cat:
             card = int(fmax[f]) + 1 if n > 0 else 1
             if card <= nbins_cats:
                 e = (np.arange(1, card, dtype=np.float32) - 0.5)
@@ -289,6 +290,93 @@ def _device_sketch_edges(X, is_cat: Sequence[bool], nrow: int, nbins: int,
             f"effective bin count {n_bins_eff} exceeds the 14-bit routing "
             f"limit; lower nbins_cats (reference default is 1024)")
     return edges, n_bins_eff
+
+
+@lru_cache(maxsize=8)
+def _mesh_extremes(mesh):
+    """Cached builder of the per-column (finite count, finite min, finite
+    max) of a row-sharded matrix: every data shard reduces its own rows,
+    then one pmin / pmax / psum of F numbers over the ``data`` axis. Pad
+    rows (global index >= nrow) and non-finite values drop out, as in
+    :func:`_sketch_stats`."""
+    from jax.sharding import PartitionSpec as P
+
+    def local(X, nrow):
+        per = X.shape[0]
+        row0 = jax.lax.axis_index("data") * per
+        ok = (((row0 + jnp.arange(per)) < nrow)[:, None]
+              & jnp.isfinite(X))
+        x = X.astype(jnp.float32)
+        return (jax.lax.psum(jnp.sum(ok, axis=0, dtype=jnp.int32), "data"),
+                jax.lax.pmin(jnp.min(jnp.where(ok, x, jnp.inf), axis=0),
+                             "data"),
+                jax.lax.pmax(jnp.max(jnp.where(ok, x, -jnp.inf), axis=0),
+                             "data"))
+
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P("data"), P()),
+                                 out_specs=P()))
+
+
+def _mesh_sketch_edges(mesh, X, is_cat: Sequence[bool], nrow: int,
+                       nbins: int, nbins_cats: int, uniform: bool):
+    """(edges, effective bin count, bytes fetched) of a row-sharded
+    matrix whose every column's edges need only its extremes, from
+    :func:`_mesh_extremes`; None where a column needs ranks (quantile
+    edges, an enum past ``nbins_cats``) or the rows do not split evenly
+    over the data axis."""
+    from h2o3_tpu import telemetry
+    from h2o3_tpu.parallel.mesh import n_data_shards
+    if X.shape[0] % n_data_shards(mesh):
+        return None
+    # ONE counted fetch of O(F) numbers (transfer-seam)
+    nfin, fmin, fmax = (np.asarray(v) for v in telemetry.device_get(
+        _mesh_extremes(mesh)(X, jnp.int32(nrow)), pipeline="train"))
+    if any(g is not None for g in _rank_grids(nfin, fmax, is_cat, nbins,
+                                              nbins_cats, uniform)):
+        return None
+    return (*_edges_of_stats(nfin, fmin, fmax, None, is_cat, nbins,
+                             nbins_cats, uniform),
+            nfin.nbytes + fmin.nbytes + fmax.nbytes)
+
+
+def _device_sketch_edges(X, is_cat: Sequence[bool], nrow: int, nbins: int,
+                         nbins_cats: int, uniform: bool):
+    """(edges, effective bin count, bytes fetched) of
+    :func:`bin_matrix_device` on one device: one device sort, the fetch
+    of its O(F) stats, the host's float64 lerp."""
+    from h2o3_tpu import telemetry
+    F = X.shape[1]
+    Xs, nfin_d, fmin_d, fmax_d = _sketch_stats(X, jnp.int32(nrow))
+    # ONE counted fetch of the O(F) sketch stats (transfer-seam)
+    nfin, fmin, fmax = (np.asarray(v) for v in telemetry.device_get(
+        (nfin_d, fmin_d, fmax_d), pipeline="train"))
+    d2h_bytes = nfin.nbytes + fmin.nbytes + fmax.nbytes
+    # per-feature quantile grids (numeric: nbins; over-wide cats:
+    # nbins_cats) — build one padded rank-index matrix for a single gather
+    qgrids = _rank_grids(nfin, fmax, is_cat, nbins, nbins_cats, uniform)
+    qmax = max((len(v) for v in qgrids if v is not None), default=0)
+    quant_vals: List[Optional[np.ndarray]] = [None] * F
+    if qmax:
+        lo_idx = np.zeros((qmax, F), np.int32)
+        hi_idx = np.zeros((qmax, F), np.int32)
+        for f, virt in enumerate(qgrids):
+            if virt is None:
+                continue
+            lo_idx[: len(virt), f] = np.floor(virt).astype(np.int32)
+            hi_idx[: len(virt), f] = np.ceil(virt).astype(np.int32)
+        a, b = (np.asarray(v) for v in telemetry.device_get(
+            _gather_rank_pairs(Xs, jnp.asarray(lo_idx),
+                               jnp.asarray(hi_idx)), pipeline="train"))
+        d2h_bytes += a.nbytes + b.nbytes
+        for f, virt in enumerate(qgrids):
+            if virt is None:
+                continue
+            t = virt - np.floor(virt)
+            quant_vals[f] = _np_quantile_lerp(a[: len(virt), f],
+                                              b[: len(virt), f], t)
+    del Xs  # release the sorted full-matrix copy before digitize allocates
+    return (*_edges_of_stats(nfin, fmin, fmax, quant_vals, is_cat, nbins,
+                             nbins_cats, uniform), d2h_bytes)
 
 
 def bin_matrix(X, names: Sequence[str], is_cat: Sequence[bool], nrow: int,

@@ -19,7 +19,9 @@ Estimate provenance (recorded on the entry and on /3/Scheduler):
   even a wild over-estimate live-locked-free).
 - ``shape`` — conservative fallback: the dense design matrix at the
   spec's padded row count times a per-algo working-set factor (margins,
-  histograms, optimizer state), plus the y/w/margin vectors.
+  histograms, optimizer state), plus the y/w/margin vectors; all of it
+  row-sharded, so the estimate is ONE device's share of it
+  (``memman.per_shard``), against one device's admission budget.
 - ``stream-window`` — the frame will not fit dense (the same
   ``fits_device`` test build_training_spec applies), so the train takes
   the host-chunked streaming path and admits at its budget-sized
@@ -131,14 +133,18 @@ def estimate_submission(builder, frame, y=None, x=None,
     if not mm.fits_device(x_bytes + mm.stats()["device_resident_bytes"]):
         # streamed-mode admission: the design stays on host and only the
         # resident window + working vectors occupy HBM
-        win = int(mm.budget * STREAM_WINDOW_FRACTION) + aux_bytes
+        win = int(mm.budget * STREAM_WINDOW_FRACTION) + mm.per_shard(
+            aux_bytes)
         return Estimate(win, True, "stream-window")
 
     factor = ALGO_WORKING_FACTOR.get(
         getattr(builder, "algo", ""), DEFAULT_WORKING_FACTOR)
     # validation matrix is resident but carries no histogram/optimizer
     # working set — added outside the factor
-    base = int(x_bytes * factor) + valid_bytes + aux_bytes
+    # one device's share (memman.per_shard): the design, the validation
+    # matrix and the vectors are all row-sharded over the data axis, and
+    # the admission budget they are held against is one device's
+    base = mm.per_shard(int(x_bytes * factor) + valid_bytes + aux_bytes)
     prefix = _COSTMODEL_PREFIX.get(getattr(builder, "algo", ""))
     if prefix:
         from h2o3_tpu.telemetry import costmodel

@@ -76,7 +76,10 @@ def test_gamma_streaming_guard_rekeyed(monkeypatch):
     gamma's inverse default must fail fast there, gamma+log streams."""
     from h2o3_tpu import memman
     fr = _gamma_frame(n=6000, seed=4)
+    # a forced budget, held against whole arrays (one shard) as
+    # memman.reset(budget=...) holds one
     monkeypatch.setattr(memman.manager(), "budget", 60_000)
+    monkeypatch.setattr(memman.manager(), "shards", 1)
     bad = H2OGeneralizedLinearEstimator(family="gamma", alpha=[0.0],
                                         Lambda=[0.0])
     with pytest.raises(RuntimeError, match="monotone-safe"):

@@ -122,3 +122,64 @@ def test_default_budget_is_the_device_limit_or_an_error(
     else:
         with pytest.raises(want, match="bytes_limit"):
             memman._default_budget()
+
+
+# ---------------- the budget is one device's, the arrays are row-sharded --
+
+
+@pytest.mark.parametrize("per_shard,streams", [(True, False), (False, True)])
+def test_a_table_that_fits_four_shards_and_not_one_stays_dense(per_shard,
+                                                               streams):
+    """Rows are split over the data axis, so a device holds a quarter of a
+    design matrix on a four-shard mesh: held against ONE device's budget by
+    its per-shard bytes it stays dense where the whole would be streamed.
+    build_training_spec and the scheduler's estimate read the one rule
+    (memman.per_shard)."""
+    import jax
+    from h2o3_tpu import sched
+    from h2o3_tpu.models.gbm import H2OGradientBoostingEstimator
+    from h2o3_tpu.models.model_base import build_training_spec
+    from h2o3_tpu.parallel.mesh import current_mesh, make_mesh, set_mesh
+    old = current_mesh()
+    set_mesh(make_mesh(n_data=4, devices=jax.devices()[:4]))
+    try:
+        fr = _frame(n=20_000)
+        x_bytes = (fr.nrow + 256) * 8 * 4
+        # half the matrix: a quarter of it is under 90% of this, all of it
+        # is not
+        mm = memman.reset(budget=x_bytes // 2, per_shard=per_shard)
+        assert mm.per_shard(x_bytes) == (x_bytes // 4 if per_shard
+                                         else x_bytes)
+        assert mm.fits_device(x_bytes) is not streams
+        spec = build_training_spec(fr, "resp")
+        assert spec.stream is streams
+        assert (spec.X is None) is streams
+        est = sched.estimate_submission(
+            H2OGradientBoostingEstimator(ntrees=2, max_depth=2), fr,
+            y="resp")
+        assert est.streamed is streams
+        if not streams:
+            # a device's share of the design, its working set and vectors
+            # (a cached executable's cost may raise it, to 4x at most)
+            share = mm.per_shard(
+                int(x_bytes * 1.7) + (fr.nrow + 256) * 4 * 4)
+            assert share <= est.bytes <= 4 * share
+            assert est.bytes == share or est.source == "costmodel+shape"
+    finally:
+        set_mesh(old)
+
+
+def test_per_shard_follows_the_mesh_and_an_unlimited_budget_divides_nothing():
+    import jax
+    from h2o3_tpu.parallel.mesh import current_mesh, make_mesh, set_mesh
+    assert memman.reset().per_shard(1000) == 1000       # the CPU: unlimited
+    old = current_mesh()
+    try:
+        mm = memman.reset(budget=10_000, per_shard=True)
+        for nd in (1, 2, 8):
+            set_mesh(make_mesh(n_data=nd, devices=jax.devices()[:nd]))
+            assert mm.per_shard(1001) == -(-1001 // nd)
+    finally:
+        set_mesh(old)
+    # a forced budget is held against whole arrays unless asked otherwise
+    assert memman.reset(budget=10_000).per_shard(1001) == 1001

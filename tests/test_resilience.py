@@ -549,8 +549,9 @@ def test_streamed_train_cancel_propagates(monkeypatch):
     CANCELLED with the committed trees."""
     from h2o3_tpu import memman
     fr = _reg_frame(n=1200, seed=4)
-    # force streaming: tiny device budget
+    # force streaming: tiny device budget, held against whole arrays
     monkeypatch.setattr(memman.manager(), "budget", 60_000)
+    monkeypatch.setattr(memman.manager(), "shards", 1)
     est = H2OGradientBoostingEstimator(ntrees=50, max_depth=3, seed=2)
     est.train(y="y", training_frame=fr, background=True)
     est.job.cancel()

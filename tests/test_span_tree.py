@@ -174,7 +174,10 @@ def test_a_packed_drf_train_carries_the_bin_spans_and_gbms_record(
     assert (sketch.attrs["edges"], sketch.attrs["n_edges"]) == ("uniform", 19)
     pc, want = est.model.output["packed_codes"], gbm.model.output["packed_codes"]
     assert set(pc) == set(want)
-    assert pc == {**want, "n_nodes": 15}     # both depth 3, the same frame
+    # both depth 3, the same frame and mesh; DRF's 4 trees all-reduce
+    # four sixths of what GBM's 6 did
+    assert pc == {**want, "n_nodes": 15,
+                  "psum_bytes": want["psum_bytes"] * 4 // 6}
 
 
 def test_a_predict_leaves_its_four_children(frame, warm_train):
@@ -328,6 +331,48 @@ def test_a_set_split_train_says_so_in_spans_counters_and_routes():
                   else str(exposition))
     assert 'h2o3_tree_set_splits_total{algo="gbm"}' in exposition
     assert 'h2o3_tree_splits_total{algo="gbm"}' in exposition
+
+
+@pytest.mark.parametrize("hist,where", [("uniform_adaptive", "mesh"),
+                                        ("quantiles_global", "device")])
+def test_the_sketch_and_loop_spans_say_where_edges_were_made_and_what_crossed(
+        frame, hist, where):
+    """On the suite's 8-shard mesh (ISSUE 35): ``train.bin.sketch`` says
+    where the edges were made and what the host fetched for them,
+    ``train.loop`` and the model's record the mesh layout and the bytes the
+    train all-reduced, ``h2o3_collective_bytes_total`` moves by them once,
+    and the shards' straggler ratio rides the loop span."""
+    from h2o3_tpu.parallel.mesh import current_mesh, n_data_shards
+    nd = n_data_shards(current_mesh())
+    assert nd == 8
+    before = _counter("h2o3_collective_bytes_total")
+    est = H2OGradientBoostingEstimator(
+        ntrees=4, max_depth=3, distribution="bernoulli", seed=1,
+        packed_codes=True, histogram_type=hist, nbins=20)
+    telemetry.clear_spans()
+    est.train(y="y", training_frame=frame)
+    _, named = _tree(telemetry.finished_spans())
+    sketch, loop = named["train.bin.sketch"][0], named["train.loop"][0]
+    assert sketch.attrs["where"] == where
+    # the extremes alone are 3 numbers a column; ranks add their neighbours
+    assert (sketch.attrs["d2h_bytes"] == 3 * FEATURES * 4 if where == "mesh"
+            else sketch.attrs["d2h_bytes"] > 3 * FEATURES * 4)
+    pc = est.model.output["packed_codes"]
+    assert (pc["sketch"], pc["n_data"], pc["n_model"]) == (where, nd, 1)
+    # 25,000 rows a shard: float32 histograms, both children a level;
+    # F x W = 8 x 32 lanes; (1 + 2 + 4) x 3 rows and 8 leaves' totals
+    assert pc["psum_bytes"] == 4 * 4 * (21 * FEATURES * 32 + 3 * 8)
+    for key in ("n_data", "n_model", "psum_bytes"):
+        assert loop.attrs[key] == pc[key], key
+    assert _counter("h2o3_collective_bytes_total") - before == pc[
+        "psum_bytes"]
+    seen = est.model.output["spmd"].get("collective") or {}
+    assert loop.attrs.get("straggler_ratio") == seen.get("straggler_ratio")
+    exposition = __import__("h2o3_tpu.api.server", fromlist=["x"])._metrics(
+        {}, None)["__raw"]
+    exposition = (exposition.decode() if isinstance(exposition, bytes)
+                  else str(exposition))
+    assert 'h2o3_collective_bytes_total{algo="gbm",op="psum"}' in exposition
 
 
 def _jit_spans(stage, parent=None):

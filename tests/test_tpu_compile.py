@@ -271,3 +271,45 @@ def test_the_scorer_gathers_nothing_over_the_rows_on_v5e(depth, one_chip,
         r"\[(\d+),(\d+)\]", text) if str(rows) in (a, b)}
     assert beside_rows <= {1, F, trees}, beside_rows
     assert compiled.memory_analysis().temp_size_in_bytes < rows * nodes
+
+
+WHOLE_TABLE = 123_534_976      # the airline table's rows, padded over 4 shards
+
+
+@pytest.fixture(scope="module")
+def host_mesh(topo):
+    """The four chips of the described host as the platform's mesh."""
+    import numpy as np
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+
+
+@pytest.mark.parametrize("what", ["extremes", "digitize"])
+def test_the_mesh_bin_stage_keeps_every_row_on_its_chip_on_v5e(
+        what, host_mesh, no_persistent_cache):
+    """The whole airline table row-sharded over a v5e 2x2 (ISSUE 35): the
+    sketch's per-shard extremes reduce F numbers over the data axis and the
+    digitise is elementwise: no all-gather, and a chip holds its quarter of
+    the matrix and of the codes, not the table."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from h2o3_tpu.ops import binning
+    rows = NamedSharding(host_mesh, P("data"))
+    whole = NamedSharding(host_mesh, P())
+    X = jax.ShapeDtypeStruct((WHOLE_TABLE, 8), jnp.float32, sharding=rows)
+    if what == "extremes":
+        compiled = binning._mesh_extremes(host_mesh).lower(
+            X, jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)).compile()
+    else:
+        compiled = binning._digitize.lower(
+            X, jax.ShapeDtypeStruct((8, 299), jnp.float32, sharding=whole),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=whole),
+            dtype=jnp.int32).compile()
+    text = compiled.as_text()
+    for op in ("all-gather", "all-to-all", "collective-permute"):
+        assert op not in text, op
+    assert ("all-reduce" in text) == (what == "extremes")
+    mem = compiled.memory_analysis()
+    quarter = WHOLE_TABLE // 4 * 8 * 4
+    assert mem.argument_size_in_bytes <= quarter + 4096 * 8
+    assert (mem.temp_size_in_bytes + mem.output_size_in_bytes
+            <= 2 * quarter + (1 << 20))

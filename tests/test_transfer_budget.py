@@ -295,3 +295,116 @@ def test_transfer_bytes_pipeline_attribution():
     t0 = r.value("h2o3_d2h_bytes_total")
     telemetry.record_d2h(25)           # no span, no label: total only
     assert r.value("h2o3_d2h_bytes_total") == t0 + 25
+
+
+# ------------------------------------------- the bin stage's edges, by mesh
+
+
+def _edge_matrix(kind, rows=5000, padded=5120, seed=35):
+    """[padded, 4] and its columns' kinds: numeric columns with NaN and
+    both infinities, enum columns of level indices with NaN; the pad rows
+    hold values that would move every edge if they were read."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(padded, 4)).astype(np.float32) * 7.0
+    is_cat = [False, False, False, False]
+    if kind == "enum":
+        X[:, 1] = rng.integers(0, 300, padded)
+        X[:, 3] = rng.integers(0, 7, padded)
+        is_cat = [False, True, False, True]
+    X[rng.random(X.shape) < 0.04] = np.nan
+    X[5, 0], X[6, 0], X[7, 2] = np.inf, -np.inf, np.inf
+    X[rows:] = 1e9
+    return X, is_cat
+
+
+@pytest.mark.parametrize("kind", ["numeric", "enum"])
+@pytest.mark.parametrize("hist", ["uniform_adaptive", "uniform"])
+def test_mesh_made_edges_are_the_one_device_edges_and_fetch_a_few_numbers(
+        hist, kind):
+    """On a mesh with more than one data shard (the 8-shard CPU mesh) the
+    edges that need only a column's extremes come from per-shard statistics
+    reduced over the data axis: bit-equal to one device's edges and codes,
+    with O(F) numbers brought to the host and no row-sized array."""
+    import jax
+    from h2o3_tpu.ops import binning
+    from h2o3_tpu.parallel.mesh import (current_mesh, data_sharding,
+                                        make_mesh, set_mesh)
+    rows = 5000
+    X, is_cat = _edge_matrix(kind, rows)
+    old, got = current_mesh(), {}
+    try:
+        for nd in (1, 8):
+            set_mesh(make_mesh(n_data=nd, devices=jax.devices()[:nd]))
+            Xd = jax.device_put(X, data_sharding())
+            d0 = _counter("h2o3_d2h_pipeline_bytes_total",
+                          {"pipeline": "train"})
+            bm = binning.bin_matrix_device(Xd, list("abcd"), is_cat, rows,
+                                           nbins=20, histogram_type=hist)
+            got[nd] = (bm, _counter("h2o3_d2h_pipeline_bytes_total",
+                                    {"pipeline": "train"}) - d0)
+    finally:
+        set_mesh(old)
+    (one, _), (mesh, fetched) = got[1], got[8]
+    assert (one.sketch, mesh.sketch) == ("device", "mesh")
+    # finite count, min and max of 4 columns
+    assert fetched == 3 * 4 * 4
+    assert one.n_bins == mesh.n_bins
+    for a, b in zip(one.edges, mesh.edges):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert np.array_equal(np.asarray(one.codes.rm), np.asarray(mesh.codes.rm))
+    assert len(mesh.codes.rm.sharding.device_set) == 8
+
+
+def test_quantile_edges_on_the_cpu_mesh_keep_the_device_sort():
+    """Ranks need the sorted columns: the CPU mesh keeps the device sort
+    (an accelerator mesh copies the matrix to the host and says so), and
+    so does an enum past nbins_cats."""
+    import jax
+    from h2o3_tpu.ops import binning
+    from h2o3_tpu.parallel.mesh import data_sharding
+    X, is_cat = _edge_matrix("enum")
+    Xd = jax.device_put(X, data_sharding())
+    bm = binning.bin_matrix_device(Xd, list("abcd"), is_cat, 5000, nbins=20,
+                                   histogram_type="quantiles_global")
+    assert bm.sketch == "device"
+    wide = binning.bin_matrix_device(Xd, list("abcd"), is_cat, 5000, nbins=20,
+                                     nbins_cats=64,
+                                     histogram_type="uniform_adaptive")
+    assert wide.sketch == "device"
+    host = binning.bin_matrix(Xd, list("abcd"), is_cat, 5000, nbins=20,
+                              nbins_cats=64,
+                              histogram_type="uniform_adaptive")
+    for a, b in zip(wide.edges, host.edges):
+        assert np.array_equal(a, b)
+
+
+def test_quantile_edges_on_an_accelerator_mesh_go_through_the_host_and_say_so(
+        monkeypatch):
+    """The branch no CPU run takes by itself (it asks the backend): on an
+    accelerator mesh, edges that need ranks come from a host copy of the
+    whole matrix. The sketch span says so, the D2H counter carries the
+    table's bytes, and the edges are the device sort's."""
+    import jax
+    from h2o3_tpu.log import Profile
+    from h2o3_tpu.ops import binning
+    from h2o3_tpu.parallel.mesh import data_sharding
+    X, is_cat = _edge_matrix("enum")
+    Xd = jax.device_put(X, data_sharding())
+    args = (Xd, list("abcd"), is_cat, 5000)
+    kw = dict(nbins=20, histogram_type="quantiles_global", with_t=False)
+    want = binning.bin_matrix_device(*args, **kw)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d0 = _counter("h2o3_d2h_pipeline_bytes_total", {"pipeline": "train"})
+    telemetry.clear_spans()
+    got = binning.bin_matrix_device(*args, prof=Profile(), **kw)
+    fetched = _counter("h2o3_d2h_pipeline_bytes_total",
+                       {"pipeline": "train"}) - d0
+    assert (want.sketch, got.sketch) == ("device", "host")
+    sketch, = [s for s in telemetry.finished_spans()
+               if s.name.endswith("bin.sketch")]
+    assert (sketch.attrs["where"], sketch.attrs["d2h_bytes"]) == (
+        "host", X.nbytes)
+    assert fetched >= X.nbytes
+    for a, b in zip(want.edges, got.edges):
+        assert np.array_equal(a, b)
+    assert np.array_equal(np.asarray(want.codes.rm), np.asarray(got.codes.rm))

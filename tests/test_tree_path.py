@@ -167,7 +167,10 @@ _TINY = dict(ntrees=2, max_depth=2, seed=3, min_rows=1.0,
 _PACKED_KEYS = {"enabled", "dtype", "W", "bytes_per_value", "n_bins",
                 "kernel", "feature_block", "row_tile", "leaf_lookup",
                 "n_nodes", "lanes", "lane_layout", "set_features",
-                "level_hist", "acc_rows"}
+                "level_hist", "acc_rows",
+                # where the edges were made and the mesh the train ran
+                # under, with what it all-reduced (PR 35)
+                "sketch", "n_data", "n_model", "psum_bytes"}
 # the trees of this numeric frame as the commit before category-set splits
 # (fe4f801) grew them: feat, na_left, is_split, and thr and value to four
 # decimals
