@@ -840,13 +840,15 @@ class TreeInputs(NamedTuple):
     def record(self, mesh_attrs: dict) -> dict:
         """``model.output["packed_codes"]``: what the level kernel
         streamed, the plan of its levels, where the sketch's edges were
-        made and the layout the train ran under (:meth:`mesh_attrs`)."""
+        made and how many columns it sorted, and the layout the train ran
+        under (:meth:`mesh_attrs`)."""
         if not self.packed:
             return packed_codes_record(False)
         return packed_codes_record(
             True, dtype=self.pc.rm.dtype, W=self.pc.W,
             bytes_per_value=self.pc.itemsize, n_bins=self.bm.n_bins,
-            plan={**self.level_plan, "sketch": self.bm.sketch, **mesh_attrs},
+            plan={**self.level_plan, "sketch": self.bm.sketch,
+                  "ranked_features": self.bm.ranked_features, **mesh_attrs},
             set_features=self.set_features)
 
 

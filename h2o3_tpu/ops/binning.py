@@ -83,8 +83,10 @@ class BinnedMatrix:
     nrow: int
     # where the edges were made (bin_matrix_device): "mesh" (per-shard
     # statistics reduced over the data axis), "device" (one device's
-    # sort) or "host" (a host copy of the matrix)
+    # extremes and, for the columns that need ranks, its sort) or "host"
+    # (a host copy of the matrix), and how many columns the device sorted
     sketch: str = "host"
+    ranked_features: int = 0
 
     @property
     def n_features(self) -> int:
@@ -118,20 +120,40 @@ def uniform_edges(col: np.ndarray, nbins: int) -> np.ndarray:
     return np.linspace(lo, hi, nbins + 1)[1:-1].astype(np.float32)
 
 
+def _counted(X, row0, nrow):
+    """[rows, F] bool: the values of a block of rows (its first is row
+    ``row0`` of the matrix) that a sketch counts: finite, and not in a pad
+    row (index >= nrow) - the host path's ``col[np.isfinite(col)]``."""
+    return (((row0 + jnp.arange(X.shape[0])) < nrow)[:, None]
+            & jnp.isfinite(X))
+
+
+def _column_extremes(X, row0, nrow):
+    """Per column the (count, min, max) of a block's counted values
+    (:func:`_counted`); a column with none reads (0, +inf, -inf). The ONE
+    statement of the rule: one device runs it whole
+    (:func:`_device_extremes`), a mesh a shard with the three reduced over
+    the ``data`` axis (:func:`_mesh_extremes`). One pass of reductions
+    over ``X``; no row-sized array outlives it."""
+    ok = _counted(X, row0, nrow)
+    x = X.astype(jnp.float32)
+    return (jnp.sum(ok, axis=0, dtype=jnp.int32),
+            jnp.min(jnp.where(ok, x, jnp.inf), axis=0),
+            jnp.max(jnp.where(ok, x, -jnp.inf), axis=0))
+
+
 @jax.jit
-def _sketch_stats(X, nrow):
-    """Device half of the global sketch: finite-masked sort per feature
-    plus the tiny per-feature stats the host edge rules need. Pad rows
-    (index >= nrow) and ±inf are masked to NaN so they sort last and drop
-    out of the finite count — matching the host path's
-    ``col[np.isfinite(col)]`` filter."""
-    inrow = (jnp.arange(X.shape[0]) < nrow)[:, None]
-    Xf = jnp.where(inrow & jnp.isfinite(X), X.astype(jnp.float32), jnp.nan)
-    Xs = jnp.sort(Xf, axis=0)                       # finite asc, NaN last
-    nfin = jnp.sum(~jnp.isnan(Xf), axis=0).astype(jnp.int32)
-    fmax = jnp.take_along_axis(Xs, jnp.maximum(nfin - 1, 0)[None, :],
-                               axis=0)[0]
-    return Xs, nfin, Xs[0], fmax
+def _device_extremes(X, nrow):
+    """:func:`_column_extremes` of a whole matrix on one device."""
+    return _column_extremes(X, 0, nrow)
+
+
+@jax.jit
+def _sort_finite(X, nrow):
+    """Each column's counted values ascending, NaN after them: what
+    :func:`_column_extremes` counts, in rank order."""
+    return jnp.sort(jnp.where(_counted(X, 0, nrow), X.astype(jnp.float32),
+                              jnp.nan), axis=0)
 
 
 @jax.jit
@@ -169,12 +191,17 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
     the codes, which costs one dispatch latency and tells the two device
     programs' times apart.
 
-    The device sorts each feature once and the host fetches only O(F)
-    stats plus the 2·(nbins-1) quantile neighbour values per feature; the
-    float64 lerp and the unique/truncate bookkeeping stay on host where
-    they are exact and cheap. Digitisation then runs on device as usual.
-    This is the "no host round-trips" rule applied to binning itself —
-    the sketch half of XGBoost's ``tree_method=hist``.
+    One reduction pass gives every column's finite count, min and max
+    (:func:`_column_extremes`), :func:`_rank_grids` says from them which
+    columns' edges read ranks (quantile edges, an enum past
+    ``nbins_cats``), and the device sorts those columns alone
+    (:func:`_device_sketch_edges`): ``uniform_adaptive`` / ``uniform``
+    numerics and identity-bin enums are never sorted. The host fetches
+    only O(F) stats plus the 2·(nbins-1) quantile neighbour values of a
+    ranked feature; the float64 lerp and the unique/truncate bookkeeping
+    stay on host where they are exact and cheap. Digitisation then runs
+    on device as usual. This is the "no host round-trips" rule applied to
+    binning itself — the sketch half of XGBoost's ``tree_method=hist``.
 
     On a mesh with more than one data shard (the rule is by MESH, not by
     backend, so the CPU test mesh runs it) edges that need only a
@@ -195,9 +222,11 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
     sharded quantile sketch is the open lever (ROADMAP). The digitise
     always runs on the sharded device matrix.
 
-    The sketch span's attrs ``where`` (``mesh`` / ``device`` / ``host``)
-    and ``d2h_bytes`` say where the edges were made and what the host
-    fetched for them; ``BinnedMatrix.sketch`` carries ``where``."""
+    The sketch span's attrs ``where`` (``mesh`` / ``device`` / ``host``),
+    ``d2h_bytes`` and ``ranked_features`` say where the edges were made,
+    what the host fetched for them and how many columns the device sorted
+    (0 on the mesh and through the host); ``BinnedMatrix.sketch`` and
+    ``.ranked_features`` carry the first and the last."""
     from h2o3_tpu import telemetry
     from h2o3_tpu.parallel.mesh import current_mesh, n_data_shards
     phase = (prof.phase if prof is not None
@@ -209,6 +238,7 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
         found, where = (_mesh_sketch_edges(mesh, X, is_cat, nrow, nbins,
                                            nbins_cats, uniform)
                         if sharded else None), "mesh"
+        ranked = 0          # columns sorted on the device for their ranks
         if found is None and sharded and jax.default_backend() != "cpu":
             X_host = np.asarray(telemetry.device_get(X, pipeline="train"),
                                 np.float32)
@@ -217,25 +247,28 @@ def bin_matrix_device(X, names: Sequence[str], is_cat: Sequence[bool],
                             X_host.nbytes), "host"
             del X_host
         elif found is None:
-            found, where = _device_sketch_edges(
-                X, is_cat, nrow, nbins, nbins_cats, uniform), "device"
+            *found, ranked = _device_sketch_edges(
+                X, is_cat, nrow, nbins, nbins_cats, uniform)
+            where = "device"
         edges, n_bins_eff, d2h_bytes = found
         if sp is not None:
             # which edge rule the sketch served, the widest edge list,
-            # where the edges were made and what the host fetched for them
+            # where the edges were made, what the host fetched for them
+            # and how many columns the device sorted for their ranks
             sp.attrs.update(
                 edges="uniform" if uniform else "quantile",
                 n_edges=max((len(e) for e in edges), default=0),
                 enum_features=int(sum(bool(c) for c in is_cat)),
                 numeric_features=int(sum(not c for c in is_cat)),
-                where=where, d2h_bytes=int(d2h_bytes))
+                where=where, d2h_bytes=int(d2h_bytes),
+                ranked_features=ranked)
     with phase("bin.digitize"):
         codes = make_codes_view(digitize_with_edges(X, edges, n_bins_eff),
                                 with_t=with_t)
         jax.block_until_ready(codes)  # h2o3-lint: allow[transfer-seam] digitise timing fence: between two device programs on one stream, so bin.digitize and bin.pack each carry their own
     return BinnedMatrix(codes=codes, n_bins=n_bins_eff, edges=edges,
                         names=list(names), is_categorical=list(is_cat),
-                        nrow=nrow, sketch=where)
+                        nrow=nrow, sketch=where, ranked_features=ranked)
 
 
 def _rank_grids(nfin, fmax, is_cat: Sequence[bool], nbins: int,
@@ -294,24 +327,16 @@ def _edges_of_stats(nfin, fmin, fmax, quant_vals, is_cat: Sequence[bool],
 
 @lru_cache(maxsize=8)
 def _mesh_extremes(mesh):
-    """Cached builder of the per-column (finite count, finite min, finite
-    max) of a row-sharded matrix: every data shard reduces its own rows,
-    then one pmin / pmax / psum of F numbers over the ``data`` axis. Pad
-    rows (global index >= nrow) and non-finite values drop out, as in
-    :func:`_sketch_stats`."""
+    """Cached builder of :func:`_column_extremes` of a row-sharded matrix:
+    every data shard reduces its own rows, then one psum / pmin / pmax of
+    F numbers over the ``data`` axis."""
     from jax.sharding import PartitionSpec as P
 
     def local(X, nrow):
-        per = X.shape[0]
-        row0 = jax.lax.axis_index("data") * per
-        ok = (((row0 + jnp.arange(per)) < nrow)[:, None]
-              & jnp.isfinite(X))
-        x = X.astype(jnp.float32)
-        return (jax.lax.psum(jnp.sum(ok, axis=0, dtype=jnp.int32), "data"),
-                jax.lax.pmin(jnp.min(jnp.where(ok, x, jnp.inf), axis=0),
-                             "data"),
-                jax.lax.pmax(jnp.max(jnp.where(ok, x, -jnp.inf), axis=0),
-                             "data"))
+        row0 = jax.lax.axis_index("data") * X.shape[0]
+        nfin, fmin, fmax = _column_extremes(X, row0, nrow)
+        return (jax.lax.psum(nfin, "data"), jax.lax.pmin(fmin, "data"),
+                jax.lax.pmax(fmax, "data"))
 
     return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P("data"), P()),
                                  out_specs=P()))
@@ -341,42 +366,46 @@ def _mesh_sketch_edges(mesh, X, is_cat: Sequence[bool], nrow: int,
 
 def _device_sketch_edges(X, is_cat: Sequence[bool], nrow: int, nbins: int,
                          nbins_cats: int, uniform: bool):
-    """(edges, effective bin count, bytes fetched) of
-    :func:`bin_matrix_device` on one device: one device sort, the fetch
-    of its O(F) stats, the host's float64 lerp."""
+    """(edges, effective bin count, bytes fetched, columns sorted) of
+    :func:`bin_matrix_device` on one device. The extremes come first (a
+    column's max says whether an enum is past ``nbins_cats``), then
+    :func:`_rank_grids` names the columns whose edges read ranks, and the
+    device sorts those alone: where it names none no sort is dispatched;
+    where it names them all ``X`` is sorted as it is, with no column
+    copy."""
     from h2o3_tpu import telemetry
     F = X.shape[1]
-    Xs, nfin_d, fmin_d, fmax_d = _sketch_stats(X, jnp.int32(nrow))
     # ONE counted fetch of the O(F) sketch stats (transfer-seam)
     nfin, fmin, fmax = (np.asarray(v) for v in telemetry.device_get(
-        (nfin_d, fmin_d, fmax_d), pipeline="train"))
+        _device_extremes(X, jnp.int32(nrow)), pipeline="train"))
     d2h_bytes = nfin.nbytes + fmin.nbytes + fmax.nbytes
     # per-feature quantile grids (numeric: nbins; over-wide cats:
     # nbins_cats) — build one padded rank-index matrix for a single gather
     qgrids = _rank_grids(nfin, fmax, is_cat, nbins, nbins_cats, uniform)
-    qmax = max((len(v) for v in qgrids if v is not None), default=0)
+    ranked = [f for f, virt in enumerate(qgrids) if virt is not None]
     quant_vals: List[Optional[np.ndarray]] = [None] * F
-    if qmax:
-        lo_idx = np.zeros((qmax, F), np.int32)
-        hi_idx = np.zeros((qmax, F), np.int32)
-        for f, virt in enumerate(qgrids):
-            if virt is None:
-                continue
-            lo_idx[: len(virt), f] = np.floor(virt).astype(np.int32)
-            hi_idx[: len(virt), f] = np.ceil(virt).astype(np.int32)
+    if ranked:
+        qmax = max(len(qgrids[f]) for f in ranked)
+        lo_idx = np.zeros((qmax, len(ranked)), np.int32)
+        hi_idx = np.zeros((qmax, len(ranked)), np.int32)
+        for j, f in enumerate(ranked):
+            virt = qgrids[f]
+            lo_idx[: len(virt), j] = np.floor(virt).astype(np.int32)
+            hi_idx[: len(virt), j] = np.ceil(virt).astype(np.int32)
+        Xs = _sort_finite(X if len(ranked) == F else X[:, np.asarray(ranked)],
+                          jnp.int32(nrow))
         a, b = (np.asarray(v) for v in telemetry.device_get(
             _gather_rank_pairs(Xs, jnp.asarray(lo_idx),
                                jnp.asarray(hi_idx)), pipeline="train"))
+        del Xs  # release the sorted copy before digitize allocates
         d2h_bytes += a.nbytes + b.nbytes
-        for f, virt in enumerate(qgrids):
-            if virt is None:
-                continue
+        for j, f in enumerate(ranked):
+            virt = qgrids[f]
             t = virt - np.floor(virt)
-            quant_vals[f] = _np_quantile_lerp(a[: len(virt), f],
-                                              b[: len(virt), f], t)
-    del Xs  # release the sorted full-matrix copy before digitize allocates
+            quant_vals[f] = _np_quantile_lerp(a[: len(virt), j],
+                                              b[: len(virt), j], t)
     return (*_edges_of_stats(nfin, fmin, fmax, quant_vals, is_cat, nbins,
-                             nbins_cats, uniform), d2h_bytes)
+                             nbins_cats, uniform), d2h_bytes, len(ranked))
 
 
 def bin_matrix(X, names: Sequence[str], is_cat: Sequence[bool], nrow: int,

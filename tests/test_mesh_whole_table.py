@@ -98,6 +98,7 @@ def test_four_shards_say_what_they_ran_and_all_reduced(precision):
     sketch, loop = spans["train.bin.sketch"], spans["train.loop"]
     # finite count, min and max of 8 columns: 3 x 8 x 4 bytes
     assert (sketch.attrs["where"], sketch.attrs["d2h_bytes"]) == ("mesh", 96)
+    assert sketch.attrs["ranked_features"] == pc["ranked_features"] == 0
     for key in ("n_data", "n_model", "psum_bytes"):
         assert loop.attrs[key] == pc[key], key
     seen = spmd.get("collective", {})
@@ -110,7 +111,10 @@ def test_one_shard_all_reduces_nothing():
     pc = m.output["packed_codes"]
     assert (pc["n_data"], pc["sketch"], pc["psum_bytes"]) == (1, "device", 0)
     assert _counter() == before
-    assert spans["train.bin.sketch"].attrs["where"] == "device"
+    # one device: the same extremes, no column sorted, the same 96 bytes
+    sketch = spans["train.bin.sketch"]
+    assert (sketch.attrs["where"], sketch.attrs["d2h_bytes"]) == ("device", 96)
+    assert sketch.attrs["ranked_features"] == pc["ranked_features"] == 0
     assert spans["train.loop"].attrs["psum_bytes"] == 0
     assert "straggler_ratio" not in spans["train.loop"].attrs
 

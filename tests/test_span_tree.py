@@ -106,6 +106,8 @@ def test_a_train_leaves_the_span_tree_with_the_right_parents(warm_train):
     assert (sketch.attrs["edges"], sketch.attrs["n_edges"]) == ("uniform", 19)
     assert (sketch.attrs["enum_features"],
             sketch.attrs["numeric_features"]) == (0, FEATURES)
+    # uniform edges read a min and a max: no column is sorted (ISSUE 36)
+    assert sketch.attrs["ranked_features"] == pc["ranked_features"] == 0
     # the stages follow one another inside train.train
     order = [named[n][0] for n in ("train.bin.sketch", "train.bin.digitize",
                                    "train.bin.pack", "train.loop",
@@ -357,8 +359,12 @@ def test_the_sketch_and_loop_spans_say_where_edges_were_made_and_what_crossed(
     # the extremes alone are 3 numbers a column; ranks add their neighbours
     assert (sketch.attrs["d2h_bytes"] == 3 * FEATURES * 4 if where == "mesh"
             else sketch.attrs["d2h_bytes"] > 3 * FEATURES * 4)
+    # uniform edges sort no column, quantile edges every one (ISSUE 36)
+    ranked = 0 if where == "mesh" else FEATURES
+    assert sketch.attrs["ranked_features"] == ranked
     pc = est.model.output["packed_codes"]
     assert (pc["sketch"], pc["n_data"], pc["n_model"]) == (where, nd, 1)
+    assert pc["ranked_features"] == ranked
     # 25,000 rows a shard: float32 histograms, both children a level;
     # F x W = 8 x 32 lanes; (1 + 2 + 4) x 3 rows and 8 leaves' totals
     assert pc["psum_bytes"] == 4 * 4 * (21 * FEATURES * 32 + 3 * 8)

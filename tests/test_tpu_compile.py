@@ -273,6 +273,25 @@ def test_the_scorer_gathers_nothing_over_the_rows_on_v5e(depth, one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < rows * nodes
 
 
+@pytest.mark.parametrize("rows,features", [(BENCH_ROWS, F), (40_001_536, 8)],
+                         ids=["defaults_10m_x_28", "airline_40m_x_8"])
+def test_the_one_device_extremes_sort_nothing_and_keep_no_row_sized_array(
+        rows, features, one_chip, no_persistent_cache):
+    """The sketch's first pass at the train cells' shapes (ISSUE 36): the
+    finite count, min and max of every column in reductions over ``X``, no
+    sort in the program and no masked or sorted ``[rows, F]`` copy beside
+    the matrix (the parent's sketch held two)."""
+    from h2o3_tpu.ops import binning
+    compiled = binning._device_extremes.lower(
+        jax.ShapeDtypeStruct((rows, features), jnp.float32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    assert not re.search(r"\bsort\(", compiled.as_text())
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes <= 3 * features * 4 + 4096
+    assert mem.temp_size_in_bytes < rows * 4        # under one column of X
+
+
 WHOLE_TABLE = 123_534_976      # the airline table's rows, padded over 4 shards
 
 

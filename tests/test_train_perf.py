@@ -33,6 +33,14 @@ def _pad(col, pad):
     return out
 
 
+def _assert_same_bins(got, want):
+    """Two BinnedMatrix of one matrix: bit-equal edges, equal codes."""
+    assert got.n_bins == want.n_bins
+    for f, (a, b) in enumerate(zip(got.edges, want.edges)):
+        assert a.dtype == b.dtype and np.array_equal(a, b), (f, a, b)
+    assert np.array_equal(np.asarray(got.codes.rm), np.asarray(want.codes.rm))
+
+
 def _parity_case(X, names, is_cat, nrow, nbins, nbins_cats, hist):
     import jax.numpy as jnp
     from h2o3_tpu.ops.binning import bin_matrix, bin_matrix_device
@@ -40,11 +48,7 @@ def _parity_case(X, names, is_cat, nrow, nbins, nbins_cats, hist):
                      nbins_cats=nbins_cats, histogram_type=hist)
     bmd = bin_matrix_device(jnp.asarray(X), names, is_cat, nrow, nbins=nbins,
                             nbins_cats=nbins_cats, histogram_type=hist)
-    assert bmh.n_bins == bmd.n_bins
-    for f in range(len(names)):
-        assert np.array_equal(bmh.edges[f], bmd.edges[f]), \
-            (hist, names[f], bmh.edges[f], bmd.edges[f])
-    assert np.array_equal(np.asarray(bmh.codes.rm), np.asarray(bmd.codes.rm))
+    _assert_same_bins(bmd, bmh)
 
 
 @pytest.mark.parametrize("hist", ["quantiles_global", "uniform_adaptive"])
@@ -67,6 +71,182 @@ def test_device_sketch_edges_match_host(hist):
     names = list("abcdefg")
     is_cat = [False, False, True, True, False, False, False]
     _parity_case(X, names, is_cat, n, nbins=16, nbins_cats=64, hist=hist)
+
+
+@pytest.fixture
+def mesh_of():
+    """``mesh_of(n)`` puts the suite on a mesh of n data shards (1: the
+    one-device sketch) until the test ends."""
+    import jax
+    from h2o3_tpu.parallel.mesh import current_mesh, make_mesh, set_mesh
+    old = current_mesh()
+    yield lambda nd: set_mesh(make_mesh(n_data=nd,
+                                        devices=jax.devices()[:nd]))
+    set_mesh(old)
+
+
+@pytest.fixture
+def sorts(monkeypatch):
+    """The blocks' shapes the sketch hands to its sort, one a call."""
+    from h2o3_tpu.ops import binning
+    seen, sort = [], binning._sort_finite
+
+    def counted(X, nrow):
+        seen.append(X.shape)
+        return sort(X, nrow)
+
+    counted.lower = sort.lower
+    monkeypatch.setattr(binning, "_sort_finite", counted)
+    return seen
+
+
+def _sketch_frame(columns, n=3000, pad=3072, seed=36):
+    """[pad, len(columns)] of the named column kinds with NaN sprinkled in
+    and pad rows that would move every edge if they were read."""
+    rng = np.random.default_rng(seed)
+    make = {"numeric": lambda: rng.normal(size=pad) * 5.0,
+            "enum": lambda: rng.integers(0, 40, pad),      # identity bins
+            "wide_enum": lambda: rng.integers(0, 200, pad)}
+    X = np.stack([make[c]() for c in columns], axis=1).astype(np.float32)
+    X[rng.random(X.shape) < 0.05] = np.nan
+    X[n:] = 1e9
+    return X, [c != "numeric" for c in columns]
+
+
+def _sketched(X, is_cat, n, hist, nbins=16, nbins_cats=64):
+    """(the device sketch's BinnedMatrix, its sketch span) on the mesh in
+    force."""
+    import jax
+    from h2o3_tpu import telemetry
+    from h2o3_tpu.log import Profile
+    from h2o3_tpu.ops.binning import bin_matrix_device
+    from h2o3_tpu.parallel.mesh import data_sharding
+    telemetry.clear_spans()
+    bm = bin_matrix_device(jax.device_put(X, data_sharding()),
+                           [f"c{i}" for i in range(X.shape[1])], is_cat, n,
+                           nbins=nbins, nbins_cats=nbins_cats,
+                           histogram_type=hist, prof=Profile())
+    span, = [s for s in telemetry.finished_spans()
+             if s.name.endswith("bin.sketch")]
+    return bm, span
+
+
+@pytest.mark.parametrize("columns", [
+    ("numeric",) * 4, ("enum",) * 3, ("numeric", "enum", "numeric", "enum")],
+    ids=["numerics", "identity_enums", "both"])
+@pytest.mark.parametrize("hist", ["uniform_adaptive", "uniform"])
+def test_one_device_sketch_sorts_nothing_where_no_edge_reads_a_rank(
+        mesh_of, sorts, hist, columns):
+    """Uniform numerics read a min and a max, an enum of at most
+    nbins_cats levels its max: ``_rank_grids`` names no column, so the
+    one-device sketch dispatches no sort (ISSUE 36), says
+    ``ranked_features`` 0, and the program it does run holds none."""
+    import jax.numpy as jnp
+    from h2o3_tpu.ops import binning
+    mesh_of(1)
+    X, is_cat = _sketch_frame(columns)
+    bm, span = _sketched(X, is_cat, 3000, hist)
+    assert sorts == []
+    assert (bm.sketch, bm.ranked_features) == ("device", 0)
+    assert (span.attrs["where"], span.attrs["ranked_features"]) == (
+        "device", 0)
+    assert span.attrs["d2h_bytes"] == 3 * len(columns) * 4
+    args = jnp.asarray(X), jnp.int32(3000)
+    assert "stablehlo.sort" in binning._sort_finite.lower(*args).as_text()
+    assert "stablehlo.sort" not in binning._device_extremes.lower(
+        *args).as_text()
+    want = binning.bin_matrix(X, list(columns), is_cat, 3000, nbins=16,
+                              nbins_cats=64, histogram_type=hist)
+    _assert_same_bins(bm, want)
+
+
+@pytest.mark.parametrize("hist,ranked", [("uniform_adaptive", [2]),
+                                         ("quantiles_global", [0, 2, 3])])
+def test_one_device_sketch_sorts_exactly_the_columns_rank_grids_names(
+        mesh_of, sorts, hist, ranked):
+    """The mixed frame: an enum past nbins_cats beside uniform numerics
+    sorts ONE column of four where the parent sorted all four; under
+    quantile edges every column but the identity-bin enum. Every column's
+    edges are the host rule's either way."""
+    from h2o3_tpu.ops import binning
+    mesh_of(1)
+    columns = ("numeric", "enum", "wide_enum", "numeric")
+    X, is_cat = _sketch_frame(columns)
+    bm, span = _sketched(X, is_cat, 3000, hist)
+    grids = binning._rank_grids(
+        np.isfinite(X[:3000]).sum(0), np.nanmax(X[:3000], axis=0), is_cat,
+        16, 64, hist == "uniform_adaptive")
+    assert [f for f, g in enumerate(grids) if g is not None] == ranked
+    assert sorts == [(X.shape[0], len(ranked))]
+    assert bm.ranked_features == span.attrs["ranked_features"] == len(ranked)
+    want = binning.bin_matrix(X, list(columns), is_cat, 3000, nbins=16,
+                              nbins_cats=64, histogram_type=hist)
+    _assert_same_bins(bm, want)
+
+
+def _hard_columns(n=3000, pad=3072, seed=11):
+    """The columns a min / max / count pass can get wrong: all-NA,
+    constant, both infinities, heavy ties, NA-heavy, a column whose min is
+    -0.0 (and one whose max is), an enum; pad rows past ``n`` hold values
+    that would move every edge."""
+    rng = np.random.default_rng(seed)
+    neg0 = np.abs(rng.normal(size=n)).astype(np.float32)
+    neg0[::7], neg0[3::7] = -0.0, 0.0             # min is -0.0 (or +0.0)
+    X = np.stack([
+        rng.normal(size=n), np.round(rng.normal(size=n) * 2),
+        np.full(n, np.nan), np.full(n, 3.25), neg0, -neg0,
+        np.where(rng.random(n) < 0.6, np.nan, rng.normal(size=n)),
+        rng.integers(0, 9, n)], axis=1).astype(np.float32)
+    X[11, 0], X[12, 0], X[13, 3] = np.inf, -np.inf, np.inf
+    return (np.concatenate([X, np.full((pad - n, X.shape[1]), -1e9,
+                                       np.float32)]),
+            [False] * 7 + [True])
+
+
+@pytest.mark.parametrize("hist", ["quantiles_global", "uniform_adaptive"])
+def test_one_device_sketch_edges_of_the_hard_columns_are_the_host_rule_s(
+        mesh_of, hist):
+    """Bit-equal edges to ``_edges_host`` whichever way a column's min and
+    max were found. The one pair of values that may differ in bits, -0.0
+    and +0.0 (a reduction may return either zero, a sort puts -0.0
+    first), gives the same ``linspace(lo, hi)[1:-1]``, the same ``lo ==
+    hi`` test and the same ``int(fmax) + 1``: held here by a column whose
+    min, and one whose max, is a zero of both signs."""
+    from h2o3_tpu.ops import binning
+    mesh_of(1)
+    X, is_cat = _hard_columns()
+    bm, span = _sketched(X, is_cat, 3000, hist)
+    edges, n_bins = binning._edges_host(X, 3000, is_cat, 16, 64, hist)
+    assert bm.n_bins == n_bins
+    assert span.attrs["ranked_features"] == (
+        0 if hist == "uniform_adaptive" else 6)    # all-NA: nothing to rank
+    for f, (a, b) in enumerate(zip(bm.edges, edges)):
+        assert a.dtype == b.dtype and np.array_equal(a, b), (f, a, b)
+    assert len(bm.edges[2]) == 0                   # all-NA
+    assert hist != "uniform_adaptive" or len(bm.edges[3]) == 0   # constant
+    neg, pos = np.float32(-0.0), np.float32(0.0)
+    assert np.signbit(neg) and not np.signbit(pos)
+    for a, b in (((neg, 1.0), (pos, 1.0)), ((-1.0, neg), (-1.0, pos))):
+        assert np.array_equal(np.linspace(*a, 17)[1:-1],
+                              np.linspace(*b, 17)[1:-1])
+    assert neg == pos and int(neg) + 1 == int(pos) + 1 == 1
+
+
+@pytest.mark.parametrize("hist", ["uniform_adaptive", "uniform"])
+def test_one_device_edges_of_the_hard_columns_are_the_mesh_s(mesh_of, hist):
+    """One statement of the finite count / min / max rule serves one
+    device and the 8-shard mesh: same edges, same codes, and 3·F numbers
+    fetched on both."""
+    X, is_cat = _hard_columns()
+    got = {}
+    for nd in (1, 8):
+        mesh_of(nd)
+        got[nd] = _sketched(X, is_cat, 3000, hist)
+    (one, s1), (mesh, s8) = got[1], got[8]
+    assert (s1.attrs["where"], s8.attrs["where"]) == ("device", "mesh")
+    assert s1.attrs["ranked_features"] == s8.attrs["ranked_features"] == 0
+    assert s1.attrs["d2h_bytes"] == s8.attrs["d2h_bytes"] == 3 * 8 * 4
+    _assert_same_bins(one, mesh)
 
 
 def test_multishard_accelerator_sketch_digitises_the_sharded_matrix(

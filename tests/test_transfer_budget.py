@@ -344,10 +344,12 @@ def test_mesh_made_edges_are_the_one_device_edges_and_fetch_a_few_numbers(
                                     {"pipeline": "train"}) - d0)
     finally:
         set_mesh(old)
-    (one, _), (mesh, fetched) = got[1], got[8]
+    (one, fetched_one), (mesh, fetched) = got[1], got[8]
     assert (one.sketch, mesh.sketch) == ("device", "mesh")
-    # finite count, min and max of 4 columns
-    assert fetched == 3 * 4 * 4
+    # finite count, min and max of 4 columns, on the mesh and (no column
+    # is sorted: ISSUE 36) on one device alike
+    assert fetched == fetched_one == 3 * 4 * 4
+    assert (one.ranked_features, mesh.ranked_features) == (0, 0)
     assert one.n_bins == mesh.n_bins
     for a, b in zip(one.edges, mesh.edges):
         assert a.dtype == b.dtype and np.array_equal(a, b)
@@ -366,16 +368,46 @@ def test_quantile_edges_on_the_cpu_mesh_keep_the_device_sort():
     Xd = jax.device_put(X, data_sharding())
     bm = binning.bin_matrix_device(Xd, list("abcd"), is_cat, 5000, nbins=20,
                                    histogram_type="quantiles_global")
-    assert bm.sketch == "device"
+    assert (bm.sketch, bm.ranked_features) == ("device", 2)
     wide = binning.bin_matrix_device(Xd, list("abcd"), is_cat, 5000, nbins=20,
                                      nbins_cats=64,
                                      histogram_type="uniform_adaptive")
-    assert wide.sketch == "device"
+    assert (wide.sketch, wide.ranked_features) == ("device", 1)
     host = binning.bin_matrix(Xd, list("abcd"), is_cat, 5000, nbins=20,
                               nbins_cats=64,
                               histogram_type="uniform_adaptive")
     for a, b in zip(wide.edges, host.edges):
         assert np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("nbins_cats,ranked", [(1024, 2), (64, 3)])
+def test_one_device_ranked_sketch_fetches_the_stats_and_its_rank_neighbours(
+        nbins_cats, ranked):
+    """The ranked side's budget, unchanged by ISSUE 36 where every column
+    is ranked: 3 numbers a column, then the two float32 neighbours of each
+    edge's rank for the columns that were sorted and no others (the two
+    numerics; the 7-level enum keeps identity bins, the 300-level one is
+    ranked past nbins_cats 64)."""
+    import jax
+    from h2o3_tpu.ops import binning
+    from h2o3_tpu.parallel.mesh import (current_mesh, data_sharding,
+                                        make_mesh, set_mesh)
+    X, is_cat = _edge_matrix("enum")
+    old = current_mesh()
+    try:
+        set_mesh(make_mesh(n_data=1, devices=jax.devices()[:1]))
+        d0 = _counter("h2o3_d2h_pipeline_bytes_total", {"pipeline": "train"})
+        bm = binning.bin_matrix_device(
+            jax.device_put(X, data_sharding()), list("abcd"), is_cat, 5000,
+            nbins=20, nbins_cats=nbins_cats,
+            histogram_type="quantiles_global", with_t=False)
+        fetched = _counter("h2o3_d2h_pipeline_bytes_total",
+                           {"pipeline": "train"}) - d0
+    finally:
+        set_mesh(old)
+    assert bm.ranked_features == ranked
+    widest = (nbins_cats if ranked == 3 else 20) - 1    # edges of a grid
+    assert fetched == 3 * 4 * 4 + 2 * widest * ranked * 4
 
 
 def test_quantile_edges_on_an_accelerator_mesh_go_through_the_host_and_say_so(

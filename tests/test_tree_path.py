@@ -170,7 +170,9 @@ _PACKED_KEYS = {"enabled", "dtype", "W", "bytes_per_value", "n_bins",
                 "level_hist", "acc_rows",
                 # where the edges were made and the mesh the train ran
                 # under, with what it all-reduced (PR 35)
-                "sketch", "n_data", "n_model", "psum_bytes"}
+                "sketch", "n_data", "n_model", "psum_bytes",
+                # the columns the sketch sorted for their ranks (PR 36)
+                "ranked_features"}
 # the trees of this numeric frame as the commit before category-set splits
 # (fe4f801) grew them: feat, na_left, is_split, and thr and value to four
 # decimals
