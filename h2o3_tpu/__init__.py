@@ -21,6 +21,9 @@ K/V store of columnar frame chunks, computed over with MRTask map/reduce
 
 Public surface mirrors the h2o python client (reference h2o-py/h2o/h2o.py).
 """
+import time as _time
+
+_T_IMPORT = (_time.time(), _time.perf_counter())    # span boot.import, below
 
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.frame.vec import Vec
@@ -69,13 +72,15 @@ def init(n_data=None, n_model=1, distributed=False,
     locked-cloud failure model (water/Paxos.java:145), recovery is
     restart + checkpoint reload.
     """
-    if distributed:
-        import jax
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes, process_id=process_id)
-    mesh = make_mesh(n_data=n_data, n_model=n_model)
-    set_mesh(mesh)
+    from h2o3_tpu.telemetry.spans import span
+    with span("boot.init"):     # root: one a process
+        if distributed:
+            import jax
+            jax.distributed.initialize(
+                coordinator_address=coordinator_address,
+                num_processes=num_processes, process_id=process_id)
+        mesh = make_mesh(n_data=n_data, n_model=n_model)
+        set_mesh(mesh)
     if distributed and port and is_coordinator():
         from h2o3_tpu.api import start_server
         start_server(port=port)
@@ -87,3 +92,14 @@ def is_coordinator() -> bool:
     'node answering the web port' role (water/H2O.java boot)."""
     import jax
     return jax.process_index() == 0
+
+
+def _record_import_span() -> None:
+    """Root span ``boot.import``: this package's own import, first line
+    to last, written when it is over (``record_span``)."""
+    from h2o3_tpu.telemetry.spans import record_span
+    record_span("boot.import", _T_IMPORT[0],
+                _time.perf_counter() - _T_IMPORT[1])
+
+
+_record_import_span()

@@ -550,224 +550,228 @@ class H2OGradientBoostingEstimator(ModelBuilder):
         adaptive, packed = inputs.adaptive, inputs.packed
         cfg, bm, pc = inputs.cfg, inputs.bm, inputs.pc
         root_lo, root_hi, nb_f = inputs.root_lo, inputs.root_hi, inputs.nb_f
-        y, w = spec.y, spec.w
-        padded = spec.X.shape[0]
-        if spec.offset is not None and K > 1:
-            raise NotImplementedError(
-                "offset_column is not supported for multinomial GBM "
-                "(matching hex/tree/gbm/GBM.java offset restrictions)")
-        prior = self._resolve_checkpoint(dist_name, spec)
-        huber_delta = jnp.float32(1.0)
-        if K == 1 and dist_name == "huber":
-            # transition point = huber_alpha w-quantile of |resid - init|
-            # on the OFFSET-ADJUSTED scale (the reference re-estimates per
-            # scoring round; computed once here; w-weighted so pad/NA/
-            # zero-weight rows can't skew it). The quantile STAYS a device
-            # scalar: it feeds the chunk step as a traced operand and the
-            # distribution's jnp ops, so the old mid-train device_get was
-            # a pure pipeline stall
-            from h2o3_tpu.models.distributions import (weighted_median,
-                                                       weighted_quantile)
-            yf0 = y.astype(jnp.float32)
-            if spec.offset is not None:
-                yf0 = yf0 - spec.offset
-            med = weighted_median(yf0, w)
-            huber_delta = jnp.maximum(weighted_quantile(
-                jnp.abs(yf0 - med), w,
-                float(p.get("huber_alpha", 0.9))).astype(jnp.float32),
-                jnp.float32(1e-10))
-        dist = (self._dist(dist_name, huber_delta) if K == 1 else None)
-        if K == 1:
-            yf = y.astype(jnp.float32)
-            if prior is not None:
-                f0 = jnp.asarray(prior.f0)
-                margin, prior_has_offset = self._prior_margin(
-                    prior, spec, padded, K)
-            else:
+        # the stage before the loop: prior or checkpoint, f0, the margins,
+        # the chunk's other operands, their placement over the mesh and
+        # the loop-entry fence
+        with prof.phase("init"):
+            y, w = spec.y, spec.w
+            padded = spec.X.shape[0]
+            if spec.offset is not None and K > 1:
+                raise NotImplementedError(
+                    "offset_column is not supported for multinomial GBM "
+                    "(matching hex/tree/gbm/GBM.java offset restrictions)")
+            prior = self._resolve_checkpoint(dist_name, spec)
+            huber_delta = jnp.float32(1.0)
+            if K == 1 and dist_name == "huber":
+                # transition point = huber_alpha w-quantile of |resid - init|
+                # on the OFFSET-ADJUSTED scale (the reference re-estimates per
+                # scoring round; computed once here; w-weighted so pad/NA/
+                # zero-weight rows can't skew it). The quantile STAYS a device
+                # scalar: it feeds the chunk step as a traced operand and the
+                # distribution's jnp ops, so the old mid-train device_get was
+                # a pure pipeline stall
+                from h2o3_tpu.models.distributions import (weighted_median,
+                                                           weighted_quantile)
+                yf0 = y.astype(jnp.float32)
                 if spec.offset is not None:
-                    # initial value on the offset-adjusted scale, not the
-                    # marginal init — early trees shouldn't spend capacity
-                    # correcting a biased intercept
-                    from h2o3_tpu.models.distributions import offset_adjusted_f0
-                    f0 = offset_adjusted_f0(dist, yf, w, spec.offset)
+                    yf0 = yf0 - spec.offset
+                med = weighted_median(yf0, w)
+                huber_delta = jnp.maximum(weighted_quantile(
+                    jnp.abs(yf0 - med), w,
+                    float(p.get("huber_alpha", 0.9))).astype(jnp.float32),
+                    jnp.float32(1e-10))
+            dist = (self._dist(dist_name, huber_delta) if K == 1 else None)
+            if K == 1:
+                yf = y.astype(jnp.float32)
+                if prior is not None:
+                    f0 = jnp.asarray(prior.f0)
+                    margin, prior_has_offset = self._prior_margin(
+                        prior, spec, padded, K)
                 else:
-                    f0 = dist.init_f0(yf, w)
-                margin = jnp.full(padded, f0, jnp.float32)
-                prior_has_offset = False
-            if spec.offset is not None and not prior_has_offset:
-                # offset enters the margin, not the trees: f = f0 + offset + Σ lr·tree
-                # (reference GBM honors offsets in every distribution's
-                # margin); a resumed margin already carries it
-                margin = margin + spec.offset
-        else:
-            if prior is not None:
-                f0 = jnp.asarray(prior.f0)
-                margin, _ = self._prior_margin(prior, spec, padded, K)
+                    if spec.offset is not None:
+                        # initial value on the offset-adjusted scale, not the
+                        # marginal init — early trees shouldn't spend capacity
+                        # correcting a biased intercept
+                        from h2o3_tpu.models.distributions import offset_adjusted_f0
+                        f0 = offset_adjusted_f0(dist, yf, w, spec.offset)
+                    else:
+                        f0 = dist.init_f0(yf, w)
+                    margin = jnp.full(padded, f0, jnp.float32)
+                    prior_has_offset = False
+                if spec.offset is not None and not prior_has_offset:
+                    # offset enters the margin, not the trees: f = f0 + offset + Σ lr·tree
+                    # (reference GBM honors offsets in every distribution's
+                    # margin); a resumed margin already carries it
+                    margin = margin + spec.offset
             else:
-                pri = jnp.maximum(
-                    jnp.zeros(K, jnp.float32).at[y].add(w) / w.sum(), 1e-9)
-                f0 = jnp.log(pri)
-                margin = jnp.broadcast_to(f0, (padded, K)).astype(jnp.float32)
-            yf = y
-        seed = int(p.get("seed", -1) or -1)
-        key = jax.random.PRNGKey(seed if seed != -1 else int(time.time() * 1e3) % (2**31))
-        ntrees = int(p["ntrees"])
-        start_trees = prior.ntrees_built if prior is not None else 0
-        ntrees_new = ntrees - start_trees
-        lr = float(p["learn_rate"])
-        anneal = float(p["learn_rate_annealing"])
-        lr *= anneal ** start_trees
-        col_rate = float(p["col_sample_rate"]) * float(p["col_sample_rate_per_tree"])
-        srpc = self.validate_sample_rate_per_class(spec)
-        if srpc is not None and float(p.get("sample_rate", 1.0)) < 1.0:
-            from h2o3_tpu.log import warn as _warn
-            _warn("sample_rate is ignored when sample_rate_per_class "
-                  "is specified (hex/tree/SharedTree.java:210)")
-        keeper = ScoreKeeper(p.get("stopping_rounds", 0), p.get("stopping_metric"),
-                             p.get("stopping_tolerance", 1e-3), task)
-        interval = max(int(p.get("score_tree_interval", 5) or 5), 1)
-        # validation margin tracked with train edges
-        mesh = current_mesh()
-        nd = n_data_shards(mesh)
-        # chunk operands; na_bin is the packed codes' reserved lane W-1
-        Xtr, codes_t_arg, has_t, na_bin = inputs.operands(spec.X)
-        if Xtr.shape[0] % nd != 0:
-            raise ValueError(
-                f"padded row count {Xtr.shape[0]} is not divisible by "
-                f"the {nd}-shard data axis — the training frame was built "
-                f"under a different mesh; rebuild it after h2o3_tpu.init()")
-        has_valid = valid_spec is not None
-        if has_valid:
-            if valid_spec.X.shape[0] % nd != 0:
+                if prior is not None:
+                    f0 = jnp.asarray(prior.f0)
+                    margin, _ = self._prior_margin(prior, spec, padded, K)
+                else:
+                    pri = jnp.maximum(
+                        jnp.zeros(K, jnp.float32).at[y].add(w) / w.sum(), 1e-9)
+                    f0 = jnp.log(pri)
+                    margin = jnp.broadcast_to(f0, (padded, K)).astype(jnp.float32)
+                yf = y
+            seed = int(p.get("seed", -1) or -1)
+            key = jax.random.PRNGKey(seed if seed != -1 else int(time.time() * 1e3) % (2**31))
+            ntrees = int(p["ntrees"])
+            start_trees = prior.ntrees_built if prior is not None else 0
+            ntrees_new = ntrees - start_trees
+            lr = float(p["learn_rate"])
+            anneal = float(p["learn_rate_annealing"])
+            lr *= anneal ** start_trees
+            col_rate = float(p["col_sample_rate"]) * float(p["col_sample_rate_per_tree"])
+            srpc = self.validate_sample_rate_per_class(spec)
+            if srpc is not None and float(p.get("sample_rate", 1.0)) < 1.0:
+                from h2o3_tpu.log import warn as _warn
+                _warn("sample_rate is ignored when sample_rate_per_class "
+                      "is specified (hex/tree/SharedTree.java:210)")
+            keeper = ScoreKeeper(p.get("stopping_rounds", 0), p.get("stopping_metric"),
+                                 p.get("stopping_tolerance", 1e-3), task)
+            interval = max(int(p.get("score_tree_interval", 5) or 5), 1)
+            # validation margin tracked with train edges
+            mesh = current_mesh()
+            nd = n_data_shards(mesh)
+            # chunk operands; na_bin is the packed codes' reserved lane W-1
+            Xtr, codes_t_arg, has_t, na_bin = inputs.operands(spec.X)
+            if Xtr.shape[0] % nd != 0:
                 raise ValueError(
-                    f"validation frame padded rows {valid_spec.X.shape[0]} "
-                    f"not divisible by the {nd}-shard data axis — rebuild it "
-                    f"after h2o3_tpu.init()")
-            if adaptive:
-                vtrain = valid_spec.X
-            elif packed:
-                # validation codes share the training sketch AND the
-                # packed NA = W-1 convention (predict_binned walk)
-                vtrain = pack_codes_for(valid_spec.X, bm, pc.W, pc.widths)
-            else:
-                vtrain = make_codes_view(digitize_with_edges(
-                    valid_spec.X, bm.edges, bm.n_bins)).rm
-            if prior is not None:
-                vmargin = prior._margin_matrix(valid_spec.X).astype(jnp.float32)
-            else:
-                vmargin = (jnp.full(valid_spec.X.shape[0], f0, jnp.float32) if K == 1
-                           else jnp.broadcast_to(f0, (valid_spec.X.shape[0], K)).astype(jnp.float32))
-            if K == 1 and valid_spec.offset is not None:
-                vmargin = vmargin + valid_spec.offset
-        else:  # small dummies (untraced branches, but args need shapes)
-            vtrain = jnp.zeros((8 * nd, cfg.n_features), Xtr.dtype)
-            vmargin = (jnp.zeros(8 * nd, jnp.float32) if K == 1
-                       else jnp.zeros((8 * nd, K), jnp.float32))
+                    f"padded row count {Xtr.shape[0]} is not divisible by "
+                    f"the {nd}-shard data axis — the training frame was built "
+                    f"under a different mesh; rebuild it after h2o3_tpu.init()")
+            has_valid = valid_spec is not None
+            if has_valid:
+                if valid_spec.X.shape[0] % nd != 0:
+                    raise ValueError(
+                        f"validation frame padded rows {valid_spec.X.shape[0]} "
+                        f"not divisible by the {nd}-shard data axis — rebuild it "
+                        f"after h2o3_tpu.init()")
+                if adaptive:
+                    vtrain = valid_spec.X
+                elif packed:
+                    # validation codes share the training sketch AND the
+                    # packed NA = W-1 convention (predict_binned walk)
+                    vtrain = pack_codes_for(valid_spec.X, bm, pc.W, pc.widths)
+                else:
+                    vtrain = make_codes_view(digitize_with_edges(
+                        valid_spec.X, bm.edges, bm.n_bins)).rm
+                if prior is not None:
+                    vmargin = prior._margin_matrix(valid_spec.X).astype(jnp.float32)
+                else:
+                    vmargin = (jnp.full(valid_spec.X.shape[0], f0, jnp.float32) if K == 1
+                               else jnp.broadcast_to(f0, (valid_spec.X.shape[0], K)).astype(jnp.float32))
+                if K == 1 and valid_spec.offset is not None:
+                    vmargin = vmargin + valid_spec.offset
+            else:  # small dummies (untraced branches, but args need shapes)
+                vtrain = jnp.zeros((8 * nd, cfg.n_features), Xtr.dtype)
+                vmargin = (jnp.zeros(8 * nd, jnp.float32) if K == 1
+                           else jnp.zeros((8 * nd, K), jnp.float32))
 
-        # scoring cadence: early stopping OR an explicit
-        # score_tree_interval both record ScoreKeeper history (the
-        # reference scores every interval regardless of stopping —
-        # learning_curve_plot reads this)
-        # reference default score_tree_interval=0 (score only at the
-        # stopping cadence); ANY positive value is an explicit request
-        sti = int(p.get("score_tree_interval", 0) or 0)
-        score_each = keeper.rounds > 0 or sti > 0
-        chunk = interval if score_each else min(ntrees_new, 50)
-        # in-training checkpoints: align chunk commits to the checkpoint
-        # cadence so every `tree_interval` committed trees persist a
-        # resumable state (hex/tree/SharedTree in_training_checkpoints_*)
-        ckpt_dir = p.get("in_training_checkpoints_dir")
-        ckpt_interval = max(int(
-            p.get("in_training_checkpoints_tree_interval", 1) or 1), 1)
-        ckpt_on = bool(ckpt_dir)
-        if ckpt_on and not score_each:
-            # align chunk commits to the checkpoint cadence — but NEVER
-            # when interval scoring is on: shrinking the chunk there
-            # would change the early-stopping score cadence (a silent
-            # model change); checkpoints then land at the scoring
-            # chunk's commit boundaries instead
-            chunk = max(min(chunk, ckpt_interval), 1)
-        if ckpt_on and ntrees_new / ckpt_interval > 50:
-            # each commit re-fetches every committed tree + writes a
-            # full artifact (O(T²) across the train) — loud, not silent
-            from h2o3_tpu.log import warn as _warn
-            _warn("gbm: in_training_checkpoints_tree_interval=%d means "
-                  "~%d checkpoint commits, each fetching all committed "
-                  "trees and writing a full artifact — consider a "
-                  "larger interval", ckpt_interval,
-                  int(ntrees_new / ckpt_interval))
-        trees_since_ckpt = 0
-        # monotone constraints ({col: ±1}, hex/tree/DTree Constraints) and
-        # interaction constraints ([[col,...],...], per-branch feature
-        # allowance) ride as traced arrays through the chunk step
-        mc = p.get("monotone_constraints") or {}
-        has_mono = bool(mc)
-        mono_arr = jnp.zeros(cfg.n_features, jnp.int32)
-        if has_mono:
-            mono_host = np.zeros(cfg.n_features, np.int32)
-            for cname, direction in dict(mc).items():
-                if cname not in spec.names:
-                    raise ValueError(
-                        f"monotone_constraints column '{cname}' is not a "
-                        f"training feature {list(spec.names)}")
-                if spec.is_cat[spec.names.index(cname)]:
-                    raise ValueError(
-                        f"monotone constraint on categorical column "
-                        f"'{cname}' is not supported (reference restricts "
-                        f"constraints to numeric columns)")
-                mono_host[spec.names.index(cname)] = int(direction)
-            mono_arr = jnp.asarray(mono_host)
-        ic = p.get("interaction_constraints") or None
-        has_sets = bool(ic)
-        sets_arr = jnp.ones((1, cfg.n_features), bool)
-        if has_sets:
-            sets_host = np.zeros((len(ic), cfg.n_features), bool)
-            for si, group in enumerate(ic):
-                for cname in group:
+            # scoring cadence: early stopping OR an explicit
+            # score_tree_interval both record ScoreKeeper history (the
+            # reference scores every interval regardless of stopping —
+            # learning_curve_plot reads this)
+            # reference default score_tree_interval=0 (score only at the
+            # stopping cadence); ANY positive value is an explicit request
+            sti = int(p.get("score_tree_interval", 0) or 0)
+            score_each = keeper.rounds > 0 or sti > 0
+            chunk = interval if score_each else min(ntrees_new, 50)
+            # in-training checkpoints: align chunk commits to the checkpoint
+            # cadence so every `tree_interval` committed trees persist a
+            # resumable state (hex/tree/SharedTree in_training_checkpoints_*)
+            ckpt_dir = p.get("in_training_checkpoints_dir")
+            ckpt_interval = max(int(
+                p.get("in_training_checkpoints_tree_interval", 1) or 1), 1)
+            ckpt_on = bool(ckpt_dir)
+            if ckpt_on and not score_each:
+                # align chunk commits to the checkpoint cadence — but NEVER
+                # when interval scoring is on: shrinking the chunk there
+                # would change the early-stopping score cadence (a silent
+                # model change); checkpoints then land at the scoring
+                # chunk's commit boundaries instead
+                chunk = max(min(chunk, ckpt_interval), 1)
+            if ckpt_on and ntrees_new / ckpt_interval > 50:
+                # each commit re-fetches every committed tree + writes a
+                # full artifact (O(T²) across the train) — loud, not silent
+                from h2o3_tpu.log import warn as _warn
+                _warn("gbm: in_training_checkpoints_tree_interval=%d means "
+                      "~%d checkpoint commits, each fetching all committed "
+                      "trees and writing a full artifact — consider a "
+                      "larger interval", ckpt_interval,
+                      int(ntrees_new / ckpt_interval))
+            trees_since_ckpt = 0
+            # monotone constraints ({col: ±1}, hex/tree/DTree Constraints) and
+            # interaction constraints ([[col,...],...], per-branch feature
+            # allowance) ride as traced arrays through the chunk step
+            mc = p.get("monotone_constraints") or {}
+            has_mono = bool(mc)
+            mono_arr = jnp.zeros(cfg.n_features, jnp.int32)
+            if has_mono:
+                mono_host = np.zeros(cfg.n_features, np.int32)
+                for cname, direction in dict(mc).items():
                     if cname not in spec.names:
                         raise ValueError(
-                            f"interaction_constraints column '{cname}' is "
-                            f"not a training feature")
-                    sets_host[si, spec.names.index(cname)] = True
-            sets_arr = jnp.asarray(sets_host)
-        # pin the margins to the data sharding BEFORE the first dispatch:
-        # freshly-built margins (jnp.full of a traced f0) are replicated,
-        # while every chunk OUTPUT is data-sharded — without this the
-        # first call of each bucket compiles a second, replicated-operand
-        # executable (visible as one stray recompile per new ntrees)
-        from jax.sharding import NamedSharding
-        rows_sh = NamedSharding(mesh, P(DATA_AXIS))
-        margin = resilient_device_put(margin, rows_sh, pipeline="train")
-        vmargin = resilient_device_put(vmargin, rows_sh,
-                                       pipeline="train")
-        # buffer donation is only safe when (a) an early stop can never
-        # force a rollback to the previous chunk's margins and (b) no
-        # in-training checkpoint will device_get a margin after it has
-        # been donated to the next dispatch
-        donate = (keeper.rounds == 0 and not ckpt_on
-                  and jax.default_backend() == "tpu")
-        sc_spec = valid_spec if has_valid else spec
-        want_auc = keeper.metric == "auc"
-        rate_t = jnp.float32(float(p["sample_rate"]))
-        col_rate_t = jnp.float32(col_rate)
-        anneal_t = jnp.float32(anneal)
-        all_trees = []          # [(device chunk trees, n_active)]
-        built = 0               # committed trees
-        disp = 0                # dispatched trees (committed + in flight)
-        inflight = None         # last dispatched, not yet committed chunk
-        stopped = False
-        # per-shard collective/straggler observations (ISSUE 8): the
-        # commit point sits one chunk behind the dispatch frontier, so
-        # watching the committed chunk's output shards there costs the
-        # pipeline nothing the score fetch wasn't already paying
-        shard_obs = []
-        partn = partitioner(mesh)
-        # performance accounting (ISSUE 11): per-executable cost capture
-        # at this jit seam + the measured loop wall -> the train's
-        # roofline point (None when telemetry is off — checked no-op)
-        perf_acc = telemetry.costmodel.accumulator(
-            "train.loop", n_devices=mesh.size)
-        jax.block_until_ready(margin)  # h2o3-lint: allow[transfer-seam] loop-entry fence: resume-margin upload must land before the tree-loop clock starts
+                            f"monotone_constraints column '{cname}' is not a "
+                            f"training feature {list(spec.names)}")
+                    if spec.is_cat[spec.names.index(cname)]:
+                        raise ValueError(
+                            f"monotone constraint on categorical column "
+                            f"'{cname}' is not supported (reference restricts "
+                            f"constraints to numeric columns)")
+                    mono_host[spec.names.index(cname)] = int(direction)
+                mono_arr = jnp.asarray(mono_host)
+            ic = p.get("interaction_constraints") or None
+            has_sets = bool(ic)
+            sets_arr = jnp.ones((1, cfg.n_features), bool)
+            if has_sets:
+                sets_host = np.zeros((len(ic), cfg.n_features), bool)
+                for si, group in enumerate(ic):
+                    for cname in group:
+                        if cname not in spec.names:
+                            raise ValueError(
+                                f"interaction_constraints column '{cname}' is "
+                                f"not a training feature")
+                        sets_host[si, spec.names.index(cname)] = True
+                sets_arr = jnp.asarray(sets_host)
+            # pin the margins to the data sharding BEFORE the first dispatch:
+            # freshly-built margins (jnp.full of a traced f0) are replicated,
+            # while every chunk OUTPUT is data-sharded — without this the
+            # first call of each bucket compiles a second, replicated-operand
+            # executable (visible as one stray recompile per new ntrees)
+            from jax.sharding import NamedSharding
+            rows_sh = NamedSharding(mesh, P(DATA_AXIS))
+            margin = resilient_device_put(margin, rows_sh, pipeline="train")
+            vmargin = resilient_device_put(vmargin, rows_sh,
+                                           pipeline="train")
+            # buffer donation is only safe when (a) an early stop can never
+            # force a rollback to the previous chunk's margins and (b) no
+            # in-training checkpoint will device_get a margin after it has
+            # been donated to the next dispatch
+            donate = (keeper.rounds == 0 and not ckpt_on
+                      and jax.default_backend() == "tpu")
+            sc_spec = valid_spec if has_valid else spec
+            want_auc = keeper.metric == "auc"
+            rate_t = jnp.float32(float(p["sample_rate"]))
+            col_rate_t = jnp.float32(col_rate)
+            anneal_t = jnp.float32(anneal)
+            all_trees = []          # [(device chunk trees, n_active)]
+            built = 0               # committed trees
+            disp = 0                # dispatched trees (committed + in flight)
+            inflight = None         # last dispatched, not yet committed chunk
+            stopped = False
+            # per-shard collective/straggler observations (ISSUE 8): the
+            # commit point sits one chunk behind the dispatch frontier, so
+            # watching the committed chunk's output shards there costs the
+            # pipeline nothing the score fetch wasn't already paying
+            shard_obs = []
+            partn = partitioner(mesh)
+            # performance accounting (ISSUE 11): per-executable cost capture
+            # at this jit seam + the measured loop wall -> the train's
+            # roofline point (None when telemetry is off — checked no-op)
+            perf_acc = telemetry.costmodel.accumulator(
+                "train.loop", n_devices=mesh.size)
+            jax.block_until_ready(margin)  # h2o3-lint: allow[transfer-seam] loop-entry fence: resume-margin upload must land before the tree-loop clock starts
 
         def commit_ckpt(cur_margin):
             """Write an in-training checkpoint at the COMMITTED tree
@@ -888,7 +892,9 @@ class H2OGradientBoostingEstimator(ModelBuilder):
                     # per-executable FLOP/byte attribution: ONE trace+lower
                     # per (config, bucket) key for the process lifetime (NO
                     # backend compile — the zero-recompile guards never see
-                    # it); warm dispatches pay a dict lookup. scale=bucket:
+                    # it, and JAX serves the lowering from its own cache of
+                    # the dispatch above: 2 ms on a v5e, PR 37); warm
+                    # dispatches pay a dict lookup. scale=bucket:
                     # HLO cost analysis counts the tree-scan body once, and
                     # the executable runs it `bucket` times (masked trees
                     # included — they compute). The capture wall is noted
@@ -1012,7 +1018,7 @@ class H2OGradientBoostingEstimator(ModelBuilder):
             for key, phase in (
                 ("bin", "bin"), ("sketch", "bin.sketch"),
                 ("digitize", "bin.digitize"), ("pack", "bin.pack"),
-                ("loop", "loop"), ("score", "score"),
+                ("init", "init"), ("loop", "loop"), ("score", "score"),
                 ("finalize", "finalize"))}
         if perf_acc is not None:
             # measured device time = the loop wall (dispatches pipeline;

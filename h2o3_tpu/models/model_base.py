@@ -986,7 +986,7 @@ class ModelBuilder:
             return
         tp["total_s"] = round(time.perf_counter() - t_call, 4)
         tp["other_s"] = round(tp["total_s"] - sum(
-            tp.get(k, 0.0) for k in ("queue_s", "spec_s", "bin_s",
+            tp.get(k, 0.0) for k in ("queue_s", "spec_s", "bin_s", "init_s",
                                      "loop_s", "finalize_s")), 4)
 
     def _join_typed(self, job: Job):

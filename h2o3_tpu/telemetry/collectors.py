@@ -14,8 +14,14 @@
   and ``jit.build`` (backend compile with no cache hit inside it), one
   of each under the span that was open on the calling thread
   (``spans.fold_span``): its seconds are the stage's host time there,
-  attr ``n`` the number of events; ``h2o3_span_seconds{span="jit.*"}``
-  sums them for /metrics. None of these events fires on a warm dispatch.
+  attr ``n`` the number of events, attr ``top`` the up to five
+  ``[fun_name, seconds]`` of most seconds among them: JAX hands the
+  program's name over with every one of these events (``fun_name``);
+  a ``jit.load`` has none of its own and takes the name of the
+  ``backend_compile_duration`` event that closes around it.
+  ``h2o3_span_seconds{span="jit.*"}`` sums the seconds for /metrics; no
+  series carries a name (unbounded cardinality). None of these events
+  fires on a warm dispatch.
 - **Compile-cache hit/miss**: the persistent-compile-cache events
   (``/jax/compilation_cache/cache_hits`` / ``cache_misses``).
 - **Transfer bytes**: ``record_h2d``/``record_d2h`` counters called from
@@ -69,8 +75,9 @@ def _cache_misses():
         help="persistent compile cache misses")
 
 
-def _duration_listener(key: str, dur: float, **_kw) -> None:
+def _duration_listener(key: str, dur: float, fun_name=None, **_kw) -> None:
     dur = float(dur)
+    start = time.time() - dur  # h2o3-lint: allow[monotonic-durations] wall START anchor reconstructed from a duration JAX reports after the fact
     stage = JIT_STAGE_EVENTS.get(key)
     if stage is None:
         if not key.endswith(BACKEND_COMPILE_SUFFIX):
@@ -79,16 +86,20 @@ def _duration_listener(key: str, dur: float, **_kw) -> None:
         registry().histogram(
             "h2o3_xla_compile_seconds",
             help="XLA backend compile durations").observe(dur)
-        loaded, _TLS.loaded = getattr(_TLS, "loaded", False), False
-        if loaded:
-            return              # counted when its retrieval time arrived
-        stage = "build"
+        loaded, _TLS.loaded = getattr(_TLS, "loaded", None), None
+        if loaded is not None:
+            # a load: the retrieval's own interval, under this event's name
+            stage, (start, dur) = "load", loaded
+        else:
+            stage = "build"
     elif key == CACHE_RETRIEVAL_EVENT:
-        _TLS.loaded = True
+        # JAX names no program here; the backend_compile_duration event
+        # that closes around it does, and the load is folded then
+        _TLS.loaded = (start, dur)
+        return
     from h2o3_tpu.telemetry.spans import fold_span
-    fold_span(
-        f"jit.{stage}",
-        time.time() - dur, dur)  # h2o3-lint: allow[monotonic-durations] wall START anchor reconstructed from a duration JAX reports after the fact
+    fold_span(f"jit.{stage}", start, dur,
+              label=None if fun_name is None else str(fun_name))
 
 
 def _event_listener(key: str, **_kw) -> None:

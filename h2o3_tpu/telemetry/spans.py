@@ -33,6 +33,13 @@ call               histogram  ring  timeline  profiler
 interval that is already over; a profiler annotation has to be entered
 and left on one thread while the work runs, so neither can make one.
 
+What a process leaves before its first model (ISSUE 37): root
+``boot.import`` (``record_span``: the package's own import, from the
+first line of ``h2o3_tpu/__init__.py`` to its last, over when it can be
+written); root ``boot.init`` (``span()`` around ``h2o3_tpu.init``);
+then the first root ``train.*``, whose ``jit.*`` children say by name
+what was traced, lowered, loaded and built (attr ``top``, below).
+
 Parentage: within a thread, nesting is implicit (a thread-local stack).
 Across threads — the micro-batcher's submit/batch/collect trio, the
 training job thread — the parent is handed off EXPLICITLY: capture
@@ -50,7 +57,11 @@ another thread (the batcher) or a duration reported after the fact.
 ``fold_span`` is ``record_span`` for reports that come by the hundred
 under one span (the collectors' ``jit.*``: JAX reports each trace, lower
 and load when it is over): they become ONE child a name of the span
-they fell in, so the ring keeps the spans an operator looks for.
+they fell in, so the ring keeps the spans an operator looks for. The
+child carries attr ``n``, the reports it folded, and, where they came
+with a label (the collectors pass the program's name), attr ``top``: the
+up to five ``[label, seconds]`` of most seconds among them. The names
+live on spans, which the ring bounds; no series is labelled by them.
 """
 from __future__ import annotations
 
@@ -161,7 +172,7 @@ class Span:
         self.t0 = time.perf_counter()
         self.duration_s: Optional[float] = None
         self.annotation = None      # span(): its open profiler annotation
-        # fold_span(): {name: [reports, [(start_wall, seconds), ...]]}
+        # fold_span(): {name: [reports, [(start_wall, seconds, label), ...]]}
         self.folded: Optional[Dict[str, list]] = None
         # trace linkage: the thread's bound trace id wins (the REST
         # handler / job thread bound it), else inherit the parent's —
@@ -175,8 +186,8 @@ class Span:
             self.duration_s = time.perf_counter() - self.t0
             if self.folded:
                 for name, (n, kept) in self.folded.items():
-                    record_span(name, kept[0][0], sum(d for _, d in kept),
-                                parent=self, n=n)
+                    record_span(name, kept[0][0], sum(k[1] for k in kept),
+                                parent=self, n=n, **_top(kept))
                 self.folded = None
             _record_finished(self)
         return self
@@ -337,7 +348,25 @@ def record_span(name: str, start_wall: float, duration_s: float,
     return sp
 
 
-def fold_span(name: str, start_wall: float, duration_s: float) -> None:
+TOP_LABELS = 5
+
+
+def _top(kept) -> Dict[str, list]:
+    """Attr ``top`` of a folded child: the up to five ``[label, seconds]``
+    of most seconds among the labelled reports it kept, a label's
+    reports summed; nothing where no report carried a label."""
+    by_label: Dict[str, float] = {}
+    for _, seconds, label in kept:
+        if label is not None:
+            by_label[label] = by_label.get(label, 0.0) + seconds
+    if not by_label:
+        return {}
+    ranked = sorted(by_label.items(), key=lambda kv: -kv[1])[:TOP_LABELS]
+    return {"top": [[label, seconds] for label, seconds in ranked]}
+
+
+def fold_span(name: str, start_wall: float, duration_s: float,
+              label: Optional[str] = None) -> None:
     """Fold an already-measured interval into ONE child span ``name`` of
     the calling thread's current span. The child is written when that
     span finishes, with the intervals' summed seconds and their number as
@@ -345,13 +374,18 @@ def fold_span(name: str, start_wall: float, duration_s: float) -> None:
     traces 137 times a predict) costs the ring one entry a parent. An
     interval that holds earlier ones of its name replaces them in the
     sum: JAX reports an outer function's trace after the inner traces it
-    contains, and the seconds are host time, counted once. With no open
-    span the interval is recorded at once, as ``record_span`` does."""
+    contains, and the seconds are host time, counted once. ``label``
+    says what the interval was spent on (the collectors pass the
+    program's name): the child then carries attr ``top``, the up to five
+    ``[label, seconds]`` of most seconds among the reports it kept.
+    With no open span the interval is recorded at once, as
+    ``record_span`` does."""
     if not registry().enabled:
         return
+    report = (start_wall, float(duration_s), label)
     parent = current_span()
     if parent is None:
-        record_span(name, start_wall, duration_s, n=1)
+        record_span(name, start_wall, duration_s, n=1, **_top([report]))
         return
     if parent.folded is None:
         parent.folded = {}
@@ -360,7 +394,7 @@ def fold_span(name: str, start_wall: float, duration_s: float) -> None:
     kept = acc[1]
     while kept and kept[-1][0] > start_wall:
         kept.pop()
-    kept.append((start_wall, float(duration_s)))
+    kept.append(report)
 
 
 def finished_spans(n: Optional[int] = None) -> List[Span]:

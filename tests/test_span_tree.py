@@ -72,13 +72,15 @@ def _tree(spans):
     return parents, named
 
 
-def test_a_train_leaves_the_span_tree_with_the_right_parents(warm_train):
+def test_a_train_leaves_the_span_tree_with_the_right_parents(frame,
+                                                             warm_train):
     est, spans = warm_train
     parents, named = _tree(spans)
     want = {"train.gbm": None, "train.queue": "train.gbm",
             "train.spec": "train.gbm", "train.train": "train.gbm",
             "train.bin": "train.train", "train.bin.sketch": "train.bin",
             "train.bin.digitize": "train.bin", "train.bin.pack": "train.bin",
+            "train.init": "train.train",
             "train.loop": "train.train", "train.score": "train.loop",
             "train.finalize": "train.train"}
     for name, parent in want.items():
@@ -87,6 +89,7 @@ def test_a_train_leaves_the_span_tree_with_the_right_parents(warm_train):
     # one span per wait on a score entry: 6 trees scored every 3
     assert len(named["train.score"]) == 2
     assert len(named["train.bin"]) == len(named["train.loop"]) == 1
+    assert len(named["train.init"]) == 1     # the stage before the loop
     loop = named["train.loop"][0]
     assert loop.attrs["trees"] == 6 and loop.attrs["chunks"] == 2
     # the packed path says what its levels ran, as the model's record does
@@ -110,8 +113,8 @@ def test_a_train_leaves_the_span_tree_with_the_right_parents(warm_train):
     assert sketch.attrs["ranked_features"] == pc["ranked_features"] == 0
     # the stages follow one another inside train.train
     order = [named[n][0] for n in ("train.bin.sketch", "train.bin.digitize",
-                                   "train.bin.pack", "train.loop",
-                                   "train.finalize")]
+                                   "train.bin.pack", "train.init",
+                                   "train.loop", "train.finalize")]
     starts = [s.t0 for s in order]
     assert starts == sorted(starts)
     for a, b in zip(order, order[1:]):
@@ -122,14 +125,15 @@ def test_train_profile_is_the_spans_durations(warm_train):
     est, spans = warm_train
     _, named = _tree(spans)
     tp = est.model.output["train_profile"]
-    assert set(tp) == {"bin_s", "sketch_s", "digitize_s", "pack_s", "loop_s",
-                       "score_s", "finalize_s", "queue_s", "spec_s",
-                       "total_s", "other_s"}
+    assert set(tp) == {"bin_s", "sketch_s", "digitize_s", "pack_s", "init_s",
+                       "loop_s", "score_s", "finalize_s", "queue_s",
+                       "spec_s", "total_s", "other_s"}
 
     def seconds(name):
         return sum(s.duration_s for s in named[name])
 
-    for key, name in (("bin_s", "train.bin"), ("loop_s", "train.loop"),
+    for key, name in (("bin_s", "train.bin"), ("init_s", "train.init"),
+                      ("loop_s", "train.loop"),
                       ("score_s", "train.score"),
                       ("finalize_s", "train.finalize"),
                       ("spec_s", "train.spec"), ("queue_s", "train.queue"),
@@ -145,8 +149,9 @@ def test_train_profile_is_the_spans_durations(warm_train):
     assert tp["bin_s"] - parts <= 0.05 * tp["bin_s"] + 2e-3
     assert tp["other_s"] >= 0.0
     assert tp["total_s"] >= seconds("train.gbm")
-    stages = sum(tp[k] for k in ("queue_s", "spec_s", "bin_s", "loop_s",
-                                 "finalize_s", "other_s"))
+    # what _close_train_profile subtracts, init_s among it, and what is left
+    stages = sum(tp[k] for k in ("queue_s", "spec_s", "bin_s", "init_s",
+                                 "loop_s", "finalize_s", "other_s"))
     assert stages == pytest.approx(tp["total_s"], abs=1e-3)
 
 
@@ -381,6 +386,44 @@ def test_the_sketch_and_loop_spans_say_where_edges_were_made_and_what_crossed(
     assert 'h2o3_collective_bytes_total{algo="gbm",op="psum"}' in exposition
 
 
+BOOT = r"""
+import json
+import h2o3_tpu as h2o
+from h2o3_tpu import telemetry
+h2o.init()
+print(json.dumps([[s.name, s.span_id, s.parent_id, s.t_wall, s.duration_s]
+                  for s in telemetry.finished_spans()]))
+"""
+
+
+def test_a_process_boots_under_two_root_spans_once():
+    """Root ``boot.import`` (the package's import, first line to last)
+    and root ``boot.init`` around ``h2o3_tpu.init``: one of each in a new
+    process, the ring's earliest spans (ISSUE 37)."""
+    import json
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", H2O3_TELEMETRY="1",
+               PYTHONPATH=repo)
+    done = subprocess.run([sys.executable, "-c", BOOT], env=env, cwd=repo,
+                          capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    spans = json.loads(done.stdout.strip().splitlines()[-1])
+    by_name = {}
+    for name, _span_id, parent_id, t_wall, seconds in spans:
+        by_name.setdefault(name, []).append((parent_id, t_wall, seconds))
+    assert {n: len(v) for n, v in by_name.items()
+            if n.startswith("boot.")} == {"boot.import": 1, "boot.init": 1}
+    (imp_parent, imp_start, imp_s), = by_name["boot.import"]
+    (init_parent, init_start, init_s), = by_name["boot.init"]
+    assert imp_parent == 0 and init_parent == 0
+    assert imp_s > 0 and init_s > 0
+    # the import is the ring's earliest span and is over when init starts
+    assert imp_start == min(row[3] for row in spans)
+    assert imp_start + imp_s <= init_start + 1e-3
+
+
 def _jit_spans(stage, parent=None):
     return [s for s in telemetry.finished_spans()
             if s.name == f"jit.{stage}"
@@ -418,6 +461,103 @@ def test_reports_under_one_span_fold_into_one_child_counted_once():
         "count": 2, "seconds": 7.0}
 
 
+def test_a_folded_child_names_its_five_longest_labels():
+    """``top`` (ISSUE 37): the up to five ``[label, seconds]`` of most
+    seconds among the reports a folded child kept, a label's reports
+    summed; a report inside a later one is counted in ``n`` and not in
+    the seconds nor in ``top``; reports without a label leave no
+    ``top``."""
+    from h2o3_tpu.telemetry.spans import TOP_LABELS, fold_span
+    telemetry.clear_spans()
+    with telemetry.span("t.top"):
+        fold_span("t.top.x", 10.0, 0.5, label="inner")
+        fold_span("t.top.x", 9.0, 2.0, label="outer")      # holds inner
+        for i in range(7):
+            fold_span("t.top.x", 20.0 + i, 0.1 * (i + 1), label=f"f{i}")
+        fold_span("t.top.x", 30.0, 0.25, label="f0")        # f0 again
+        fold_span("t.top.x", 31.0, 4.0)                     # no label
+        fold_span("t.top.y", 40.0, 1.0)
+    x, y, _ = telemetry.finished_spans()
+    assert x.attrs["n"] == 11
+    assert x.duration_s == pytest.approx(2.0 + 2.8 + 0.25 + 4.0)
+    top = x.attrs["top"]
+    assert TOP_LABELS == 5 and len(top) == 5
+    assert [name for name, _ in top] == ["outer", "f6", "f5", "f4", "f3"]
+    assert [sec for _, sec in top] == pytest.approx([2.0, 0.7, 0.6, 0.5, 0.4])
+    assert y.attrs == {"n": 1}
+    # under no span a labelled report is a span of its own, and named
+    fold_span("t.top.x", 50.0, 2.0, label="alone")
+    assert telemetry.finished_spans()[-1].attrs == {
+        "n": 1, "top": [["alone", 2.0]]}
+
+
+def test_a_jit_cache_miss_names_the_program_it_built():
+    """A miss under an open span leaves ``jit.trace``, ``jit.lower`` and
+    ``jit.build`` whose ``top`` names the jitted function and whose
+    seconds are the span's (ISSUE 37); JAX hands the name over with the
+    event (``fun_name``)."""
+    salt = float(time.time_ns() % 1_000_003)    # a program no run has cached
+
+    @jax.jit
+    def salted_miss(x):
+        return jnp.cos(x) * salt - jnp.cumsum(x)
+
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    x = jnp.arange(8, dtype=jnp.float32)
+    telemetry.clear_spans()
+    try:
+        with telemetry.span("t.miss") as parent:
+            salted_miss(x)
+        with telemetry.span("t.hit") as again:
+            salted_miss(x)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          floor)
+    for stage in ("trace", "lower", "build"):
+        sp, = _jit_spans(stage, parent)
+        names = [name for name, _ in sp.attrs["top"]]
+        assert any("salted_miss" in name for name in names), (stage, names)
+        assert len(names) <= 5
+        assert sum(sec for _, sec in sp.attrs["top"]) == pytest.approx(
+            sp.duration_s)
+    built, = _jit_spans("build", parent)
+    assert built.attrs["n"] == 1 and len(built.attrs["top"]) == 1
+    assert not _jit_spans("load", parent)
+    # a warm dispatch fires none of these events
+    assert not [s for s in telemetry.finished_spans()
+                if s.parent_id == again.span_id]
+
+
+def test_a_cost_capture_after_the_dispatch_lowers_nothing_anew():
+    """What gbm's ``chunk_lowering`` leans on, read on the v5e in PR 37
+    (2 ms a chunk key, ``jit.lower`` ``n`` 1): asked for the ``Lowered``
+    of a step it has just dispatched, by shape, JAX serves its own
+    lowering cache; the capture leaves no ``jit.lower`` and no
+    ``jit.build`` behind."""
+    from functools import partial
+    from h2o3_tpu.telemetry import costmodel
+
+    @jax.jit
+    def captured_step(x, y):
+        return jnp.dot(x, y).sum()
+
+    x = jnp.ones((32, 32), jnp.float32)
+    telemetry.clear_spans()
+    with telemetry.span("t.dispatch") as dispatch:
+        captured_step(x, x)
+    with telemetry.span("t.capture") as capture:
+        shape = jax.ShapeDtypeStruct(x.shape, x.dtype)
+        cost = costmodel.lowered_cost(
+            partial(captured_step.lower, shape, shape))
+    assert cost is not None and cost.flops > 0      # the CPU gives costs
+    lowered, = _jit_spans("lower", dispatch)
+    assert lowered.attrs["n"] == 1
+    assert not _jit_spans("lower", capture)
+    assert not _jit_spans("build", capture) and not _jit_spans("load",
+                                                               capture)
+
+
 def test_an_unjitted_scan_traces_on_every_call_a_jitted_one_once():
     def scan_sum(xs):
         def body(c, x):     # a new closure a call, as the scorer's one_tree
@@ -444,7 +584,9 @@ def test_an_unjitted_scan_traces_on_every_call_a_jitted_one_once():
         jitted(xs)
     traced, = _jit_spans("trace", first)    # scan_sum and traces inside it
     assert traced.attrs["n"] >= 1
-    assert _jit_spans("lower", first)[0].attrs == {"n": 1}  # one program
+    lowered, = _jit_spans("lower", first)           # one program, by name
+    assert lowered.attrs == {"n": 1,
+                             "top": [["jit(scan_sum)", lowered.duration_s]]}
     assert not [s for s in telemetry.finished_spans()
                 if s.parent_id == again.span_id]
     # what /metrics exports of the stage is the spans' seconds
@@ -503,6 +645,12 @@ def test_a_persistent_cache_hit_is_a_load_not_a_build():
     np.testing.assert_array_equal(first, again)
     assert events(cold) == {"build": 1, "load": 0}
     assert events(cached) == {"build": 0, "load": 1}
+    # JAX reports a retrieval without a name: the load takes the name of the
+    # backend_compile_duration event that closes around it (ISSUE 37)
+    for stage, parent in (("build", cold), ("load", cached)):
+        sp, = _jit_spans(stage, parent)
+        (name, seconds), = sp.attrs["top"]
+        assert "salted" in name and seconds == sp.duration_s
     # the old counter counts both, as its help text now says
     assert telemetry.registry().value("h2o3_xla_compiles_total") \
         - compiles == 2
@@ -552,7 +700,9 @@ def test_telemetry_off_leaves_ring_histograms_and_trace_empty(
     est, _ = warm_train
 
     def jit_events():
-        return telemetry.stage_seconds("jit.")
+        return {**telemetry.stage_seconds("jit."),
+                **telemetry.stage_seconds("boot."),
+                **telemetry.stage_seconds("train.")}
 
     telemetry.clear_spans()
     before = jit_events()
@@ -562,6 +712,21 @@ def test_telemetry_off_leaves_ring_histograms_and_trace_empty(
         try:
             with telemetry.span("t.off") as sp:
                 est.model.predict(frame)        # retraces its scan
+            # what ISSUE 37 added: the boot spans, train.init, a labelled
+            # report and the import span's own call
+            from h2o3_tpu.parallel.mesh import current_mesh, set_mesh
+            from h2o3_tpu.telemetry.spans import fold_span
+            mesh = current_mesh()
+            try:
+                h2o.init(n_data=mesh.shape["data"],
+                         n_model=mesh.shape["model"])
+            finally:
+                set_mesh(mesh)
+            h2o._record_import_span()
+            fold_span("jit.build", time.time(), 1.0, label="t.off.program")
+            H2OGradientBoostingEstimator(
+                ntrees=2, max_depth=2, seed=1, packed_codes=True).train(
+                    y="y", training_frame=frame)
         finally:
             jax.profiler.stop_trace()
         assert sp is None
@@ -570,4 +735,5 @@ def test_telemetry_off_leaves_ring_histograms_and_trace_empty(
     assert telemetry.finished_spans() == []
     assert jit_events() == before
     names = _host_event_names(str(tmp_path))
-    assert not any(n.startswith(("score.", "jit.", "t.off")) for n in names)
+    assert not any(n.startswith(("score.", "jit.", "t.off", "boot.",
+                                 "train.")) for n in names)
