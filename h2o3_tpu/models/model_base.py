@@ -412,9 +412,10 @@ class Model:
         'predict' + one prob column per class.
 
         Spanned at the boundaries where the work stops: ``score.adapt``
-        (host), ``score.dispatch`` (trace, lower, load and enqueue: it
-        returns before the device is done; attrs ``_score_attrs``: which
-        form the scorer's program has), ``score.fetch`` (the wait
+        (host), ``score.dispatch`` (the enqueue of programs JAX has
+        cached, and on a shape's first call their trace, lower and load:
+        it returns before the device is done; attrs ``_score_attrs``:
+        which form the scorer's program has), ``score.fetch`` (the wait
         for the device and the D2H) and ``score.frame`` (host, and the
         result columns' uploads)."""
         with _tel.span("score.predict", rows=frame.nrow, model=self.key):

@@ -1831,7 +1831,9 @@ def _block_step(XT, feat, thr, right_if_na, is_split, low, cat_set=None,
     nodes (tables [B], ``feat`` clipped at 0): each node's predicate over
     ALL rows from scalars of its tables and one contiguous column of
     ``XT``, then the row's own selected on ``low``'s bits. Plain ``lax``
-    calls: the body is traced again on every eager call. With ``cat_set``
+    calls where ``jnp``'s would do: a nested ``jnp`` wrapper is a trace of
+    its own, and a level unrolls up to _PREDICATE_BLOCK of these when the
+    scorer's program is traced. With ``cat_set``
     [B, n] / ``is_set`` [B] a node's predicate is the set's where the
     node splits on one: the word selected on the level's high bits, its
     bit tested, no per-row index."""
@@ -1889,32 +1891,15 @@ def _score_tree_predicates(XT, feat, thr, na_left, is_split, value,
     return node_lookup(value, nid)
 
 
-def predict_raw_stacked(X, feat, thr, na_left, is_split, value, max_depth: int,
-                        cat_set=None, is_set=None):
-    """Scoring-time prediction on raw features for a stack of T trees.
-
-    feat/thr/... are [T, M]; X is [rows, F] float32 with NaN=NA.
-    Returns [rows, T] per-tree contributions; caller sums/weights.
-    The score0 analog (hex/Model.java:2304, GBM: walk CompressedTrees)
-    vectorized over rows, one tree a scan step.
-
-    Up to SCORER_PREDICATE_MAX nodes and from SCORER_ROWS_PER_NODE rows a
-    node the descent looks nothing up by row (``_score_tree_predicates``
-    on the transposed matrix, a bitcast under the TPU's layout of a
-    narrow matrix): a level's ``X[r, feat[nid[r]]]`` is an element
-    gather, 5-11 ms at 500k rows on the v5e, 88% of a predict's device
-    time (PERF.md §6, PR 32). Larger trees (DRF's deep heaps) and small
-    batches (the serving buckets) keep the gathers, 5 a level and one for
-    the value, whose cost has no per-node part. The rule
-    (``scorer_node_form``) reads static shapes; both forms give the same
-    bits.
-
-    ``cat_set`` [T, M, n] uint32 and ``is_set`` [T, M] (a model with
-    category-set splits, GBM on enum columns): a row at a node with
-    ``is_set`` goes left iff the bit of its level (the enum column's
-    value) is on in the node's words, and where NA goes if the value is
-    NA or past the words. Without them the program is the numeric one."""
-    if scorer_node_form(feat.shape[1], X.shape[0]) == "predicate":
+@partial(jax.jit, static_argnames=("max_depth", "node_form"))
+def _score_stack(X, feat, thr, na_left, is_split, value, cat_set, is_set, *,
+                 max_depth: int, node_form: str):
+    """``predict_raw_stacked``'s program: one tree a scan step, the descent
+    in ``node_form``. The tables and ``X`` are arguments, so the program
+    is keyed on shapes, depth and form alone: every model with tables of
+    one shape (a grid's, AutoML's, a checkpoint's prior) runs one
+    executable, and the persistent compile cache's key holds no weights."""
+    if node_form == "predicate":
         Xs, score_tree = X.T, _score_tree_predicates
     else:
         Xs, score_tree = X, _score_tree_gather
@@ -1927,6 +1912,45 @@ def predict_raw_stacked(X, feat, thr, na_left, is_split, value, max_depth: int,
 
     _, contribs = lax.scan(one_tree, None, jnp.arange(feat.shape[0]))
     return contribs.T  # [rows, T]
+
+
+def predict_raw_stacked(X, feat, thr, na_left, is_split, value, max_depth: int,
+                        cat_set=None, is_set=None):
+    """Scoring-time prediction on raw features for a stack of T trees.
+
+    feat/thr/... are [T, M]; X is [rows, F] float32 with NaN=NA.
+    Returns [rows, T] per-tree contributions; caller sums/weights.
+    The score0 analog (hex/Model.java:2304, GBM: walk CompressedTrees)
+    vectorized over rows, one tree a scan step, in ONE jitted program
+    (``_score_stack``) that JAX caches on the shapes it sees: the first
+    call of a shape traces, lowers and loads it, every later one (of this
+    model or of any other with tables of that shape) is a cache hit.
+    Each distinct padded row count of ``X`` is a program of its own.
+    Under a caller's ``jax.jit`` (serving's bucket executables) it is
+    inlined.
+
+    Up to SCORER_PREDICATE_MAX nodes and from SCORER_ROWS_PER_NODE rows a
+    node the descent looks nothing up by row (``_score_tree_predicates``
+    on the transposed matrix, a bitcast under the TPU's layout of a
+    narrow matrix): a level's ``X[r, feat[nid[r]]]`` is an element
+    gather, 5-11 ms at 500k rows on the v5e, 88% of a predict's device
+    time (PERF.md §6, PR 32). Larger trees (DRF's deep heaps) and small
+    batches (the serving buckets) keep the gathers, 5 a level and one for
+    the value, whose cost has no per-node part. The rule
+    (``scorer_node_form``) reads static shapes and is read HERE, on every
+    call, and handed to the program as a static argument: the form is
+    part of the program's key, not of whatever a shape first compiled.
+    Both forms give the same bits.
+
+    ``cat_set`` [T, M, n] uint32 and ``is_set`` [T, M] (a model with
+    category-set splits, GBM on enum columns): a row at a node with
+    ``is_set`` goes left iff the bit of its level (the enum column's
+    value) is on in the node's words, and where NA goes if the value is
+    NA or past the words. Without them the program is the numeric one."""
+    return _score_stack(
+        X, feat, thr, na_left, is_split, value, cat_set, is_set,
+        max_depth=max_depth,
+        node_form=scorer_node_form(feat.shape[1], X.shape[0]))
 
 
 def bins_to_thresholds(tree_split_bin: np.ndarray, tree_feat: np.ndarray,

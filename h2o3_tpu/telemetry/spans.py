@@ -370,8 +370,8 @@ def fold_span(name: str, start_wall: float, duration_s: float,
     """Fold an already-measured interval into ONE child span ``name`` of
     the calling thread's current span. The child is written when that
     span finishes, with the intervals' summed seconds and their number as
-    attr ``n``, so a report that comes by the hundred (an un-jitted scan
-    traces 137 times a predict) costs the ring one entry a parent. An
+    attr ``n``, so a report that comes by the hundred (a boost chunk's
+    trace holds 1,534 nested ones) costs the ring one entry a parent. An
     interval that holds earlier ones of its name replaces them in the
     sum: JAX reports an outer function's trace after the inner traces it
     contains, and the seconds are host time, counted once. ``label``

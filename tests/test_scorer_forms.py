@@ -3,7 +3,11 @@ whole columns, selected on the node id's bits, where it gathered five things
 a level by row (PR 32). Its [rows, T] contributions have to equal the
 gather form's bit for bit at every depth on both sides of the rule
 (``tree.scorer_node_form``: by the tree's size and by the rows), and a
-model's prediction frame with them.
+model's prediction frame with them. Since PR 38 the scan is one jitted
+program (``tree._score_stack``) keyed on shapes, depth and form, with the
+tables as arguments: a warm predict compiles and traces nothing, a second
+model of the same shape runs the first's program, and the form stays part
+of the key.
 
 ``reference`` is the gather form as the scorer had it before PR 32, kept
 here so that the comparison does not lean on the code under test.
@@ -14,6 +18,7 @@ import numpy as np
 import pytest
 
 import h2o3_tpu as h2o
+from h2o3_tpu import telemetry
 from h2o3_tpu.models import tree
 from h2o3_tpu.models.tree import (SCORER_PREDICATE_MAX, SCORER_ROWS_PER_NODE,
                                   predict_raw_stacked, scorer_node_form)
@@ -182,6 +187,9 @@ def _estimator(algo, depth):
         return H2OXGBoostEstimator(
             ntrees=5, max_depth=depth, seed=32, distribution="bernoulli",
             tree_method="hist", max_bins=64)
+    if algo == "gbm-sets":        # packed: enums split on sets of levels
+        return H2OGradientBoostingEstimator(
+            ntrees=5, max_depth=depth, seed=32, min_rows=5, packed_codes=True)
     return H2ORandomForestEstimator(ntrees=5, max_depth=depth, seed=32)
 
 
@@ -205,3 +213,119 @@ def test_a_models_prediction_frame_is_the_gather_forms(monkeypatch, algo,
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(a.view(np.int32), b.view(np.int32),
                                       err_msg=name)
+
+
+@pytest.fixture
+def spans_on():
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    telemetry.install()
+    yield
+    telemetry.set_enabled(was)
+
+
+def _set_frame():
+    """An enum column whose levels' effects are not monotone in the level
+    index beside a numeric one, as ``tests/test_set_splits.py`` has them:
+    a packed GBM splits the enum on sets of levels."""
+    from h2o3_tpu.frame.vec import T_ENUM, Vec
+    rng = np.random.default_rng(38)
+    n = 9000
+    c = rng.integers(0, 40, n)
+    x = rng.standard_normal(n).astype(np.float32)
+    y = (rng.standard_normal(40)[c] + 0.5 * x
+         + 0.3 * rng.standard_normal(n)) > 0
+    return h2o.Frame(["c", "x", "resp"], [
+        Vec.from_numpy(c, T_ENUM, [f"k{i}" for i in range(40)]),
+        Vec.from_numpy(x),
+        Vec.from_numpy(y.astype(np.int32), T_ENUM, ["a", "b"])])
+
+
+def _tables(model):
+    return {k: getattr(model, k) for k in (
+        "_feat", "_thr", "_na_left", "_is_split", "_value", "_cat_set",
+        "_is_set") if getattr(model, k, None) is not None}
+
+
+def _predict_counted(model, fr):
+    """(the prediction frame, executables compiled or loaded meanwhile,
+    the ``jit.*`` spans the predict left)."""
+    compiles = telemetry.registry().value("h2o3_xla_compiles_total")
+    telemetry.clear_spans()
+    pred = model.predict(fr)
+    return (pred,
+            telemetry.registry().value("h2o3_xla_compiles_total") - compiles,
+            [s.name for s in telemetry.finished_spans()
+             if s.name.startswith("jit.")])
+
+
+@pytest.mark.parametrize("algo,depth", [("gbm", 5), ("xgboost", 6),
+                                        ("drf", 8), ("gbm-sets", 4)])
+def test_a_warm_predict_compiles_and_traces_nothing(spans_on, algo, depth):
+    """The scorer's program is JAX's to cache: the second predict of a
+    frame is a hit (no executable, no ``jit.trace``), and so is the FIRST
+    predict of a second model whose tables have the first's shapes, since
+    the tables are arguments of the program and not constants in it."""
+    def trained(second):
+        # a second model of the same shapes and other numbers: another
+        # step size where trees are boosted, another bootstrap in a forest
+        est = _estimator(algo, depth)
+        if second:
+            est.params.update({"seed": 33} if algo == "drf" else
+                              {"learn_rate": 0.2})
+        est.train(y="resp", training_frame=fr)
+        return est.model
+
+    fr = _set_frame() if algo == "gbm-sets" else _frame()
+    one = trained(False)
+    if algo == "gbm-sets":
+        assert int(np.asarray(one._is_set).sum()) > 0
+    first, _, _ = _predict_counted(one, fr)       # the shape's first call
+    again, compiles, jit = _predict_counted(one, fr)
+    assert (compiles, jit) == (0, [])
+    other = trained(True)
+    t1, t2 = _tables(one), _tables(other)
+    assert {k: (v.shape, v.dtype) for k, v in t1.items()} == {
+        k: (v.shape, v.dtype) for k, v in t2.items()}
+    assert not np.array_equal(np.asarray(t1["_value"]),
+                              np.asarray(t2["_value"]))
+    theirs, compiles, jit = _predict_counted(other, fr)
+    assert (compiles, jit) == (0, [])
+    # one program, each model's own numbers through it
+    for a, b, same in ((first, again, True), (first, theirs, False)):
+        pa, pb = (np.asarray(p.vec(p.names[-1]).data)[:fr.nrow]
+                  for p in (a, b))
+        assert np.array_equal(pa, pb) == same
+
+
+def test_the_form_is_part_of_the_programs_key(spans_on, monkeypatch):
+    """The rule is read outside the jitted program on every call and
+    handed in as a static argument: with the bound at 0 between two calls
+    of ONE shape the second is a program of its own, which gathers, and
+    not the predicates the shape first compiled; the bits are equal."""
+    depth = 5
+    rows = _n_rows(depth, "predicate")
+    rng = np.random.default_rng(38)
+    args = [jnp.asarray(a) for a in (_rows(rng, rows),
+                                     *_stack(rng, 3, depth).values())]
+
+    def program():
+        return str(jax.make_jaxpr(
+            lambda *a: predict_raw_stacked(*a, depth))(*args))
+
+    def run(name):
+        telemetry.clear_spans()
+        with telemetry.span(name):
+            out = np.asarray(predict_raw_stacked(*args, depth))
+        return out.view(np.int32), [s.name for s in telemetry.finished_spans()
+                                    if s.name == "jit.trace"]
+
+    by_predicates, traced = run("t.predicates")
+    assert traced and "gather" not in program()
+    again, traced = run("t.predicates.again")
+    assert not traced
+    np.testing.assert_array_equal(again, by_predicates)
+    monkeypatch.setattr(tree, "SCORER_PREDICATE_MAX", 0)
+    by_gathers, traced = run("t.gathers")
+    assert traced and "gather" in program()
+    np.testing.assert_array_equal(by_gathers, by_predicates)

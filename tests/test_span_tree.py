@@ -189,7 +189,17 @@ def test_a_packed_drf_train_carries_the_bin_spans_and_gbms_record(
 
 def test_a_predict_leaves_its_four_children(frame, warm_train):
     est, _ = warm_train
-    est.model.predict(frame)            # the ops outside the scan compile
+    telemetry.clear_spans()
+    est.model.predict(frame)            # the shape's first: programs compile
+    # what the host pays once a shape is under the span that was open, by
+    # name: the scorer's program under score.dispatch, one span a stage
+    # however many events it folds
+    dispatch, = _tree(telemetry.finished_spans())[1]["score.dispatch"]
+    traced, = _jit_spans("trace", dispatch)
+    lowered, = _jit_spans("lower", dispatch)
+    assert traced.attrs["n"] > 1
+    assert any("_score_stack" in name for name, _ in lowered.attrs["top"])
+    assert 0 < traced.duration_s + lowered.duration_s <= dispatch.duration_s
     telemetry.clear_spans()
     pred = est.model.predict(frame)
     assert pred.nrow == ROWS
@@ -203,16 +213,10 @@ def test_a_predict_leaves_its_four_children(frame, warm_train):
     total = sum(named[k][0].duration_s for k in kids)
     assert total <= root.duration_s
     assert total == pytest.approx(root.duration_s, rel=0.05)
-    # what the host pays on every call is under score.dispatch, by name:
-    # one span a stage, however many events it folds
-    jit = {n: p for n, p in parents.items() if n.startswith("jit.")}
-    assert "jit.trace" in jit and "jit.lower" in jit
-    assert all(p == ["score.dispatch"] for p in jit.values()), jit
-    assert named["jit.trace"][0].attrs["n"] > 1
-    # so a predict costs the ring its five spans and one a stage it paid
-    assert len(telemetry.finished_spans()) <= 5 + 4
-    in_jit = sum(named[n][0].duration_s for n in jit)
-    assert 0 < in_jit <= named["score.dispatch"][0].duration_s
+    # a warm predict is cache hits all through (PR 38): it costs the ring
+    # its five spans and no jit.* span
+    assert sorted(parents) == sorted(("score.predict",) + kids)
+    assert len(telemetry.finished_spans()) == 5
 
 
 @pytest.mark.parametrize("form", ["predicate", "gather"])
@@ -560,7 +564,7 @@ def test_a_cost_capture_after_the_dispatch_lowers_nothing_anew():
 
 def test_an_unjitted_scan_traces_on_every_call_a_jitted_one_once():
     def scan_sum(xs):
-        def body(c, x):     # a new closure a call, as the scorer's one_tree
+        def body(c, x):     # a new closure a call
             return jax.lax.add(c, x), c
         return jax.lax.scan(body, jnp.float32(0), xs)
 
@@ -711,7 +715,7 @@ def test_telemetry_off_leaves_ring_histograms_and_trace_empty(
         _harness_trace(str(tmp_path))
         try:
             with telemetry.span("t.off") as sp:
-                est.model.predict(frame)        # retraces its scan
+                est.model.predict(frame)
             # what ISSUE 37 added: the boot spans, train.init, a labelled
             # report and the import span's own call
             from h2o3_tpu.parallel.mesh import current_mesh, set_mesh
