@@ -21,8 +21,8 @@ L1/L2, UniformAdaptive init.
 from __future__ import annotations
 
 import time
-from functools import partial
-from typing import Dict, List, Optional
+from functools import lru_cache
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +98,21 @@ def _forward(params, x, act, drop_key=None, in_drop=0.0, hid_drops=None):
     return h
 
 
+# rows a forward pass over a frame takes at a time: one hidden layer's
+# activations over 10M rows are 8 GB ([rows, 200] float32), a block's 0.84
+_FORWARD_BLOCK = 1 << 20
+
+
+def _forward_rows(params, X, act):
+    """``_forward`` over every row of a frame, ``_FORWARD_BLOCK`` rows at a
+    time: the same products row by row, without any layer's activations
+    for all the rows at once."""
+    if X.shape[0] <= _FORWARD_BLOCK:
+        return _forward(params, X, act)
+    return jnp.concatenate([_forward(params, X[lo:lo + _FORWARD_BLOCK], act)
+                            for lo in range(0, X.shape[0], _FORWARD_BLOCK)])
+
+
 def _loss_fn(out, y, w, task, dist_name):
     if task == "autoencoder":
         # reconstruction MSE over the standardized inputs (y = Xs batch)
@@ -125,35 +140,74 @@ def _init_opt(net, adaptive: bool):
             else (zeros_like_params(net),))
 
 
-from functools import lru_cache  # noqa: E402
+class _StepConfig(NamedTuple):
+    """What one optimizer step computes, from the parameters alone: the
+    network's sizes, activation, loss, penalties, dropout and optimizer.
+    Hashable: it keys the compiled step and epoch programs."""
+    sizes: tuple
+    act_name: str
+    task: str
+    dist_name: str
+    l1: float
+    l2: float
+    in_drop: float
+    hid_drops: tuple
+    use_dropout: bool
+    adaptive: bool
+    rho: float
+    eps: float
+    rate0: float
+    annealing: float
+    mom_start: float
+    mom_ramp: float
+    mom_stable: float
+
+
+def _step_config(p: Dict, sizes, act_name: str, task: str,
+                 dist_name: str) -> _StepConfig:
+    hidden = list(sizes[1:-1])
+    in_drop = float(p.get("input_dropout_ratio", 0.0))
+    hid_drops = p.get("hidden_dropout_ratios")
+    if hid_drops is None:
+        hid_drops = ([0.5] * len(hidden) if act_name.endswith("_dropout")
+                     else [0.0] * len(hidden))
+    hid_drops = tuple(float(d) for d in hid_drops)
+    return _StepConfig(
+        tuple(int(s) for s in sizes), act_name, task, dist_name,
+        float(p.get("l1", 0.0)), float(p.get("l2", 0.0)), in_drop,
+        hid_drops, in_drop > 0 or any(d > 0 for d in hid_drops),
+        bool(p.get("adaptive_rate", True)), float(p.get("rho", 0.99)),
+        float(p.get("epsilon", 1e-8)), float(p.get("rate", 0.005)),
+        float(p.get("rate_annealing", 1e-6)),
+        float(p.get("momentum_start", 0.0)),
+        max(float(p.get("momentum_ramp", 1e6)), 1.0),
+        float(p.get("momentum_stable", 0.0)))
 
 
 @lru_cache(maxsize=64)
-def _compiled_epoch(sizes, act_name, task, dist_name, l1, l2, in_drop,
-                    hid_drops, use_dropout, adaptive, rho, eps, rate0,
-                    annealing, mom_start, mom_ramp, mom_stable, batch,
-                    n_batches, use_rows, padded, shuffle):
-    """Build + cache the jitted epoch for a static config. Data rides as
-    ARGUMENTS: a closure over the design matrix bakes it into the program
-    as a constant (~90s XLA compile at MNIST shape), and a fresh closure
-    per estimator re-pays the compile every train."""
-    act = _ACTS[act_name]
+def _step_body(cfg: _StepConfig):
+    """One optimizer step on one batch: ``(params, opt, samples, xb, yb,
+    wb, key) -> (params, opt, samples + rows, loss)``. The epoch's scan
+    runs it as its body; ``compiled_step`` hands it out alone."""
+    act = _ACTS[cfg.act_name]
 
     def loss(params, xb, yb, wb, dkey):
         out = _forward(params, xb, act,
-                       drop_key=dkey if use_dropout else None,
-                       in_drop=in_drop, hid_drops=list(hid_drops))
-        l = _loss_fn(out, yb, wb, task, dist_name)
-        if l2 > 0:
-            l = l + l2 * sum((layer["W"] ** 2).sum() for layer in params)
-        if l1 > 0:
-            l = l + l1 * sum(jnp.abs(layer["W"]).sum() for layer in params)
+                       drop_key=dkey if cfg.use_dropout else None,
+                       in_drop=cfg.in_drop, hid_drops=list(cfg.hid_drops))
+        l = _loss_fn(out, yb, wb, cfg.task, cfg.dist_name)
+        if cfg.l2 > 0:
+            l = l + cfg.l2 * sum((layer["W"] ** 2).sum() for layer in params)
+        if cfg.l1 > 0:
+            l = l + cfg.l1 * sum(jnp.abs(layer["W"]).sum()
+                                 for layer in params)
         return l
 
     grad_fn = jax.value_and_grad(loss)
+    rho, eps = cfg.rho, cfg.eps
 
     def sgd_update(params, opt, grads, samples):
-        if adaptive:
+        if cfg.adaptive:
             # ADADELTA (hex/deeplearning adaptive_rate default)
             Eg, Ed = opt
             new_p, nEg, nEd = [], [], []
@@ -172,10 +226,10 @@ def _compiled_epoch(sizes, act_name, task, dist_name, l1, l2, in_drop,
             return new_p, (nEg, nEd)
         # momentum SGD with annealing + ramp
         vel, = opt
-        lr = rate0 / (1.0 + annealing * samples)
-        mom = jnp.where(samples < mom_ramp,
-                        mom_start + (mom_stable - mom_start)
-                        * samples / mom_ramp, mom_stable)
+        lr = cfg.rate0 / (1.0 + cfg.annealing * samples)
+        mom = jnp.where(samples < cfg.mom_ramp,
+                        cfg.mom_start + (cfg.mom_stable - cfg.mom_start)
+                        * samples / cfg.mom_ramp, cfg.mom_stable)
         new_p, nv = [], []
         for layer, g, v in zip(params, grads, vel):
             upd, uv = {}, {}
@@ -185,6 +239,40 @@ def _compiled_epoch(sizes, act_name, task, dist_name, l1, l2, in_drop,
             new_p.append(upd)
             nv.append(uv)
         return new_p, (nv,)
+
+    def step(params, opt, samples, xb, yb, wb, bkey):
+        l, grads = grad_fn(params, xb, yb, wb, bkey)
+        params, opt = sgd_update(params, opt, grads, samples)
+        return params, opt, samples + xb.shape[0], l
+
+    return step
+
+
+@lru_cache(maxsize=64)
+def _jitted_step(cfg: _StepConfig):
+    return jax.jit(_step_body(cfg))
+
+
+def compiled_step(model: "DeepLearningModel"):
+    """The optimizer step a train of ``model``'s parameters runs, compiled
+    alone: the body of the epoch's scan, for a caller that follows a few
+    steps from a given state (the benchmark's check). Arguments and
+    result as ``_step_body`` says; ``opt`` is ``model.optimizer_state``'s
+    layout."""
+    sizes = ([int(model.net[0]["W"].shape[0])]
+             + [int(ly["W"].shape[1]) for ly in model.net])
+    return _jitted_step(_step_config(model.params, sizes, model.activation,
+                                     model.task, model.dist_name))
+
+
+@lru_cache(maxsize=64)
+def _compiled_epoch(cfg: _StepConfig, batch, n_batches, use_rows, padded,
+                    shuffle):
+    """Build + cache the jitted epoch for a static config. Data rides as
+    ARGUMENTS: a closure over the design matrix bakes it into the program
+    as a constant (~90s XLA compile at MNIST shape), and a fresh closure
+    per estimator re-pays the compile every train."""
+    step = _step_body(cfg)
 
     @jax.jit
     def run_epoch(params, opt, samples, ekey, Xs, y, w, shift):
@@ -208,9 +296,9 @@ def _compiled_epoch(sizes, act_name, task, dist_name, l1, l2, in_drop,
             yb = jax.lax.dynamic_slice_in_dim(yp, i * batch, batch)
             wb = jax.lax.dynamic_slice_in_dim(wp, i * batch, batch)
             bkey = jax.random.fold_in(dkey, i)
-            l, grads = grad_fn(params, xb, yb, wb, bkey)
-            params, opt = sgd_update(params, opt, grads, samples)
-            return (params, opt, samples + batch), l
+            params, opt, samples, l = step(params, opt, samples, xb, yb, wb,
+                                           bkey)
+            return (params, opt, samples), l
 
         (params, opt, samples), losses = jax.lax.scan(
             one_batch, (params, opt, samples), jnp.arange(n_batches))
@@ -234,13 +322,16 @@ class DeepLearningModel(Model):
         self.dist_name = dist_name
         self.hidden = list(hidden)
         self.activation = activation
+        # ADADELTA's accumulators (or the momentum) as the last step left
+        # them, on the device; a restored model has none
+        self.optimizer_state = None
 
     def _predict_matrix(self, X, offset=None):
         from h2o3_tpu.models.glm import expand_scoring_matrix
         Xe = expand_scoring_matrix(self, X)
         Xs = (Xe - jnp.asarray(self.xm)[None, :]) / jnp.asarray(self.xs)[None, :]
         act = _ACTS[self.activation]
-        out = _forward(self.net, Xs, act)
+        out = _forward_rows(self.net, Xs, act)
         if self.task == "autoencoder":
             return out                    # standardized reconstruction
         if self.task == "classification":
@@ -282,7 +373,7 @@ class DeepLearningModel(Model):
         Xe = expand_scoring_matrix(self, X)
         Xs = (Xe - jnp.asarray(self.xm)[None, :]) / \
             jnp.asarray(self.xs)[None, :]
-        out = _forward(self.net, Xs, _ACTS[self.activation])
+        out = _forward_rows(self.net, Xs, _ACTS[self.activation])
         err = (out - Xs) ** 2
         if per_feature:
             E = np.asarray(telemetry.device_get(
@@ -322,6 +413,7 @@ class DeepLearningModel(Model):
         m.dist_name = ex["dist_name"]
         m.hidden = list(ex["hidden"])
         m.activation = ex["activation"]
+        m.optimizer_state = None
         m.xm = arrays["xm"]
         m.xs = arrays["xs"]
         m.impute_means = unpack_impute_means(arrays)
@@ -441,7 +533,18 @@ class H2ODeepLearningEstimator(ModelBuilder):
         return net
 
     def _train_impl(self, spec: TrainingSpec, valid_spec, job: Job):
+        """The trainer's stages, each a span under ``train.train`` and a
+        key of ``model.output["train_profile"]`` (model_base adds queue,
+        spec, total and other): ``train.init`` (design matrix, moments,
+        standardisation, the up-front permutation, ended by a fence),
+        ``train.loop`` (the epochs up to the loop fence, then
+        ``train.score``: the last epoch's training loss, or, where early
+        stopping asks, every epoch's) and ``train.finalize`` (the model
+        and its metrics). Scores nest in the loop as the tree trainers'
+        do, so ``train_profile``'s ``score_s`` is inside ``loop_s``."""
+        from h2o3_tpu.log import Profile
         p = self.params
+        prof = Profile()
         autoenc = bool(p.get("autoencoder"))
         task = ("autoencoder" if autoenc else
                 "classification" if spec.nclasses > 1 else "regression")
@@ -455,217 +558,240 @@ class H2ODeepLearningEstimator(ModelBuilder):
                              f"{sorted(_ACTS)} (maxout not implemented)")
         act = _ACTS[act_name]
         prior = self._resolve_checkpoint(spec, task, act_name)
-        Xe, exp_names, means = expand_design(
-            spec, impute_means=(dict(prior.impute_means)
-                                if prior is not None else None))
-        if prior is not None and list(prior.exp_names) != list(exp_names):
-            raise ValueError(
-                f"checkpoint expanded design {prior.exp_names} differs "
-                f"from the training frame's {exp_names} — the prior "
-                f"weights would address the wrong inputs")
-        Fe = Xe.shape[1]
-        w = spec.w
-        # weighted standardization
-        if prior is not None:
-            # continue in the PRIOR model's input space — its weights
-            # are only valid under its own standardization (and the
-            # fresh reduction would be discarded anyway)
-            xm = jnp.asarray(prior.xm, jnp.float32)
-            xs = jnp.asarray(prior.xs, jnp.float32)
-        else:
-            wsum = w.sum()
-            xm = (Xe * w[:, None]).sum(0) / wsum
-            xv = (w[:, None] * (Xe - xm[None, :]) ** 2).sum(0) / wsum
-            xs = jnp.sqrt(jnp.maximum(xv, 1e-12))
-            if not bool(p.get("standardize", True)):
-                xm = jnp.zeros_like(xm)
-                xs = jnp.ones_like(xs)
-        Xs = (Xe - xm[None, :]) / xs[None, :]
-        if task == "autoencoder":
-            # the network reconstructs its own standardized inputs
-            # (hex/deeplearning autoencoder mode)
-            y = Xs
-            n_out = Fe
-        else:
-            y = (spec.y.astype(jnp.int32) if task == "classification"
-                 else spec.y.astype(jnp.float32))
-            n_out = spec.nclasses if task == "classification" else 1
-        hidden = [int(h) for h in (p.get("hidden") or (200, 200))]
-        sizes = [Fe] + hidden + [n_out]
-        seed = int(p.get("seed", -1) or -1)
-        key = jax.random.PRNGKey(seed if seed != -1
-                                 else int(time.time() * 1e3) % (2 ** 31))
-        key, ik = jax.random.split(key)
-        if prior is not None:
-            net = [{"W": jnp.asarray(ly["W"], jnp.float32),
-                    "b": jnp.asarray(ly["b"], jnp.float32)}
-                   for ly in prior.net]
-        else:
-            net = _init_params(ik, sizes)
-        net = self._apply_initial_weights(net, sizes)
-
-        padded = Xs.shape[0]
-        nrow = spec.nrow
-        # cap the batch so an epoch always makes >=8 optimizer updates
-        # (and never exceeds the frame): the reference's per-row Hogwild
-        # loop gets nrow updates per epoch; one giant batch would starve
-        # small frames of updates entirely
-        batch = max(min(int(p.get("mini_batch_size", 256)),
-                        max(padded // 8, 1)), 1)
-        n_batches = padded // batch
-        use_rows = n_batches * batch
-        epochs = float(p.get("epochs", 10.0))
-        prior_epochs = 0.0
-        if prior is not None:
-            # epochs is the TOTAL (hex/Model checkpoint semantics, same
-            # contract as the GBM resolver's ntrees): continue for the
-            # remainder, and reject a target the prior already met
-            prior_epochs = float(prior.output.get("epochs_trained", 0.0))
-            if epochs <= prior_epochs:
+        with prof.phase("init"):
+            Xe, exp_names, means = expand_design(
+                spec, impute_means=(dict(prior.impute_means)
+                                    if prior is not None else None))
+            if prior is not None and list(prior.exp_names) != list(exp_names):
                 raise ValueError(
-                    f"epochs ({epochs}) must exceed the checkpoint's "
-                    f"epochs_trained ({prior_epochs})")
-            epochs = epochs - prior_epochs
-        adaptive = bool(p.get("adaptive_rate", True))
-        rho = float(p.get("rho", 0.99))
-        eps = float(p.get("epsilon", 1e-8))
-        rate0 = float(p.get("rate", 0.005))
-        annealing = float(p.get("rate_annealing", 1e-6))
-        mom_start = float(p.get("momentum_start", 0.0))
-        mom_ramp = max(float(p.get("momentum_ramp", 1e6)), 1.0)
-        mom_stable = float(p.get("momentum_stable", 0.0))
-        l1 = float(p.get("l1", 0.0))
-        l2 = float(p.get("l2", 0.0))
-        in_drop = float(p.get("input_dropout_ratio", 0.0))
-        hid_drops = p.get("hidden_dropout_ratios")
-        if hid_drops is None:
-            hid_drops = ([0.5] * len(hidden) if act_name.endswith("_dropout")
-                         else [0.0] * len(hidden))
-        hid_drops = [float(d) for d in hid_drops]
-        use_dropout = in_drop > 0 or any(d > 0 for d in hid_drops)
+                    f"checkpoint expanded design {prior.exp_names} differs "
+                    f"from the training frame's {exp_names} — the prior "
+                    f"weights would address the wrong inputs")
+            Fe = Xe.shape[1]
+            w = spec.w
+            # weighted standardization
+            if prior is not None:
+                # continue in the PRIOR model's input space — its weights
+                # are only valid under its own standardization (and the
+                # fresh reduction would be discarded anyway)
+                xm = jnp.asarray(prior.xm, jnp.float32)
+                xs = jnp.asarray(prior.xs, jnp.float32)
+            else:
+                wsum = w.sum()
+                xm = (Xe * w[:, None]).sum(0) / wsum
+                xv = (w[:, None] * (Xe - xm[None, :]) ** 2).sum(0) / wsum
+                xs = jnp.sqrt(jnp.maximum(xv, 1e-12))
+                if not bool(p.get("standardize", True)):
+                    xm = jnp.zeros_like(xm)
+                    xs = jnp.ones_like(xs)
+            Xs = (Xe - xm[None, :]) / xs[None, :]
+            del Xe
+            if task == "autoencoder":
+                # the network reconstructs its own standardized inputs
+                # (hex/deeplearning autoencoder mode)
+                y = Xs
+                n_out = Fe
+            else:
+                y = (spec.y.astype(jnp.int32) if task == "classification"
+                     else spec.y.astype(jnp.float32))
+                n_out = spec.nclasses if task == "classification" else 1
+            hidden = [int(h) for h in (p.get("hidden") or (200, 200))]
+            sizes = [Fe] + hidden + [n_out]
+            seed = int(p.get("seed", -1) or -1)
+            key = jax.random.PRNGKey(seed if seed != -1
+                                     else int(time.time() * 1e3) % (2 ** 31))
+            key, ik = jax.random.split(key)
+            if prior is not None:
+                net = [{"W": jnp.asarray(ly["W"], jnp.float32),
+                        "b": jnp.asarray(ly["b"], jnp.float32)}
+                       for ly in prior.net]
+            else:
+                net = _init_params(ik, sizes)
+            net = self._apply_initial_weights(net, sizes)
 
-        opt0 = _init_opt(net, adaptive)
-        shuffle = bool(p.get("shuffle_training_data", False))
-        run_epoch = _compiled_epoch(
-            tuple(sizes), act_name, task, dist_name, l1, l2, in_drop,
-            tuple(hid_drops), use_dropout, adaptive, rho, eps, rate0,
-            annealing, mom_start, mom_ramp, mom_stable, batch, n_batches,
-            use_rows, padded, shuffle)
+            padded = Xs.shape[0]
+            # cap the batch so an epoch always makes >=8 optimizer updates
+            # (and never exceeds the frame): the reference's per-row
+            # Hogwild loop gets nrow updates per epoch; one giant batch
+            # would starve small frames of updates entirely
+            batch = max(min(int(p.get("mini_batch_size", 256)),
+                            max(padded // 8, 1)), 1)
+            n_batches = padded // batch
+            use_rows = n_batches * batch
+            epochs = float(p.get("epochs", 10.0))
+            prior_epochs = 0.0
+            if prior is not None:
+                # epochs is the TOTAL (hex/Model checkpoint semantics, same
+                # contract as the GBM resolver's ntrees): continue for the
+                # remainder, and reject a target the prior already met
+                prior_epochs = float(prior.output.get("epochs_trained", 0.0))
+                if epochs <= prior_epochs:
+                    raise ValueError(
+                        f"epochs ({epochs}) must exceed the checkpoint's "
+                        f"epochs_trained ({prior_epochs})")
+                epochs = epochs - prior_epochs
+            cfg = _step_config(p, sizes, act_name, task, dist_name)
+            opt0 = _init_opt(net, cfg.adaptive)
+            shuffle = bool(p.get("shuffle_training_data", False))
+            run_epoch = _compiled_epoch(cfg, batch, n_batches, use_rows,
+                                        padded, shuffle)
 
-        if not shuffle:
-            key, pk = jax.random.split(key)
-            perm0 = jax.random.permutation(pk, padded)
-            Xs = Xs[perm0]
-            y = y[perm0]
-            w = w[perm0]
-        keeper = ScoreKeeper(p.get("stopping_rounds", 0),
-                             p.get("stopping_metric"),
-                             p.get("stopping_tolerance", 1e-3),
-                             "binomial" if spec.nclasses == 2 else
-                             "multinomial" if spec.nclasses > 2 else
-                             "regression")
-        n_epochs = max(int(np.ceil(epochs)), 1)
-        # annealing/momentum ramp continue from the prior sample count
-        samples = jnp.float32(prior.output.get("training_samples", 0.0)
-                              if prior is not None else 0.0)
-        t0 = time.monotonic()
+            if not shuffle:
+                key, pk = jax.random.split(key)
+                perm0 = jax.random.permutation(pk, padded)
+                Xs = Xs[perm0]
+                y = y[perm0]
+                w = w[perm0]
+            keeper = ScoreKeeper(p.get("stopping_rounds", 0),
+                                 p.get("stopping_metric"),
+                                 p.get("stopping_tolerance", 1e-3),
+                                 "binomial" if spec.nclasses == 2 else
+                                 "multinomial" if spec.nclasses > 2 else
+                                 "regression")
+            n_epochs = max(int(np.ceil(epochs)), 1)
+            # annealing/momentum ramp continue from the prior sample count
+            samples = jnp.float32(prior.output.get("training_samples", 0.0)
+                                  if prior is not None else 0.0)
+            jax.block_until_ready((Xs, y, w))  # h2o3-lint: allow[transfer-seam] stage fence: train.init ends when the permuted design matrix is on the device
         history = []
-        # cancel/max_runtime polling (the last ROADMAP-listed algo
-        # without it — GLM/KMeans landed in PR 7): run_epoch dispatches
-        # ASYNCHRONOUSLY, so an unbounded loop would enqueue every
-        # remaining epoch before a watchdog cancel could land — the
-        # cooperative poll would see nothing left to skip. Poll BEFORE
-        # each dispatch and keep at most two epochs in flight by
-        # blocking on epoch e-1's loss scalar before dispatching e+1:
-        # compute still overlaps host work, but a cancel takes effect
-        # within ~one epoch instead of at the end of the train.
-        prev_loss = None
-        e = 0
-        for e in range(n_epochs):
-            if job.cancel_requested:
-                e -= 1      # this epoch never dispatched
-                break
-            key, ekey = jax.random.split(key)
-            if prev_loss is not None:
-                jax.block_until_ready(prev_loss)  # h2o3-lint: allow[transfer-seam] deliberate depth bound: at most 2 epochs in flight (cancel-polling contract)
-            net, opt0, samples, mloss = run_epoch(
-                net, opt0, samples, ekey, Xs, y, w,
-                jnp.int32((e * batch) % max(padded, 1)))
-            prev_loss = mloss
-            job.set_progress((e + 1) / n_epochs)
-            if keeper.rounds > 0 or e == n_epochs - 1:
-                entry = self._score(net, act, Xs, y, w, valid_spec, task,
-                                    dist_name, xm, xs, means, exp_names, spec,
-                                    e + 1)
+        # what the epochs run, as the loop span and the model say it;
+        # "default" matmul precision is bfloat16 operands with float32
+        # accumulation on the TPU; weights and optimizer state are float32
+        loop_rec = {"sizes": list(sizes), "batch": batch,
+                    "n_batches": n_batches,
+                    "optimizer": ("adadelta" if cfg.adaptive
+                                  else "momentum_sgd"),
+                    "precision": str(jax.config.jax_default_matmul_precision
+                                     or "default")}
+        with prof.phase("loop") as sp_loop:
+            # cancel/max_runtime polling (the last ROADMAP-listed algo
+            # without it — GLM/KMeans landed in PR 7): run_epoch dispatches
+            # ASYNCHRONOUSLY, so an unbounded loop would enqueue every
+            # remaining epoch before a watchdog cancel could land — the
+            # cooperative poll would see nothing left to skip. Poll BEFORE
+            # each dispatch and keep at most two epochs in flight by
+            # blocking on epoch e-1's loss scalar before dispatching e+1:
+            # compute still overlaps host work, but a cancel takes effect
+            # within ~one epoch instead of at the end of the train.
+            prev_loss = None
+            e = 0
+            for e in range(n_epochs):
+                if job.cancel_requested:
+                    e -= 1      # this epoch never dispatched
+                    break
+                key, ekey = jax.random.split(key)
+                if prev_loss is not None:
+                    jax.block_until_ready(prev_loss)  # h2o3-lint: allow[transfer-seam] deliberate depth bound: at most 2 epochs in flight (cancel-polling contract)
+                net, opt0, samples, mloss = run_epoch(
+                    net, opt0, samples, ekey, Xs, y, w,
+                    jnp.int32((e * batch) % max(padded, 1)))
+                prev_loss = mloss
+                job.set_progress((e + 1) / n_epochs)
+                if keeper.rounds > 0:
+                    with prof.phase("score"):
+                        entry = self._score(net, act, Xs, y, w, task,
+                                            e + 1)
+                    keeper.record(entry)
+                    history.append(entry)
+                    if keeper.should_stop():
+                        break
+                if job.cancel_requested:
+                    break
+            jax.block_until_ready(net[0]["W"])  # h2o3-lint: allow[transfer-seam] epoch-loop timing fence: the loop clock must cover device completion
+            if e >= 0 and (not history or history[-1]["epoch"] != e + 1):
+                # the last epoch's training loss, once the epochs are done
+                with prof.phase("score"):
+                    entry = self._score(net, act, Xs, y, w, task, e + 1)
                 keeper.record(entry)
                 history.append(entry)
-                if keeper.should_stop():
-                    break
-            if job.cancel_requested:
-                break
-        jax.block_until_ready(net[0]["W"])  # h2o3-lint: allow[transfer-seam] epoch-loop timing fence: the loop clock must cover device completion
-        t_loop = time.monotonic() - t0
+            loop_rec["epochs"] = e + 1
+            if sp_loop is not None:
+                sp_loop.attrs.update(loop_rec)
+        # counted once a train on the host, from shapes: what the
+        # epochs dispatched, not a device fetch
+        for name, n, what in (
+                ("h2o3_dl_optimizer_steps_total", n_batches * (e + 1),
+                 "optimizer steps of finished DeepLearning trains"),
+                ("h2o3_dl_rows_trained_total", use_rows * (e + 1),
+                 "rows the optimizer steps of finished DeepLearning "
+                 "trains read")):
+            telemetry.counter(name, {"algo": self.algo}, help=what).inc(n)
 
-        model = DeepLearningModel(
-            f"dl_{id(self) & 0xffffff:x}", self.params, spec, net, exp_names,
-            {k: float(telemetry.device_get(v, pipeline="train"))
-             for k, v in means.items()},
-            telemetry.device_get(xm, pipeline="train"),
-            telemetry.device_get(xs, pipeline="train"), task, dist_name,
-            hidden,
-            act_name)
-        model.scoring_history = history
-        model.output["training_loop_seconds"] = t_loop
-        model.output["epochs_trained"] = prior_epochs + e + 1
-        model.output["training_samples"] = float(
-            telemetry.device_get(samples, pipeline="train"))
-        if task == "autoencoder":
-            # reconstruction error metrics (hex/ModelMetricsAutoEncoder:
-            # MSE over all reconstructed cells)
-            from h2o3_tpu.models.metrics import ModelMetricsRegression
-
-            def recon_metrics(Xs_in, w_in):
-                out_ = _forward(net, Xs_in, act)
-                per_row, wh = (np.asarray(v) for v in
-                               telemetry.device_get(
-                                   (((out_ - Xs_in) ** 2).mean(axis=1),
-                                    w_in), pipeline="train"))
-                live = wh > 0
-                mse = float((per_row[live] * wh[live]).sum()
-                            / max(wh[live].sum(), 1e-30))
-                # MSE IS the reconstruction error — do not route per-row
-                # MSEs through the regression maker (that would square
-                # them again); ModelMetricsAutoEncoder reports the mean
-                mm = ModelMetricsRegression(
-                    mse=mse, rmse=float(np.sqrt(mse)),
-                    mae=float("nan"), rmsle=float("nan"),
-                    r2=float("nan"), mean_residual_deviance=mse,
-                    nobs=int(live.sum()))
-                return mm, mse
-
-            model.training_metrics, mse = recon_metrics(Xs, w)
-            model.output["reconstruction_mse"] = mse
-            if valid_spec is not None:
-                vXe, _, _ = expand_design(valid_spec, impute_means=means)
-                vXs = (vXe - xm[None, :]) / xs[None, :]
-                model.validation_metrics, vmse = recon_metrics(
-                    vXs, valid_spec.w)
-                model.output["validation_reconstruction_mse"] = vmse
-            return model
-        out = model._predict_matrix(spec.X)
-        model.training_metrics = compute_metrics(out, spec.y, w,
-                                                 spec.nclasses,
-                                                 spec.response_domain)
-        if valid_spec is not None:
-            vout = model._predict_matrix(valid_spec.X)
-            model.validation_metrics = compute_metrics(
-                vout, valid_spec.y, valid_spec.w, spec.nclasses,
-                spec.response_domain)
+        with prof.phase("finalize"):
+            model = DeepLearningModel(
+                f"dl_{id(self) & 0xffffff:x}", self.params, spec, net,
+                exp_names,
+                {k: float(telemetry.device_get(v, pipeline="train"))
+                 for k, v in means.items()},
+                telemetry.device_get(xm, pipeline="train"),
+                telemetry.device_get(xs, pipeline="train"), task, dist_name,
+                hidden, act_name)
+            model.optimizer_state = opt0
+            model.scoring_history = history
+            model.output["epochs_trained"] = prior_epochs + e + 1
+            model.output["training_samples"] = float(
+                telemetry.device_get(samples, pipeline="train"))
+            model.output["train_loop"] = loop_rec
+            model.output["precision"] = {
+                "matmul": loop_rec["precision"], "weights": "float32",
+                "optimizer_state": "float32"}
+            if task == "autoencoder":
+                self._autoencoder_metrics(model, net, act, Xs, w,
+                                          valid_spec, means, xm, xs)
+            else:
+                out = model._predict_matrix(spec.X)
+                model.training_metrics = compute_metrics(
+                    out, spec.y, spec.w, spec.nclasses,
+                    spec.response_domain)
+                if valid_spec is not None:
+                    vout = model._predict_matrix(valid_spec.X)
+                    model.validation_metrics = compute_metrics(
+                        vout, valid_spec.y, valid_spec.w, spec.nclasses,
+                        spec.response_domain)
+        model.output["training_loop_seconds"] = prof.phases["loop"]
+        model.output["train_profile"] = {
+            f"{k}_s": round(prof.phases.get(k, 0.0), 4)
+            for k in ("init", "loop", "score", "finalize")}
         return model
 
-    def _score(self, net, act, Xs, y, w, valid_spec, task, dist_name, xm,
-               xs, means, exp_names, spec, epoch):
-        out = _forward(net, Xs, act)
+    @staticmethod
+    def _autoencoder_metrics(model, net, act, Xs, w, valid_spec, means, xm,
+                             xs):
+        """Reconstruction error metrics (hex/ModelMetricsAutoEncoder: MSE
+        over all reconstructed cells), training and validation."""
+        from h2o3_tpu.models.metrics import ModelMetricsRegression
+
+        def recon_metrics(Xs_in, w_in):
+            out_ = _forward_rows(net, Xs_in, act)
+            per_row, wh = (np.asarray(v) for v in
+                           telemetry.device_get(
+                               (((out_ - Xs_in) ** 2).mean(axis=1),
+                                w_in), pipeline="train"))
+            live = wh > 0
+            mse = float((per_row[live] * wh[live]).sum()
+                        / max(wh[live].sum(), 1e-30))
+            # MSE IS the reconstruction error — do not route per-row
+            # MSEs through the regression maker (that would square
+            # them again); ModelMetricsAutoEncoder reports the mean
+            mm = ModelMetricsRegression(
+                mse=mse, rmse=float(np.sqrt(mse)),
+                mae=float("nan"), rmsle=float("nan"),
+                r2=float("nan"), mean_residual_deviance=mse,
+                nobs=int(live.sum()))
+            return mm, mse
+
+        model.training_metrics, mse = recon_metrics(Xs, w)
+        model.output["reconstruction_mse"] = mse
+        if valid_spec is not None:
+            vXe, _, _ = expand_design(valid_spec, impute_means=means)
+            vXs = (vXe - xm[None, :]) / xs[None, :]
+            model.validation_metrics, vmse = recon_metrics(
+                vXs, valid_spec.w)
+            model.output["validation_reconstruction_mse"] = vmse
+
+    @staticmethod
+    def _score(net, act, Xs, y, w, task, epoch):
+        out = _forward_rows(net, Xs, act)
         if task == "autoencoder":
             mse = float(telemetry.device_get(
                 (w * ((out - y) ** 2).mean(axis=1)).sum() / w.sum(),

@@ -344,6 +344,97 @@ def test_a_set_split_train_says_so_in_spans_counters_and_routes():
     assert 'h2o3_tree_splits_total{algo="gbm"}' in exposition
 
 
+@pytest.mark.parametrize("stopping_rounds", [0, 2],
+                         ids=["scored_once", "scored_every_epoch"])
+def test_a_deeplearning_train_leaves_its_stages_attrs_and_counters(
+        frame, stopping_rounds):
+    """A DeepLearning train (ISSUE 39) leaves ``train.init``,
+    ``train.loop`` (attrs: what the epochs ran, as ``model.output
+    ["train_loop"]`` says it) with ``train.score`` in it, and
+    ``train.finalize`` under ``train.train``; its ``train_profile`` is
+    their durations; the two counters move by what the epochs dispatched,
+    from shapes; /metrics shows them. The last epoch is scored once after
+    the loop's fence; early stopping scores every epoch (three epochs never
+    meet two rounds of two) and nothing after."""
+    from h2o3_tpu.api import server
+    from h2o3_tpu.models.deeplearning import H2ODeepLearningEstimator
+    names = ("h2o3_dl_optimizer_steps_total", "h2o3_dl_rows_trained_total")
+    before = {k: _counter(k, "deeplearning") for k in names}
+    est = H2ODeepLearningEstimator(
+        hidden=[16, 8], epochs=3, seed=1, distribution="bernoulli",
+        mini_batch_size=4096, stopping_rounds=stopping_rounds)
+    telemetry.clear_spans()
+    est.train(y="y", training_frame=frame)
+    parents, named = _tree(telemetry.finished_spans())
+    want = {"train.deeplearning": None, "train.queue": "train.deeplearning",
+            "train.spec": "train.deeplearning",
+            "train.train": "train.deeplearning", "train.init": "train.train",
+            "train.loop": "train.train", "train.score": "train.loop",
+            "train.finalize": "train.train"}
+    for name, parent in want.items():
+        assert name in parents, f"no span {name}: {sorted(parents)}"
+        assert set(parents[name]) == {parent}, (name, parents[name])
+    assert len(named["train.score"]) == (3 if stopping_rounds else 1)
+    out = est.model.output
+    n_batches = ROWS // 4096
+    assert out["train_loop"] == {
+        "sizes": [FEATURES, 16, 8, 2], "batch": 4096,
+        "n_batches": n_batches, "epochs": 3, "optimizer": "adadelta",
+        "precision": "default"}
+    loop = named["train.loop"][0]
+    for key, value in out["train_loop"].items():
+        assert loop.attrs[key] == value, key
+    assert out["precision"] == {"matmul": "default", "weights": "float32",
+                                "optimizer_state": "float32"}
+    tp = out["train_profile"]
+    assert set(tp) == {"init_s", "loop_s", "score_s", "finalize_s",
+                       "queue_s", "spec_s", "total_s", "other_s"}
+    for key, name in (("init_s", "train.init"), ("loop_s", "train.loop"),
+                      ("score_s", "train.score"),
+                      ("finalize_s", "train.finalize"),
+                      ("spec_s", "train.spec")):
+        assert tp[key] == pytest.approx(
+            sum(s.duration_s for s in named[name]), abs=6e-5), key
+    order = [named[n][0] for n in ("train.init", "train.loop",
+                                   "train.finalize")]
+    assert [s.t0 for s in order] == sorted(s.t0 for s in order)
+    assert out["training_loop_seconds"] == pytest.approx(tp["loop_s"],
+                                                         abs=6e-5)
+    assert _counter(names[0], "deeplearning") - before[names[0]] == (
+        3 * n_batches)
+    assert _counter(names[1], "deeplearning") - before[names[1]] == (
+        3 * n_batches * 4096)
+    exposition = server._metrics({}, None)["__raw"]
+    exposition = (exposition.decode() if isinstance(exposition, bytes)
+                  else str(exposition))
+    for name in names:
+        assert f'{name}{{algo="deeplearning"}}' in exposition
+
+
+def test_a_deeplearning_model_hands_out_the_step_its_epochs_ran(frame):
+    """``deeplearning.compiled_step`` is the epoch's scan body compiled
+    alone: from the train's own last optimizer state, one step on a batch
+    moves the weights and the accumulators, and counts the batch's rows."""
+    from h2o3_tpu.models import deeplearning as dl
+    est = dl.H2ODeepLearningEstimator(hidden=[16, 8], epochs=1, seed=1,
+                                      distribution="bernoulli",
+                                      mini_batch_size=4096)
+    est.train(y="y", training_frame=frame)
+    m = est.model
+    Eg, Ed = m.optimizer_state
+    assert [ly["W"].shape for ly in Eg] == [ly["W"].shape for ly in m.net]
+    xb = jnp.zeros((64, FEATURES), jnp.float32).at[:, 0].set(1.0)
+    yb = jnp.ones((64,), jnp.int32)
+    net, (nEg, nEd), samples, loss = dl.compiled_step(m)(
+        m.net, m.optimizer_state, jnp.float32(100.0), xb, yb,
+        jnp.ones((64,), jnp.float32), jax.random.PRNGKey(0))
+    assert float(samples) == 164.0 and float(loss) > 0
+    assert not np.array_equal(np.asarray(net[2]["b"]),
+                              np.asarray(m.net[2]["b"]))
+    assert not np.array_equal(np.asarray(nEd[2]["b"]),
+                              np.asarray(Ed[2]["b"]))
+
+
 @pytest.mark.parametrize("hist,where", [("uniform_adaptive", "mesh"),
                                         ("quantiles_global", "device")])
 def test_the_sketch_and_loop_spans_say_where_edges_were_made_and_what_crossed(
