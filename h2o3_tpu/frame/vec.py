@@ -236,15 +236,41 @@ class Vec:
         """Numeric → categorical conversion (h2o-py ``vec.asfactor()``;
         water/rapids/ast/prims/operators/AstAsFactor semantics): distinct
         finite values become the sorted domain, NA stays NA."""
+        return self.factor()[0]
+
+    def factor(self):
+        """(``asfactor()``'s Vec, the path that made it): ``none`` for an
+        enum, else counted in ``h2o3_factor_total{path}``. A device payload
+        of enough rows whose finite values are a small integer range is
+        factored on the device (``frame/factor.py``: ``device_range``);
+        strings, a Vec whose values live on the host (an exact wide-int or
+        time copy, a spilled payload) and every other column take the host
+        formula."""
         if self.type == T_ENUM:
-            return self
-        if self.type == T_STR:
-            return Vec._from_strings(self.host_data, current_mesh())
+            return self, "none"
+        made = None
+        if self.host_data is None and self._dev is not None:
+            from h2o3_tpu.frame.factor import factor
+            made = factor(self.data, self.nrow)
+        if made is not None:
+            codes, domain = made
+            out = Vec(codes, self.nrow, T_ENUM, domain=domain)
+            path = "device_range"
+        elif self.type == T_STR:
+            out, path = Vec._from_strings(self.host_data, current_mesh()), "host"
+        else:
+            out, path = self._factor_host(), "host"
+        from h2o3_tpu import telemetry
+        telemetry.counter("h2o3_factor_total", {"path": path},
+                          help="numeric and string Vecs made enums, by "
+                               "the path that made them").inc()
+        return out, path
+
+    def _factor_host(self) -> "Vec":
         raw = self.to_numpy()
         finite = np.isfinite(raw)
         vals = np.unique(raw[finite])
-        domain = tuple(str(int(v)) if float(v).is_integer() else str(v)
-                       for v in vals)
+        domain = tuple(level_label(v) for v in vals)
         codes = np.searchsorted(vals, raw).astype(np.int32)
         codes[~finite] = ENUM_NA
         return Vec.from_numpy(codes, vtype=T_ENUM, domain=domain)
@@ -343,6 +369,11 @@ class Vec:
         v = Vec(new_data, self.nrow, vtype or self.type,
                 domain if domain is not None else self.domain)
         return v
+
+
+def level_label(v) -> str:
+    """A numeric value's level in a factor's domain: ``"3"`` for 3.0."""
+    return str(int(v)) if float(v).is_integer() else str(v)
 
 
 def _is_integral(f: np.ndarray) -> bool:

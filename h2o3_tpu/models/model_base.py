@@ -51,6 +51,9 @@ class TrainingSpec:
     # (water/Cleaner.java graceful-degradation analog); X above is None
     X_host: Any = None
     stream: bool = False
+    # how the response became an enum (Vec.factor's path; "none" where it
+    # was one already or the train is a regression)
+    response_factor: str = "none"
 
     @property
     def n_features(self) -> int:
@@ -74,10 +77,12 @@ def build_training_spec(frame: Frame, y: str, x: Optional[Sequence[str]] = None,
     rvec = frame.vec(y)
     if classification is None:
         classification = rvec.type == T_ENUM
-    if classification and rvec.type != T_ENUM:
+    response_factor = "none"
+    if classification:
         # numeric response used as classification → derive domain
-        # (Vec.asfactor: unique finite values → sorted domain, NaN → NA)
-        rvec = rvec.asfactor()
+        # (Vec.factor: unique finite values → sorted domain, NaN → NA;
+        # an enum is its own factor)
+        rvec, response_factor = rvec.factor()
     # memory pressure gate (water/MemoryManager.java allocation gate):
     # a design matrix beyond the device budget stays on HOST and the
     # algorithms stream row chunks (X_host/stream mode)
@@ -127,7 +132,8 @@ def build_training_spec(frame: Frame, y: str, x: Optional[Sequence[str]] = None,
     return TrainingSpec(X=X, y=y_dev, w=w, names=names, is_cat=is_cat,
                         cat_domains=cat_domains, nrow=nrow, response=y,
                         response_domain=response_domain, nclasses=nclasses,
-                        offset=offset, X_host=X_host, stream=stream)
+                        offset=offset, X_host=X_host, stream=stream,
+                        response_factor=response_factor)
 
 
 def build_parallelism(par: int) -> int:
@@ -1027,8 +1033,10 @@ class ModelBuilder:
         timeline_record("train_start", f"{self.algo}")
         self._warn_compat_params()
         try:
-            with prof.phase("spec"):
+            with prof.phase("spec") as sp_spec:
                 spec = self._make_spec(training_frame, y, x)
+                if sp_spec is not None:
+                    sp_spec.attrs["response_factor"] = spec.response_factor
                 spec = self._apply_balance_classes(spec)
                 if self.params.get("calibrate_model"):
                     self._validate_calibration(spec)

@@ -90,6 +90,11 @@ def test_a_train_leaves_the_span_tree_with_the_right_parents(frame,
     assert len(named["train.score"]) == 2
     assert len(named["train.bin"]) == len(named["train.loop"]) == 1
     assert len(named["train.init"]) == 1     # the stage before the loop
+    # the numeric 0/1 response's factor: the host formula's under the row
+    # floor of the device's range pass, the range pass's at or above it
+    from h2o3_tpu.frame import factor
+    assert named["train.spec"][0].attrs["response_factor"] == (
+        "device_range" if ROWS >= factor.DEVICE_MIN_ROWS else "host")
     loop = named["train.loop"][0]
     assert loop.attrs["trees"] == 6 and loop.attrs["chunks"] == 2
     # the packed path says what its levels ran, as the model's record does
@@ -312,6 +317,8 @@ def test_a_set_split_train_says_so_in_spans_counters_and_routes():
     sketch, loop = named["train.bin.sketch"][0], named["train.loop"][0]
     assert (sketch.attrs["enum_features"],
             sketch.attrs["numeric_features"]) == (1, 1)
+    # an enum response needs no factor
+    assert named["train.spec"][0].attrs["response_factor"] == "none"
     pc = est.model.output["packed_codes"]
     # 40 levels + NA -> 48 lanes, 20 bins + NA -> 24
     assert (pc["lane_layout"], pc["lanes"], pc["set_features"], pc["W"]) == (

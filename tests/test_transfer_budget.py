@@ -297,6 +297,36 @@ def test_transfer_bytes_pipeline_attribution():
     assert r.value("h2o3_d2h_bytes_total") == t0 + 25
 
 
+# ------------------------------------------------ the response's factor
+
+
+def test_factoring_a_device_only_label_fetches_a_few_bytes_and_uploads_none(
+        monkeypatch):
+    """``Vec.asfactor`` of a 1M-row 0/1 label that lives on the device
+    alone (as a train's numeric response in ``train.spec``): the range
+    pass fetches its summary and two flags, where the host formula
+    fetched the 4 MB column and uploaded 4 MB of codes. The row floor is
+    held at 0 to keep the column small."""
+    import jax
+    from h2o3_tpu.frame import factor
+    from h2o3_tpu.frame.vec import T_REAL, Vec
+    from h2o3_tpu.parallel.mesh import data_sharding, padded_len
+    if not telemetry.enabled():
+        pytest.skip("telemetry disabled")
+    monkeypatch.setattr(factor, "DEVICE_MIN_ROWS", 0)
+    n = 1 << 20
+    y = np.full(padded_len(n), np.nan, np.float32)
+    y[:n] = np.random.default_rng(40).integers(0, 2, n)
+    vec = Vec(jax.device_put(y, data_sharding()), n, T_REAL)
+    vec.asfactor()                    # compiles its programs
+    d2h0 = _counter("h2o3_d2h_bytes_total")
+    h2d0 = _counter("h2o3_h2d_bytes_total")
+    out, path = vec.factor()
+    assert path == "device_range" and out.domain == ("0", "1")
+    assert 0 < _counter("h2o3_d2h_bytes_total") - d2h0 <= 4096
+    assert _counter("h2o3_h2d_bytes_total") - h2d0 == 0
+
+
 # ------------------------------------------- the bin stage's edges, by mesh
 
 
